@@ -106,12 +106,13 @@ class Node:
                     "search.tpu_serving.plan_cache_size", 2048),
                 prewarm_concurrency=self.settings.get_int(
                     "search.tpu_serving.prewarm_concurrency", 4),
-                # persistent XLA compile cache colocated with the node's
-                # data (restart = cache replay, not recompilation);
-                # ES_TPU_JAX_CACHE_DIR still overrides
+                # persistent XLA compile cache (restart = cache replay,
+                # not recompilation). Never under data_path: the
+                # directory is part of jax's cache key, so it stays put
+                # (JAX_COMPILATION_CACHE_DIR, this setting, or
+                # <checkout>/.jax_cache)
                 compile_cache_dir=self.settings.get(
-                    "search.tpu_serving.compile_cache_dir",
-                    _os.path.join(data_path, "jax_compile_cache")),
+                    "search.tpu_serving.compile_cache_dir"),
                 # packed-key device kernels (PERF.md round 8): single
                 # uint32 sort key + hierarchical top-k, with automatic
                 # per-launch exact-f32 fallback when the pack/batch
@@ -121,16 +122,16 @@ class Node:
                 # compressed resident packs (PERF.md round 11): 16-bit
                 # impact/doc/rank streams + residual tables + block-max
                 # metadata + delta doc stream; ~3x fewer HBM bytes/doc
-                # at identical result bits. Default ON since PR 15 (see
-                # README "kernel variants" for the real-chip soak
-                # status); incompressible packs fall back to raw
-                # residency
+                # at identical result bits. Default ON since PR 15;
+                # chip_smoke.py holds it to the numpy reference on the
+                # chip. Incompressible packs fall back to raw residency
                 compressed_pack=self.settings.get_bool(
                     "search.tpu_serving.kernel.compressed_pack", True),
                 # fused Pallas merge kernel (PR 15): the whole compressed
-                # hot loop as one kernel — off by default until the
-                # Mosaic soak on real chips lands; bit-identical and
-                # typed-fallback-gated wherever it is enabled
+                # hot loop as one kernel — off by default; runs under the
+                # interpreter off-TPU, and on a TPU backend the node
+                # refuses to start with it on (the kernel does not
+                # compile there: ops/pallas_merge.TPU_REFUSAL)
                 pallas=self.settings.get_bool(
                     "search.tpu_serving.kernel.pallas", False),
                 # supervision: dispatches overdue past this deadline are

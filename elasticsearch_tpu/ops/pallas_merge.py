@@ -11,16 +11,18 @@ in device memory and are sliced per slot inside the kernel, so the
 intermediate sorted-operand materialisation between gather and merge
 never round-trips through HBM.
 
-Dispatch is backend-aware: on TPU the kernel compiles through Mosaic;
-everywhere else it runs under interpret=True, which executes the exact
-same trace the XLA "compressed" variant lowers from — the parity sweep
-(tests/test_sparse_kernel.py) pins variant="pallas" bit-identical to
-variant="ref" on CPU by construction. Real-chip soak is still pending
-(README "kernel variants"): Mosaic support for lax.sort/top_k inside a
-kernel varies by jaxlib generation, so serving keeps the variant behind
-the `search.tpu_serving.kernel.pallas` knob with the same typed
-fallback gates (planner.choose_kernel_variant) as the other variants,
-and falls back to the plain core if Pallas itself is unavailable.
+Dispatch is backend-aware: off the TPU the kernel runs under
+interpret=True, which executes the exact same trace the XLA "compressed"
+variant lowers from — the parity sweep (tests/test_sparse_kernel.py) pins
+variant="pallas" bit-identical to variant="ref" on CPU by construction.
+
+On a TPU backend the kernel does not compile (TPU_REFUSAL below: the one
+chip run that tried it, PR 21). The row-blocked (1, T) operand blocks
+are refused by the Pallas TPU lowering before Mosaic runs; behind
+that check wait whole flat streams as single VMEM blocks and
+lax.sort/top_k/dynamic slices in the kernel body. Serving therefore
+refuses `search.tpu_serving.kernel.pallas=true` on a TPU backend at node
+start (require_servable) instead of degrading every query to the planner.
 
 Operands, outputs, gates and semantics match
 sparse.sorted_merge_topk(variant="compressed") exactly; see ops/sparse.
@@ -32,28 +34,40 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from elasticsearch_tpu.ops import sparse
 
-try:  # pragma: no cover - exercised by presence, not by a branch test
-    from jax.experimental import pallas as pl
-    _PALLAS_IMPORT_ERROR = None
-except Exception as _e:  # pallas missing from this jaxlib build
-    pl = None
-    _PALLAS_IMPORT_ERROR = _e
+#: what the Pallas TPU lowering said when the compressed small index
+#: (32,768 docs, one shard) was prewarmed with kernel.pallas=true on a
+#: TPU v5 lite (jax 0.9.0, jaxlib 0.9.0, libtpu 0.0.34; PERF.md
+#: "Bring-up on the chip (PR 21)")
+TPU_REFUSAL = (
+    "The Pallas TPU lowering currently requires that the last two "
+    "dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the "
+    "overall array. Block spec for args[2] in pallas_call kernel at "
+    "elasticsearch_tpu/ops/pallas_merge.py has block shape "
+    "(Blocked(block_size=1), Blocked(block_size=16)), array shape (8, 16)")
+
+
+def require_servable() -> None:
+    """Raise where variant="pallas" cannot serve: on a TPU backend the
+    kernel is refused at lowering, and the serving path would answer
+    that refusal from the planner with HTTP 200 on every query."""
+    if jax.default_backend() == "tpu":
+        from elasticsearch_tpu.common.errors import IllegalArgumentException
+        raise IllegalArgumentException(
+            "search.tpu_serving.kernel.pallas=true cannot be served on a "
+            "TPU backend: the kernel does not compile there. "
+            + TPU_REFUSAL)
+
 
 #: names and order of the optional operands the kernel may receive after
 #: the six required ones; absent operands are simply not passed
 _OPTIONAL_OPERANDS = ("flat_rank", "res_starts", "res_lens", "res_vals",
                       "block_max", "blk_starts", "slot_terms",
                       "doc_bases", "dbs_starts", "dlo_starts")
-
-
-def available() -> bool:
-    """May variant="pallas" run in this process? False routes the
-    planner (and direct callers) to the plain compressed core — the
-    same typed-fallback style as the d_pad/weight gates."""
-    return pl is not None
 
 
 def fused_merge_topk(
@@ -94,13 +108,6 @@ def fused_merge_topk(
         "block_max": block_max, "blk_starts": blk_starts,
         "slot_terms": slot_terms, "doc_bases": doc_bases,
         "dbs_starts": dbs_starts, "dlo_starts": dlo_starts}
-    if pl is None:
-        # typed fallback — never error: the plain core computes the
-        # same bits this kernel would
-        return sparse._merge_topk_core(
-            flat_docs, flat_impact, starts, lengths, weights, min_count,
-            **core_kw, **optional)
-
     r, t_slots = starts.shape
     kk = min(k, t_slots * max_len)
 

@@ -27,16 +27,10 @@ SHARD_AXIS = "shards"
 
 
 def shard_map(body, *, mesh: Mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions: new jax exposes it top-level
-    with `check_vma`; 0.4.x has it under `jax.experimental` with the
-    older `check_rep` spelling. Replication checking stays off either
-    way (the kernels' collectives are hand-placed)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with replication checking off (the kernels'
+    collectives are hand-placed)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def factorize_2d(n: int) -> Tuple[int, int]:

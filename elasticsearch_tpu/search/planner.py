@@ -80,16 +80,11 @@ def choose_kernel_variant(d_pad: int,
     compressed pipeline — one kernel for gather, merge, in-kernel
     block-max skip and top-k, bit-identical to "compressed". It has the
     same packable() requirement, so the fallback chain stays typed:
-    pallas unavailable (jaxlib without the pallas extra) or weights not
-    packable → the same "compressed"/"compressed_exact" choice as
+    weights not packable → the same "compressed_exact" choice as
     pallas=False. Never errors."""
     if compressed:
         if sparse.packable(d_pad, weights):
-            if pallas:
-                from elasticsearch_tpu.ops import pallas_merge
-                if pallas_merge.available():
-                    return "pallas"
-            return "compressed"
+            return "pallas" if pallas else "compressed"
         return "compressed_exact"
     if enabled and sparse.packable(d_pad, weights):
         return "packed"

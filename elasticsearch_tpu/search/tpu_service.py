@@ -1610,7 +1610,7 @@ class FlatQueryResult:
 # values so steady-state serving NEVER re-compiles.
 #
 # r5 routing (replaces r4's try-then-retry tiering, whose ~1-per-train
-# validity retries each cost a full ~100ms launch): the HOST knows every
+# validity retries each cost a full launch): the HOST knows every
 # term's postings length at lowering time, so each query routes to the
 # smallest FULL-POSTINGS sort width that holds ALL its terms' rows —
 # phase-A run totals are then EXACT BM25 (no prefixes, no rescore, no
@@ -1641,7 +1641,8 @@ KERNEL_CONFIG = {"packed_sort": True,
                  # HBM bytes/doc, exact scores via residual tables,
                  # device-side block-max pruning. Default ON since PR 15
                  # (two rounds of parity sweeps + the SLO harness behind
-                 # it; real-chip soak tracked in README). Build-time:
+                 # it; chip_smoke.py holds its two variants to the numpy
+                 # reference on the chip). Build-time:
                  # toggling only affects packs built afterwards (the
                  # bench invalidates between phases). Incompressible
                  # packs (d_pad ≥ 2^16, non-finite impacts, > 65535
@@ -1649,10 +1650,11 @@ KERNEL_CONFIG = {"packed_sort": True,
                  # format (`search.tpu_serving.kernel.compressed_pack`).
                  "compressed_pack": True,
                  # pallas=True serves compressed packs through the fused
-                 # Pallas kernel (ops/pallas_merge) when available —
-                 # bit-identical to "compressed", same typed fallbacks.
-                 # Off by default until the real-chip Mosaic soak lands
-                 # (`search.tpu_serving.kernel.pallas`).
+                 # Pallas kernel (ops/pallas_merge) — bit-identical to
+                 # "compressed" under the interpreter. Off by default, and
+                 # refused at node start on a TPU backend: the Pallas TPU
+                 # lowering does not compile it (pallas_merge.TPU_REFUSAL;
+                 # `search.tpu_serving.kernel.pallas`).
                  "pallas": False}
 
 #: per-(kernel, variant) launch counters → es_tpu_kernel_variant_total
@@ -1775,7 +1777,8 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
             hot_idx.append(i)
         else:
             full_groups[b].append(i)
-    # a tiny group isn't worth its own ~100ms launch floor: fold it into
+    # a tiny group isn't worth its own launch's fixed cost (~100 ms in
+    # round 5's records, unmeasured on today's machine): fold it into
     # the next WIDER bucket when that bucket launches anyway (always
     # correct — wider holds everything; folding into an EMPTY wider
     # bucket would save nothing and widen the sort for nothing)
@@ -2136,6 +2139,24 @@ def _execute_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
 def _n_local_devices() -> int:
     import jax
     return len(jax.devices())
+
+
+def device_stamp(devices: Sequence[Any]) -> Dict[str, Any]:
+    """What is serving: platform and device_kind as jax reports them for
+    `devices`, plus the versions a chip record is only comparable
+    within. libtpu is None where the wheel is not installed."""
+    from importlib import metadata
+
+    import jax
+    import jaxlib
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = None
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu}
 
 
 # ---------------------------------------------------------------------------
@@ -2683,6 +2704,9 @@ class TpuSearchService:
                  placement: Optional[Dict[str, Any]] = None,
                  delta: Optional[Dict[str, Any]] = None):
         _ensure_compile_cache(compile_cache_dir)
+        if pallas:
+            from elasticsearch_tpu.ops import pallas_merge
+            pallas_merge.require_servable()
         KERNEL_CONFIG["packed_sort"] = bool(packed_sort)
         KERNEL_CONFIG["compressed_pack"] = bool(compressed_pack)
         KERNEL_CONFIG["pallas"] = bool(pallas)
@@ -2697,6 +2721,7 @@ class TpuSearchService:
         # the healthy-topology mesh: partial-mesh recovery shrinks
         # packs.mesh/batcher.mesh, full-mesh recovery restores THIS
         self.full_mesh = self.packs.mesh
+        self._device_stamp = device_stamp(list(self.full_mesh.devices.flat))
         self.stages = StageTimes()
         self.batcher.stages = self.stages
         # device fault domains: per-device wedge scoring, micro-probe
@@ -3246,8 +3271,11 @@ class TpuSearchService:
     def set_kernel_pallas(self, enabled: bool) -> None:
         """Flip the fused-Pallas serving variant at runtime (launch-time:
         the next lowering pass picks it up; choose_kernel_variant still
-        falls back to "compressed" when Pallas is unavailable or the
-        batch isn't packable)."""
+        falls back to "compressed_exact" when the batch isn't packable).
+        Refused on a TPU backend, where the kernel does not compile."""
+        if enabled:
+            from elasticsearch_tpu.ops import pallas_merge
+            pallas_merge.require_servable()
         KERNEL_CONFIG["pallas"] = bool(enabled)
 
     @property
@@ -3644,9 +3672,7 @@ class TpuSearchService:
             exact_variants: Tuple[str, ...] = ("compressed",
                                                "compressed_exact")
             if KERNEL_CONFIG["pallas"]:
-                from elasticsearch_tpu.ops import pallas_merge
-                if pallas_merge.available():
-                    exact_variants = ("pallas",) + exact_variants
+                exact_variants = ("pallas",) + exact_variants
         elif (KERNEL_CONFIG["packed_sort"]
                 and _sparse.packable(resident.pack.d_pad)):
             exact_variants = ("packed", "ref")
@@ -3765,10 +3791,12 @@ class TpuSearchService:
                 "stages": self.stages.snapshot()}
 
     def device_stats(self) -> Dict[str, Any]:
-        """The /_tpu/stats `devices` block: health registry view plus
-        the supervisor's mesh topology and shed set."""
+        """The /_tpu/stats `devices` block: the device stamp (platform,
+        device_kind, versions), health registry view plus the
+        supervisor's mesh topology and shed set."""
         sup = self.supervisor
         out: Dict[str, Any] = {
+            **self._device_stamp,
             "mesh_devices": sup.mesh_device_count,
             "mesh_devices_full": sup.full_device_count,
             "remeshes": sup.c_remeshes.count,
@@ -3807,35 +3835,35 @@ _cache_configured = False
 
 
 def _ensure_compile_cache(path: Optional[str] = None) -> None:
-    """Persistent XLA compilation cache (VERDICT r3 #3): keyed on disk so
-    a process restart reuses every serving-kernel compile instead of
-    paying the 30-80s first-compile again. Precedence: the
-    ES_TPU_JAX_CACHE_DIR env var (opt out with ''), then the caller's
-    `path` (a node passes `search.tpu_serving.compile_cache_dir` or a
-    directory under its data path), then ~/.cache. First caller wins —
-    jax holds ONE cache dir per process."""
+    """Persistent XLA compilation cache: a process restart replays every
+    serving-kernel compile instead of paying it again. Where
+    JAX_COMPILATION_CACHE_DIR is set jax reads the directory from it and
+    none is set here; otherwise the caller's `path` (a node passes
+    `search.tpu_serving.compile_cache_dir`), else `<checkout>/.jax_cache`.
+    First caller wins — jax holds ONE cache dir per process."""
     global _cache_configured
     if _cache_configured:
         return
     _cache_configured = True
     import os
 
+    import jax
+
     # shared with the seed_compile_cache exporter/importer so "the dir
     # the node compiles into" and "the dir the seeder packs/unpacks"
     # can never drift apart
-    from elasticsearch_tpu.tools.seed_compile_cache import \
-        compile_cache_dir
-    path = compile_cache_dir(path)
-    if not path:
-        return
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
+    from elasticsearch_tpu.tools.seed_compile_cache import (
+        CACHE_DIR_ENV, compile_cache_dir)
+    if not os.environ.get(CACHE_DIR_ENV):
+        path = compile_cache_dir(path)
+        try:
+            os.makedirs(path, exist_ok=True)
+        except OSError as exc:  # read-only checkout: serve uncached
+            logger.warning("persistent compile cache unavailable: %s", exc)
+            return
         jax.config.update("jax_compilation_cache_dir", path)
-        # persist anything over ~100ms: at small corpus scales individual
-        # serving signatures compile in 0.3-0.9s but the full prewarm
-        # table of them still costs minutes — all of it cacheable
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as exc:  # cache is an optimization, never fatal
-        logger.warning("persistent compile cache unavailable: %s", exc)
+    # persist anything over ~100ms: at small corpus scales individual
+    # serving signatures compile in 0.3-0.9s but the full prewarm
+    # table of them still costs minutes — all of it cacheable
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -45,18 +45,25 @@ BUNDLE_VERSION = 1
 GENERATION_ENV = "ES_TPU_CACHE_GENERATION"
 
 
-def compile_cache_dir(path: Optional[str] = None) -> Optional[str]:
-    """The node's persistent-compile-cache directory, by the SAME
-    precedence `_ensure_compile_cache` applies: ES_TPU_JAX_CACHE_DIR
-    (opt out with ''), then the caller's path, then ~/.cache. Returns
-    None when the env var opts out."""
-    env = os.environ.get("ES_TPU_JAX_CACHE_DIR")
-    if env is not None:
-        path = env
-    elif path is None:
-        path = os.path.join(os.path.expanduser("~"), ".cache",
-                            "elasticsearch_tpu", "jax_cache")
-    return path or None
+#: the standard jax variable; when set, jax reads the directory from it
+#: and the program sets none in code
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def compile_cache_dir(path: Optional[str] = None) -> str:
+    """The process's persistent-compile-cache directory, by the SAME
+    precedence `_ensure_compile_cache` applies: JAX_COMPILATION_CACHE_DIR,
+    then the caller's path (`search.tpu_serving.compile_cache_dir`), then
+    `<checkout>/.jax_cache` next to the package. The directory is part of
+    jax's cache key, so it must not follow `data_path`, `~` or a
+    temporary name: a cache that moves never hits."""
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return env
+    if path:
+        return path
+    package_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_root), ".jax_cache")
 
 
 def detect_generation() -> str:
@@ -195,8 +202,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     p_exp = sub.add_parser("export", help="pack a warm cache dir into "
                                           "a seed bundle")
     p_exp.add_argument("--cache-dir", default=None,
-                       help="cache dir to pack (default: the node's "
-                            "resolved compile-cache dir)")
+                       help="cache dir to pack (default: the directory "
+                            "a node compiles into)")
     p_exp.add_argument("--out", default="compile_cache_seed.tar.gz")
     p_exp.add_argument("--generation", default=None,
                        help="override the detected backend generation")
@@ -209,10 +216,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                        help="import despite a generation mismatch")
     args = parser.parse_args(argv)
 
-    cache_dir = compile_cache_dir(args.cache_dir)
-    if cache_dir is None:
-        raise SystemExit("cache dir resolved to '' (ES_TPU_JAX_CACHE_DIR "
-                         "opts out) — pass --cache-dir explicitly")
+    # an explicit --cache-dir names the directory to pack/unpack; without
+    # one the tool works on the directory a node would compile into
+    cache_dir = args.cache_dir or compile_cache_dir()
     if args.cmd == "export":
         manifest = export_bundle(cache_dir, args.out,
                                  generation=args.generation)

@@ -116,15 +116,77 @@ class TestGenerationKeying:
         monkeypatch.setenv(seed.GENERATION_ENV, "build-host/x/y")
         assert seed.detect_generation() == "build-host/x/y"
 
-    def test_cache_dir_precedence(self, tmp_path, monkeypatch):
-        monkeypatch.delenv("ES_TPU_JAX_CACHE_DIR", raising=False)
+    def test_cache_dir_precedence(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR, then the caller's path, then
+        <checkout>/.jax_cache — never data_path, ~ or a temp name."""
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        monkeypatch.delenv(seed.CACHE_DIR_ENV, raising=False)
         assert seed.compile_cache_dir("/x") == "/x"
-        assert seed.compile_cache_dir(None).endswith(
-            os.path.join("elasticsearch_tpu", "jax_cache"))
-        monkeypatch.setenv("ES_TPU_JAX_CACHE_DIR", "/env/dir")
+        assert seed.compile_cache_dir(None) == os.path.join(
+            checkout, ".jax_cache")
+        monkeypatch.setenv(seed.CACHE_DIR_ENV, "/env/dir")
         assert seed.compile_cache_dir("/x") == "/env/dir"
-        monkeypatch.setenv("ES_TPU_JAX_CACHE_DIR", "")
-        assert seed.compile_cache_dir("/x") is None
+        assert seed.compile_cache_dir(None) == "/env/dir"
+        # an empty variable is an unset one
+        monkeypatch.setenv(seed.CACHE_DIR_ENV, "")
+        assert seed.compile_cache_dir("/x") == "/x"
+
+
+class TestNodeCacheResolution:
+    """What a node does with the resolution: the directory does not
+    follow data_path, and the standard variable is left to jax."""
+
+    @pytest.fixture
+    def config_updates(self, monkeypatch):
+        """Re-arm the once-per-process cache set-up and record what it
+        would set, without touching this process's real cache."""
+        import jax
+
+        from elasticsearch_tpu.search import tpu_service
+        calls = []
+        monkeypatch.setattr(tpu_service, "_cache_configured", False)
+        monkeypatch.setattr(jax.config, "update",
+                            lambda name, value: calls.append((name, value)))
+        return calls
+
+    def test_two_data_paths_resolve_to_one_directory(
+            self, tmp_path, monkeypatch, config_updates):
+        from elasticsearch_tpu.node import Node
+        from elasticsearch_tpu.search import tpu_service
+        monkeypatch.delenv(seed.CACHE_DIR_ENV, raising=False)
+        resolved = []
+        for name in ("a", "b"):
+            monkeypatch.setattr(tpu_service, "_cache_configured", False)
+            del config_updates[:]
+            node = Node(str(tmp_path / name))
+            node.close()
+            resolved.append(dict(config_updates)
+                            ["jax_compilation_cache_dir"])
+        assert resolved[0] == resolved[1] == seed.compile_cache_dir()
+        assert str(tmp_path) not in resolved[0]
+
+    def test_explicit_setting_is_honoured(self, tmp_path, monkeypatch,
+                                          config_updates):
+        from elasticsearch_tpu.common.settings import Settings
+        from elasticsearch_tpu.node import Node
+        monkeypatch.delenv(seed.CACHE_DIR_ENV, raising=False)
+        want = str(tmp_path / "explicit_cache")
+        node = Node(str(tmp_path / "d"), settings=Settings.of(
+            {"search.tpu_serving.compile_cache_dir": want}))
+        node.close()
+        assert dict(config_updates)["jax_compilation_cache_dir"] == want
+
+    def test_env_dir_is_never_set_from_code(self, tmp_path, monkeypatch,
+                                            config_updates):
+        from elasticsearch_tpu.common.settings import Settings
+        from elasticsearch_tpu.node import Node
+        monkeypatch.setenv(seed.CACHE_DIR_ENV, str(tmp_path / "env_cache"))
+        node = Node(str(tmp_path / "d"), settings=Settings.of(
+            {"search.tpu_serving.compile_cache_dir": "/ignored"}))
+        node.close()
+        assert config_updates  # the set-up ran ...
+        assert "jax_compilation_cache_dir" not in dict(config_updates)
 
 
 class TestCli:
@@ -140,12 +202,6 @@ class TestCli:
                         "--generation", "g"])
         assert rc == 0
         assert "imported 3 artifact(s)" in capsys.readouterr().out
-
-    def test_main_refuses_opted_out_cache_dir(self, tmp_path,
-                                              monkeypatch):
-        monkeypatch.setenv("ES_TPU_JAX_CACHE_DIR", "")
-        with pytest.raises(SystemExit, match="opts out"):
-            seed.main(["export", "--out", str(tmp_path / "o.tar.gz")])
 
 
 # ---------------------------------------------------------------------
@@ -180,7 +236,7 @@ def test_seeded_node_pays_zero_live_compiles(tmp_path):
     # into the same path, and demand zero new artifacts.
     import shutil
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("ES_TPU_JAX_CACHE_DIR", None)
+    env.pop(seed.CACHE_DIR_ENV, None)
     cache = tmp_path / "node_cache"
     cache.mkdir()
 

@@ -688,43 +688,12 @@ class TestDeltaDocStream:
 @pytest.mark.pallas
 class TestPallasKernel:
     """variant="pallas" dispatch seams. Bitwise parity itself rides the
-    5-variant sweeps above ("pallas" is in COMPRESSED_VARIANTS); these
-    pin the availability gate and the typed fallback."""
+    5-variant sweeps above ("pallas" is in COMPRESSED_VARIANTS), which
+    run the kernel under interpret=True on the CPU mesh."""
 
     def test_pallas_in_variant_tuples(self):
         assert "pallas" in sparse.KERNEL_VARIANTS
         assert "pallas" in sparse.COMPRESSED_VARIANTS
-
-    def test_interpret_mode_selected_off_tpu(self):
-        import jax
-        from elasticsearch_tpu.ops import pallas_merge
-        # tier-1 runs on the CPU mesh: the wrapper must self-select
-        # interpret mode (a compiled Mosaic call would just fail here)
-        assert jax.default_backend() != "tpu"
-        assert isinstance(pallas_merge.available(), bool)
-
-    def test_fallback_without_pallas_bit_identical(self, seeded_np,
-                                                   monkeypatch):
-        """With pallas unavailable the wrapper must fall back to the
-        plain compressed core — never error — and compute the same
-        bits."""
-        from elasticsearch_tpu.ops import pallas_merge
-        monkeypatch.setattr(pallas_merge, "pl", None)
-        assert not pallas_merge.available()
-        d_pad = 300
-        flat_docs, flat_imp, ext = make_flat(seeded_np, 3, d_pad, 150)
-        rows = [[(ext[t][0], ext[t][1], 1.0 + t, t) for t in range(3)]]
-        # k=23 keeps this trace distinct from any cached pallas jit of
-        # the same shapes, so the fallback branch genuinely traces
-        pv, pd_, pt = run_kernel(flat_docs, flat_imp, rows, [1], d_pad,
-                                 23, with_totals=True, variant="pallas",
-                                 ext=ext)
-        rv, rd, rt = run_kernel(flat_docs, flat_imp, rows, [1], d_pad,
-                                23, with_totals=True, variant="ref")
-        np.testing.assert_array_equal(rv.view(np.uint32),
-                                      pv.view(np.uint32))
-        np.testing.assert_array_equal(rd, pd_)
-        np.testing.assert_array_equal(rt, pt)
 
     def test_pallas_totals_and_counts(self, seeded_np):
         d_pad = 500
