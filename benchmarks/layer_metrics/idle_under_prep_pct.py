@@ -1,0 +1,9 @@
+"""Device idle time, % of the traced window, while the launch thread's
+state said: it prepared a launch on the host (`batcher.prep`).
+The seven `idle_*` shares sum to `device_idle_pct` (esbench/hostspans.py)."""
+
+from esbench import hostspans
+
+
+def read(facts):
+    return hostspans.idle_share_pct(facts, ("batcher.prep",))

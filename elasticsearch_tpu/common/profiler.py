@@ -321,7 +321,15 @@ class DeviceProfiler:
                 self._evict_beyond(keep=self.max_sessions - 1,
                                    protect=target)
                 import jax
-                jax.profiler.start_trace(target)
+                # the Python tracer off, TraceMe level 1 on (as the
+                # benchmark records): a session on a loaded node holds
+                # the serving pipeline's own annotations (batcher.*,
+                # completer.*, gc.full) beside the device's ops, not
+                # every Python call of every request thread
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 1
+                jax.profiler.start_trace(target, profiler_options=options)
             except Exception as exc:
                 self.last_error = f"{type(exc).__name__}: {exc}"
                 return {"started": False, "error": self.last_error}

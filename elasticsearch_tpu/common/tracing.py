@@ -29,8 +29,10 @@ breakdown, same spirit as the per-shard search slowlog.
 from __future__ import annotations
 
 import contextlib
+import gc
 import logging
 import random
+import sys
 import threading
 import time
 import uuid
@@ -423,3 +425,211 @@ def extract_context(payload: Optional[Dict[str, Any]]
     if not payload:
         return None
     return parse_traceparent(payload.get("_trace"))
+
+
+# ---------------------------------------------------------------------------
+# stage timing on the profiler's clock
+#
+# One primitive for the serving pipeline's own time: wall seconds and the
+# thread's CPU seconds into a StageTimes (which lands the same dt on the
+# request's Span through `record_stage`), and a profiler annotation around
+# the block. Annotations are TraceMe events: they land on the host plane
+# of the same `.xplane.pb` that carries the device's `XLA Ops` line, so a
+# `jax.profiler` session shows the program's stages on the device's
+# clock. With no session an annotation checks one flag.
+# ---------------------------------------------------------------------------
+
+def _annotate(name: str, meta: Dict[str, Any]) -> Any:
+    """An entered `jax.profiler.TraceAnnotation`, or None in a process
+    that never imported jax (serving fronts): no session can run there,
+    and this module must not be what imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name, **meta)
+    annotation.__enter__()
+    return annotation
+
+
+class stage:
+    """Context manager: time the block on this thread's wall clock and,
+    unless `cpu=False`, its CPU clock into `stages` (anything with
+    StageTimes' `add`; None records nothing) under `name`, inside a
+    profiler annotation carrying `meta` (`annotate=False`: stage only,
+    for per-request blocks). `seconds` and `cpu_seconds` hold the
+    block's times after exit. Wall minus CPU of a block that never
+    blocks is time spent waiting for the GIL or the scheduler. The CPU
+    clock is a system call where the wall clock is not: a block that
+    runs once per request and needs no CPU reading leaves it out."""
+
+    __slots__ = ("stages", "name", "meta", "annotate", "cpu", "seconds",
+                 "cpu_seconds", "_t0", "_c0", "_annotation")
+
+    def __init__(self, stages: Any, name: str, annotate: bool = True,
+                 cpu: bool = True, **meta: Any):
+        self.stages = stages
+        self.name = name
+        self.meta = meta
+        self.annotate = annotate
+        self.cpu = cpu
+        self.seconds = 0.0
+        self.cpu_seconds: Optional[float] = None
+
+    def __enter__(self) -> "stage":
+        self._annotation = (_annotate(self.name, self.meta)
+                            if self.annotate else None)
+        # the CPU reading inside the wall reading at both ends, so that
+        # cpu_seconds <= seconds whatever the clocks' own cost
+        self._t0 = time.perf_counter()
+        if self.cpu:
+            self._c0 = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.cpu:
+            self.cpu_seconds = time.thread_time() - self._c0
+        self.seconds = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self.stages is not None:
+            self.stages.add(self.name, self.seconds, cpu=self.cpu_seconds,
+                            attributes=self.meta or None)
+        return False
+
+
+class ThreadStates:
+    """The named states of ONE thread: `switch` closes the current state
+    and opens the next at the same clock reading, so the states
+    partition the thread's time with no gap by construction. Each state
+    is a stage (`<prefix>.<state>`: wall, CPU, count) and a profiler
+    annotation. Only the owning thread may call it (its CPU clock is
+    the thread's own); from its first `switch` to `close` it is that
+    thread's `current_states()`, so code deep in the thread's call
+    stack switches its state without being handed it."""
+
+    __slots__ = ("stages", "prefix", "train", "state", "opened_at",
+                 "closed_at", "_meta", "_t0", "_c0", "_annotation")
+
+    def __init__(self, stages: Any, prefix: str):
+        self.stages = stages
+        self.prefix = prefix
+        #: the sequence number of the train the thread works on, 0 for
+        #: none: its owner sets it, and every state opened while it is
+        #: set carries it as `train`
+        self.train = 0
+        #: the open state's name, None before the first switch and
+        #: after close()
+        self.state: Optional[str] = None
+        #: perf_counter of the first switch and of close(): the span the
+        #: states partition
+        self.opened_at: Optional[float] = None
+        self.closed_at: Optional[float] = None
+        self._meta: Dict[str, Any] = {}
+        self._annotation = None
+        self._t0 = self._c0 = 0.0
+
+    def switch(self, state: Optional[str], **meta: Any) -> float:
+        """→ the `perf_counter` reading that closed the old state and
+        opened the new one (a caller that keeps a stage of its own over
+        the same boundary uses it, and the two agree exactly)."""
+        now, cpu = time.perf_counter(), time.thread_time()
+        if self.state is not None:
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+                self._annotation = None
+            if self.stages is not None:
+                self.stages.add(f"{self.prefix}.{self.state}",
+                                now - self._t0, cpu=cpu - self._c0,
+                                attributes=self._meta or None)
+        elif self.opened_at is None:
+            self.opened_at = now
+        if self.train:
+            meta["train"] = self.train
+        self.state, self._meta, self._t0, self._c0 = state, meta, now, cpu
+        if state is None:
+            self.closed_at = now
+            _tls.states = None
+        else:
+            _tls.states = self
+            self._annotation = _annotate(f"{self.prefix}.{state}", meta)
+        return now
+
+    def note(self, **meta: Any) -> None:
+        """Facts known only at the end of the current state (how many
+        queries a hold gathered): onto its annotation and its span."""
+        self._meta = {**self._meta, **meta}
+        if self._annotation is not None:
+            self._annotation.set_metadata(**meta)
+
+    def close(self) -> None:
+        self.switch(None)
+
+
+class _NoStates:
+    """What code that may run off a batcher thread (prewarm, escalation,
+    the synchronous path) switches: nothing."""
+
+    __slots__ = ()
+    state = None
+    train = 0
+
+    def switch(self, state: Optional[str], **meta: Any) -> float:
+        return time.perf_counter()
+
+    def note(self, **meta: Any) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+NO_STATES = _NoStates()
+
+
+def current_states() -> Any:
+    """The calling thread's open ThreadStates, or NO_STATES."""
+    return getattr(_tls, "states", None) or NO_STATES
+
+
+class GcWatch:
+    """Full (generation 2) collections of this process: a `gc.callbacks`
+    entry that returns at once for generations 0 and 1 and, for a full
+    collection, wraps it in a `gc.full` profiler annotation and counts
+    it. A full collection of a serving node's heap stops every Python
+    thread for as long as it runs; this is the only place the program
+    itself says so. Observes only: nothing of the collector is tuned."""
+
+    def __init__(self) -> None:
+        self.full_collections = 0
+        self.full_pause_seconds = 0.0
+        self.longest_pause_seconds = 0.0
+        self._t0 = 0.0
+        self._annotation: Any = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._annotation = _annotate("gc.full", {})
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        self.full_collections += 1
+        self.full_pause_seconds += dt
+        self.longest_pause_seconds = max(self.longest_pause_seconds, dt)
+
+    def install(self) -> None:
+        gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+    def stats(self) -> Dict[str, Any]:
+        return {"full_collections": self.full_collections,
+                "full_pause_seconds": round(self.full_pause_seconds, 4),
+                "longest_pause_ms":
+                    round(self.longest_pause_seconds * 1000.0, 3)}

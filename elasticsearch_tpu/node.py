@@ -20,6 +20,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 from urllib.parse import parse_qs, urlparse
 
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.indices.service import IndexService, IndicesService
 from elasticsearch_tpu.rest.controller import RestController
@@ -228,7 +229,11 @@ class Node:
         # tracing: per-request root spans + propagation through the
         # coordinator fan-out and the TPU batch pipeline (sample_rate=0,
         # the default, keeps the hostpath allocation-free)
-        from elasticsearch_tpu.common.tracing import Tracer
+        from elasticsearch_tpu.common.tracing import GcWatch, Tracer
+        # full collections stop every Python thread of the node: counted
+        # (/_tpu/stats → runtime.gc) and annotated on profiler traces
+        self.gc_watch = GcWatch()
+        self.gc_watch.install()
         self.tracer = Tracer(
             sample_rate=self.settings.get_float(
                 "search.tracing.sample_rate", 0.0),
@@ -618,7 +623,7 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                KERNEL_CONFIG, KERNEL_VARIANT_COUNTS)
+                KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
@@ -629,9 +634,17 @@ class Node:
             # es_tpu_kernel_variant_total{kernel=...,variant=...}
             for labels, counter in KERNEL_VARIANT_COUNTS.items():
                 yield ("kernel.variant", labels, counter)
-            for stage, seconds, count, ring in svc.stages.metrics_view():
+            # device programs dispatched, by launch path:
+            # es_tpu_kernel_launches_total{path=...}
+            for labels, counter in LAUNCH_COUNTS.items():
+                yield ("kernel.launches", labels, counter)
+            for stage, seconds, count, ring, cpu in \
+                    svc.stages.metrics_view():
                 lb = {"stage": stage}
                 yield ("search.tpu.stage_seconds", lb, seconds, "counter")
+                if cpu is not None:
+                    yield ("search.tpu.stage_cpu_seconds", lb, cpu,
+                           "counter")
                 yield ("search.tpu.stage_operations", lb, count,
                        "counter")
                 if ring is not None:
@@ -1044,6 +1057,7 @@ class Node:
         if self._closed:
             return
         self._closed = True
+        self.gc_watch.remove()
         self.refresher_active = False
         if self._refresher:
             self._refresher.cancel()
@@ -1117,6 +1131,17 @@ class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
 
     def _do(self):
+        # wall and (sampled) CPU seconds of the whole request on its
+        # thread, from after the headers are read to after the response
+        # is written (stages of the TPU serving path; none without it)
+        tpu = self.node.tpu_search
+        stages = tpu.stages if tpu is not None else None
+        with tracing.stage(stages, "rest_request", annotate=False,
+                           cpu=stages is not None
+                           and stages.sample_cpu("rest_request")):
+            self._respond(stages)
+
+    def _respond(self, stages):
         parsed = urlparse(self.path)
         params = {k: v[0] if v else "" for k, v in
                   parse_qs(parsed.query, keep_blank_values=True).items()}
@@ -1148,7 +1173,9 @@ class _Handler(BaseHTTPRequestHandler):
             # their device-result columns in one pass (no per-hit dicts
             # on the serving path); plain payloads serialize as before
             from elasticsearch_tpu.search.serializer import dumps_response
-            data = dumps_response(payload).encode("utf-8")
+            with tracing.stage(stages, "rest_render", annotate=False,
+                               cpu=False):
+                data = dumps_response(payload).encode("utf-8")
             ctype = "application/json; charset=UTF-8"
         self.send_response(status)
         self.send_header("Content-Type", ctype)

@@ -377,16 +377,18 @@ def hierarchical_top_k(score: jax.Array, k: int, block: int = 4096,
     kk = min(k, length)
     if split is None:
         split = jax.default_backend() == "tpu"
-    if not split or length <= block or kk >= block or length % block:
-        return jax.lax.top_k(score, kk)
-    n_blocks = length // block
-    k_b = min(kk, block)
-    v, p = jax.lax.top_k(score.reshape(r, n_blocks, block), k_b)
-    base = (jnp.arange(n_blocks, dtype=jnp.int32) * block)[None, :, None]
-    v = v.reshape(r, n_blocks * k_b)
-    p = (p + base).reshape(r, n_blocks * k_b)
-    vals, pos2 = jax.lax.top_k(v, kk)
-    return vals, jnp.take_along_axis(p, pos2, axis=1)
+    with jax.named_scope("block_topk"):
+        if not split or length <= block or kk >= block or length % block:
+            return jax.lax.top_k(score, kk)
+        n_blocks = length // block
+        k_b = min(kk, block)
+        v, p = jax.lax.top_k(score.reshape(r, n_blocks, block), k_b)
+        base = (jnp.arange(n_blocks, dtype=jnp.int32)
+                * block)[None, :, None]
+        v = v.reshape(r, n_blocks * k_b)
+        p = (p + base).reshape(r, n_blocks * k_b)
+        vals, pos2 = jax.lax.top_k(v, kk)
+        return vals, jnp.take_along_axis(p, pos2, axis=1)
 
 
 def _rank_decode(ranks: jax.Array, r_start: jax.Array, r_len: jax.Array,
@@ -412,12 +414,13 @@ def segmented_run_sum(sk: jax.Array, sv: jax.Array,
     length = sk.shape[1]
     total = sv
     step = 1
-    while step < t_window:
-        shifted_t = jnp.pad(total, ((0, 0), (step, 0)))[:, :length]
-        shifted_k = jnp.pad(sk, ((0, 0), (step, 0)),
-                            constant_values=-1)[:, :length]
-        total = total + jnp.where(shifted_k == sk, shifted_t, 0.0)
-        step *= 2
+    with jax.named_scope("run_sum"):
+        while step < t_window:
+            shifted_t = jnp.pad(total, ((0, 0), (step, 0)))[:, :length]
+            shifted_k = jnp.pad(sk, ((0, 0), (step, 0)),
+                                constant_values=-1)[:, :length]
+            total = total + jnp.where(shifted_k == sk, shifted_t, 0.0)
+            step *= 2
     return total
 
 
@@ -523,7 +526,8 @@ def _merge_topk_core(
         return (jax.lax.dynamic_slice(flat_docs, (s,), (max_len,)),
                 jax.lax.dynamic_slice(flat_impact, (s,), (max_len,)))
 
-    docs, imps = jax.vmap(jax.vmap(slice_one))(starts)     # [R, T, L]
+    with jax.named_scope("gather_streams"):
+        docs, imps = jax.vmap(jax.vmap(slice_one))(starts)     # [R, T, L]
     valid = idx[None, None, :] < lengths[:, :, None]
     if compressed:
         if doc_bases is not None:
@@ -657,14 +661,17 @@ def _merge_topk_core(
         # (d_pad, code 0) and sort to the tail like the reference.
         key = ((docs.astype(jnp.uint32) << 16)
                | impact_code16(imp)).reshape(r, length)
-        sk_key = jax.lax.sort(key)
+        with jax.named_scope("merge_sort"):
+            sk_key = jax.lax.sort(key)
         sk = (sk_key >> 16).astype(jnp.int32)
         # decoded codes are LOWER bounds of the exact lane impacts, so
         # total>0 tests and candidate ordering are conservative
         sv = decode_code16(sk_key & jnp.uint32(0xFFFF))
     else:
-        sk, sv = jax.lax.sort(
-            [docs.reshape(r, length), imp.reshape(r, length)], num_keys=1)
+        with jax.named_scope("merge_sort"):
+            sk, sv = jax.lax.sort(
+                [docs.reshape(r, length), imp.reshape(r, length)],
+                num_keys=1)
 
     total = segmented_run_sum(sk, sv, t_window)
 
@@ -708,8 +715,9 @@ def _merge_topk_core(
             sk, score, cnt, kk, max_len=max_len, d_pad=d_pad,
             t_window=t_window, res=res, delta=delta)
     else:
-        vals, pos = jax.lax.top_k(score, kk)
-        hit_docs = jnp.take_along_axis(sk, pos, axis=1)
+        with jax.named_scope("block_topk"):
+            vals, pos = jax.lax.top_k(score, kk)
+            hit_docs = jnp.take_along_axis(sk, pos, axis=1)
         hit_docs = jnp.where(vals > NEG_INF, hit_docs, d_pad)
     if with_totals:
         return vals, hit_docs, totals

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.index.pack import LANE, _pad_to
 from elasticsearch_tpu.index.segment import Segment
 from elasticsearch_tpu.ops import sparse
@@ -54,6 +55,17 @@ NEG_INF = float("-inf")
 # hardware anyway — so holding this lock across enqueue costs nothing
 # in steady state while making cross-thread launches safe.
 DEVICE_DISPATCH_LOCK = threading.Lock()
+
+
+def _named(fn, name: str):
+    """`fn` under the name a profiler trace shows: jax.jit names a
+    program `jit_<__name__>` on the device's `XLA Modules` line, so the
+    makers below name what they jit after the launch path and its static
+    width instead of leaving every program `jit_body`."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 CHUNK_CAP = 4096  # max postings chunk per slot; flat arrays pad by this much
 FUSE_ROWS = 8     # max segment rows fused into one phase-A sort pool
 # phase-A gather/sort element budget per fused group (× ~8 bytes × a
@@ -720,7 +732,6 @@ def make_local_search(*, max_len: int, d_pad: int, p_pad: int, k: int,
     its XLA compile cache) instead of re-tracing per call."""
 
     if variant in sparse.COMPRESSED_VARIANTS:
-        @jax.jit
         def step(flat_docs, flat_impact, flat_rank, block_max, res_vals,
                  starts, lengths, weights, res_starts, res_lens,
                  slot_terms, min_count, doc_bases=None):
@@ -734,9 +745,8 @@ def make_local_search(*, max_len: int, d_pad: int, p_pad: int, k: int,
             top_vals, top_ids = _merge_topk(vals_b, gids_b, k, variant)
             return top_vals, top_ids, totals_b
 
-        return step
+        return jax.jit(_named(step, f"local_{variant}"))
 
-    @jax.jit
     def step(flat_docs, flat_impact, starts, lengths, weights, min_count):
         vals_b, gids_b, totals_b = _local_body(
             flat_docs, flat_impact, starts, lengths, weights, min_count,
@@ -746,7 +756,7 @@ def make_local_search(*, max_len: int, d_pad: int, p_pad: int, k: int,
         top_vals, top_ids = _merge_topk(vals_b, gids_b, k, variant)
         return top_vals, top_ids, totals_b
 
-    return step
+    return jax.jit(_named(step, f"local_{variant}"))
 
 
 @lru_cache(maxsize=64)
@@ -759,7 +769,7 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
     per device, then all_gather over "shards" + final top-k on device
     (SURVEY.md §5.8: the P3 reduce rides ICI). lru_cached by (mesh, bucket
     signature) so the query path hits the jit cache instead of re-tracing
-    every batch."""
+    every batch. The program is `jit_exact_<variant>` on a trace."""
 
     def tail(vals_b, gids_b, totals_b):
         all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1, tiled=True)
@@ -795,7 +805,7 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
                     + ((spec_post,) if delta else ()))
         mapped = shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        return jax.jit(mapped)
+        return jax.jit(_named(mapped, f"exact_{variant}"))
 
     def body(flat_docs, flat_impact, starts, lengths, weights, min_count):
         s_l = flat_docs.shape[0]
@@ -812,7 +822,7 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
         in_specs=(spec_post, spec_post, spec_sbt, spec_sbt, spec_sbt,
                   P(DATA_AXIS)),
         out_specs=out_specs)
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, f"exact_{variant}"))
 
 
 def prepare_term_ranges(pack: StackedShardPack,
@@ -870,8 +880,12 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
                        c_local: Optional[int] = None,
                        with_rescore: bool = True,
                        variant: str = "ref",
-                       pack_keys: bool = False):
-    """Block-max serving step, ONE fused launch (SURVEY.md §5.7/§7.3#3):
+                       pack_keys: bool = False,
+                       name: str = "pruned"):
+    """Block-max serving step, ONE fused launch (SURVEY.md §5.7/§7.3#3).
+    `name`: the launch path and its static width as the launch site
+    knows them (`full_s32`, `hot_c<prefix_cap>`); the program is
+    `jit_<name>` on a trace's `XLA Modules` line.
 
       phase A  candidate generation over impact-sorted postings prefixes
                (the small sorted-merge) → global top-c_cand via
@@ -971,7 +985,8 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
 
         def one_group(opnds):
             f_starts, f_lengths, f_weights, f_rows = opnds
-            docs, imps = jax.vmap(jax.vmap(slice_one))(f_starts)
+            with jax.named_scope("gather_streams"):
+                docs, imps = jax.vmap(jax.vmap(slice_one))(f_starts)
             valid = idx[None, None, :] < f_lengths[:, :, None]
             # gid key: row·(d_pad+1)+doc — distinct docs across rows
             # never merge; padded lanes carry impact 0, drop via total>0
@@ -989,16 +1004,18 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
                 gid_p = (grel[:, :, None] * (d_pad + 1)
                          + jnp.where(valid, docs, d_pad)).astype(jnp.uint32)
                 key = (gid_p << 16) | sparse.impact_code16(imp)
-                skp = jax.lax.sort(key.reshape(b, width))
+                with jax.named_scope("merge_sort"):
+                    skp = jax.lax.sort(key.reshape(b, width))
                 sk = ((skp >> 16).astype(jnp.int32)
                       + row0 * (d_pad + 1))
                 sv = sparse.decode_code16(skp & 0xFFFF)
             else:
                 gid = (f_rows[:, :, None] * (d_pad + 1)
                        + jnp.where(valid, docs, d_pad))
-                sk, sv = jax.lax.sort(
-                    [gid.reshape(b, width), imp.reshape(b, width)],
-                    num_keys=1)
+                with jax.named_scope("merge_sort"):
+                    sk, sv = jax.lax.sort(
+                        [gid.reshape(b, width), imp.reshape(b, width)],
+                        num_keys=1)
             total = sparse.segmented_run_sum(sk, sv, t_window)
             run_end = jnp.concatenate(
                 [sk[:, :-1] != sk[:, 1:], jnp.ones((b, 1), bool)],
@@ -1011,11 +1028,12 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
             # still takes the hierarchical top-k half of the packed
             # variant; selection and tie-breaks are provably identical
             # to lax.top_k
-            if variant == "packed":
-                vals_g, pos = sparse.hierarchical_top_k(score, k_dev)
-            else:
-                vals_g, pos = jax.lax.top_k(score, k_dev)
-            gid_g = jnp.take_along_axis(sk, pos, axis=1)
+            with jax.named_scope("block_topk"):
+                if variant == "packed":
+                    vals_g, pos = sparse.hierarchical_top_k(score, k_dev)
+                else:
+                    vals_g, pos = jax.lax.top_k(score, k_dev)
+                gid_g = jnp.take_along_axis(sk, pos, axis=1)
             return vals_g, gid_g, totals_g
 
         if n_groups == 1:
@@ -1120,11 +1138,12 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
         # ONE packed f32 output [B, 2k+3]: every extra output array is a
         # separate device→host fetch, so the whole result crosses in a
         # single transfer
-        gids_f32 = jax.lax.bitcast_convert_type(
-            out_gids.astype(jnp.int32), jnp.float32)
-        packed = jnp.concatenate(
-            [out_vals, gids_f32, totals[:, None].astype(jnp.float32),
-             cutoff[:, None], beta[:, None]], axis=1)
+        with jax.named_scope("pack_out"):
+            gids_f32 = jax.lax.bitcast_convert_type(
+                out_gids.astype(jnp.int32), jnp.float32)
+            packed = jnp.concatenate(
+                [out_vals, gids_f32, totals[:, None].astype(jnp.float32),
+                 cutoff[:, None], beta[:, None]], axis=1)
         return packed
 
     spec_post = P(SHARD_AXIS, None)
@@ -1133,7 +1152,7 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
         body, mesh=mesh,
         in_specs=(spec_post, spec_post, spec_post, spec_post, spec_sbt),
         out_specs=P(DATA_AXIS, None))
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, name))
 
 
 def unpack_pruned(packed: np.ndarray, k_keep: Optional[int] = None):
@@ -1212,33 +1231,26 @@ def distributed_search_raw(pack: StackedShardPack, batch: QueryBatch,
         raise ValueError(
             "compressed variant needs a batch prepared with "
             "compressed= streams (res_starts/res_lens/slot_terms)")
+    # on a batcher's launch thread: the same three-way split of dispatch
+    # as the pruned path's
+    states = tracing.current_states()
+    states.switch("lock")
     with DEVICE_DISPATCH_LOCK:
+        states.switch("put")
+        query_arrays = [batch.starts, batch.lengths, batch.weights]
         if compressed:
-            if delta:
-                (flat_docs, code16, rank16, block_max, res_vals,
-                 doc_bases) = device_arrays
-                bases = (doc_bases,)
-            else:
-                flat_docs, code16, rank16, block_max, res_vals = \
-                    device_arrays
-                bases = ()
-            vals, ids, totals = fn(flat_docs, code16, rank16, block_max,
-                                   res_vals,
-                                   jax.device_put(batch.starts, sbt),
-                                   jax.device_put(batch.lengths, sbt),
-                                   jax.device_put(batch.weights, sbt),
-                                   jax.device_put(batch.res_starts, sbt),
-                                   jax.device_put(batch.res_lens, sbt),
-                                   jax.device_put(batch.slot_terms, sbt),
-                                   jax.device_put(batch.min_count, db),
-                                   *bases)
-        else:
-            flat_docs, flat_impact = device_arrays
-            vals, ids, totals = fn(flat_docs, flat_impact,
-                                   jax.device_put(batch.starts, sbt),
-                                   jax.device_put(batch.lengths, sbt),
-                                   jax.device_put(batch.weights, sbt),
-                                   jax.device_put(batch.min_count, db))
+            query_arrays += [batch.res_starts, batch.res_lens,
+                             batch.slot_terms]
+        query_ops = [jax.device_put(a, sbt) for a in query_arrays]
+        query_ops.append(jax.device_put(batch.min_count, db))
+        # the delta form's per-block doc bases (a 6th pack array) go
+        # after the query operands
+        n_pack = 5 if compressed else 2
+        states.switch("call", path=f"exact_{variant}",
+                      rows=int(batch.starts.shape[1]))
+        vals, ids, totals = fn(*device_arrays[:n_pack], *query_ops,
+                               *device_arrays[n_pack:])
+    states.switch("prep")
     if not materialize:
         return vals, ids, totals
     return np.asarray(vals), np.asarray(ids), np.asarray(totals)
@@ -1405,7 +1417,7 @@ def make_distributed_knn(mesh: Mesh, *, d_pad: int, dims: int, k: int,
         in_specs=(P(SHARD_AXIS, None, None), P(SHARD_AXIS, None),
                   P(None, None)),
         out_specs=(P(None, None), P(None, None)))
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, f"knn_{similarity}"))
 
 
 def distributed_knn(pack: StackedVectorPack, queries: np.ndarray, k: int,
@@ -1511,7 +1523,7 @@ def make_term_sharded_search(mesh: Mesh, *, n_docs_pad: int, k: int):
         in_specs=(P(SHARD_AXIS, None, None), P(SHARD_AXIS, None, None),
                   P(SHARD_AXIS, None, None), P(SHARD_AXIS, None, None)),
         out_specs=(P(None, None), P(None, None)))
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, "term_sharded"))
 
 
 def term_sharded_search(mesh: Mesh, term_docs: np.ndarray,
@@ -1580,7 +1592,7 @@ def make_split_row_topk(mesh: Mesh, *, block: int, k: int,
         in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None),
                   P(SHARD_AXIS, None)),
         out_specs=(P(None), P(None)))
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, "split_row_topk"))
 
 
 def split_row_topk(mesh: Mesh, row_docs: np.ndarray,

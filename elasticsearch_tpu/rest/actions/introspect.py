@@ -372,6 +372,10 @@ def register(controller: RestController, node) -> None:
             out["merge"] = merge_status()
         if profiler is not None:
             out["profiler"] = profiler.info()
+        gc_watch = getattr(node, "gc_watch", None)
+        if gc_watch is not None:
+            # what stops every Python thread of the process at once
+            out["runtime"] = {"gc": gc_watch.stats()}
         return 200, out
 
     def do_tpu_traces(req: RestRequest):

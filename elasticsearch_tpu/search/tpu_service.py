@@ -34,6 +34,7 @@ Three pieces:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import threading
 import time
@@ -67,6 +68,12 @@ class StageTimes:
     per-query truth."""
 
     RING_SIZE = 512
+    #: per-request stages read the thread's CPU clock for one request in
+    #: this many. The wall clock is read in user space; the CPU clock is
+    #: a system call, and under a sandboxed kernel each one cost about
+    #: 0.5% of `qps` at saturation when every request made it (PERF.md,
+    #: Findings, PR 25). Per-train stages read it every time.
+    CPU_SAMPLE_EVERY = 16
 
     def __init__(self):
         from elasticsearch_tpu.common.metrics import SampleRing
@@ -74,12 +81,33 @@ class StageTimes:
         self._lock = threading.Lock()
         self.seconds: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
+        #: thread CPU seconds (`time.thread_time()` differences of the
+        #: thread that did the work) of the stages that report them
+        self.cpu_seconds: Dict[str, float] = {}
+        #: how many of a stage's `counts` came with a CPU reading
+        self.cpu_counts: Dict[str, int] = {}
+        self._cpu_ticks: Dict[str, Any] = {}
         self._rings: Dict[str, Any] = {}
 
-    def add(self, stage: str, dt: float, n: int = 1) -> None:
+    def sample_cpu(self, stage: str) -> bool:
+        """Whether this occurrence of a per-request `stage` should read
+        the CPU clock: the first, then one in CPU_SAMPLE_EVERY. No lock:
+        `setdefault` and `next` on a count are each one atomic step."""
+        ticks = self._cpu_ticks.get(stage)
+        if ticks is None:
+            ticks = self._cpu_ticks.setdefault(stage, itertools.count())
+        return next(ticks) % self.CPU_SAMPLE_EVERY == 0
+
+    def add(self, stage: str, dt: float, n: int = 1,
+            cpu: Optional[float] = None,
+            attributes: Optional[Dict[str, Any]] = None) -> None:
         with self._lock:
             self.seconds[stage] = self.seconds.get(stage, 0.0) + dt
             self.counts[stage] = self.counts.get(stage, 0) + n
+            if cpu is not None:
+                self.cpu_seconds[stage] = \
+                    self.cpu_seconds.get(stage, 0.0) + cpu
+                self.cpu_counts[stage] = self.cpu_counts.get(stage, 0) + n
             ring = self._rings.get(stage)
             if ring is None:
                 ring = self._rings[stage] = self._ring_cls(self.RING_SIZE)
@@ -90,7 +118,9 @@ class StageTimes:
                  exemplar=span.trace_id if span is not None else None)
         # the same dt the stats ring keeps also lands on the active trace
         # (no-op — one thread-local read — when the request isn't traced)
-        tracing.record_stage("tpu." + stage, dt, n=n)
+        if span is not None:
+            tracing.record_stage("tpu." + stage, dt, n=n,
+                                 **(attributes or {}))
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
@@ -98,6 +128,9 @@ class StageTimes:
             out = {s: {"seconds": round(self.seconds[s], 4),
                        "count": self.counts[s]}
                    for s in stages}
+            for s, cpu in self.cpu_seconds.items():
+                out[s]["cpu_seconds"] = round(cpu, 4)
+                out[s]["cpu_count"] = self.cpu_counts[s]
             rings = {s: self._rings.get(s) for s in stages}
         for s, ring in rings.items():
             if ring is None:
@@ -114,14 +147,15 @@ class StageTimes:
                 out[s]["exemplar_trace_id"] = exemplar
         return out
 
-    def metrics_view(self) -> List[Tuple[str, float, int, Any]]:
-        """(stage, total_seconds, count, ring) rows for the metrics
-        registry — the live ring OBJECTS, so the Prometheus summary
-        exports current quantiles and the completeness check can see
-        every ring is registered."""
+    def metrics_view(self) -> List[Tuple[str, float, int, Any,
+                                         Optional[float]]]:
+        """(stage, total_seconds, count, ring, cpu_seconds or None) rows
+        for the metrics registry — the live ring OBJECTS, so the
+        Prometheus summary exports current quantiles and the
+        completeness check can see every ring is registered."""
         with self._lock:
             return [(s, self.seconds[s], self.counts.get(s, 0),
-                     self._rings.get(s))
+                     self._rings.get(s), self.cpu_seconds.get(s))
                     for s in sorted(self.seconds)]
 
 
@@ -1140,6 +1174,11 @@ class _Pending:
     t_cycle: float = 0.0
     t_take: float = 0.0
     t_launched: float = 0.0
+    # the queue's sequence number of the train that took this query: the
+    # launch thread's and the completer's stages and annotations of that
+    # train, and a traced request's batch_launch/batch_finish spans,
+    # carry the same number
+    train: int = 0
     # owning tenant (stamped on the request thread): batch composition
     # takes weighted round-robin across tenant lanes so one tenant's
     # burst can't monopolize batch slots ahead of tenants already
@@ -1230,6 +1269,12 @@ class _PackQueue:
         # busy signal (the completer dequeues before materializing)
         self.n_inflight = 0
         self.inflight: Any = _queue.Queue(maxsize=self.PIPELINE_DEPTH)
+        self.train_seq = 0
+        # each thread's time, partitioned into named states (stages
+        # `batcher.*` / `completer.*` and profiler annotations)
+        self.launch_states = tracing.ThreadStates(batcher.stages, "batcher")
+        self.complete_states = tracing.ThreadStates(batcher.stages,
+                                                    "completer")
         self.completer = threading.Thread(target=self._complete,
                                           daemon=True,
                                           name="micro-batcher-complete")
@@ -1260,12 +1305,16 @@ class _PackQueue:
 
     def _run(self) -> None:
         batcher = self.batcher
+        states = self.launch_states
         try:
             while True:
                 retire = False
                 taken: List[_Pending] = []
+                states.train = train = 0
                 with self.cv:
                     idle_deadline = time.monotonic() + self.IDLE_EXIT_S
+                    if not self.pendings and not self.closed:
+                        states.switch("wait")
                     while not self.pendings and not self.closed:
                         remaining = idle_deadline - time.monotonic()
                         if remaining <= 0:
@@ -1289,6 +1338,7 @@ class _PackQueue:
                         # makes this train instead of fragmenting into
                         # the next one. An idle device pays only
                         # window_s — no refill, no latency floor.
+                        states.switch("hold")
                         deadline = time.monotonic() + batcher.window_s
                         waited_busy = False
                         # a HALF-full train launches even while the
@@ -1321,13 +1371,18 @@ class _PackQueue:
                                     0.05, batcher.window_s)
                                 continue
                             self.cv.wait(timeout=deadline - now)
+                        states.note(pending=len(self.pendings))
+                        states.switch("take")
                         taken, self.pendings = _take_fair(
                             self.pendings, batcher.max_batch,
                             batcher.tenant_weight)
+                        self.train_seq += 1
+                        states.train = train = self.train_seq
                         t_take = time.perf_counter()
                         for p in taken:
                             p.t_cycle = t_cycle
                             p.t_take = t_take
+                            p.train = train
                 if retire:
                     # NEVER hold cv while taking the batcher lock
                     # (submit's get/create path holds it before us)
@@ -1350,7 +1405,8 @@ class _PackQueue:
                     try:
                         with tracing.span_under(trace_parent,
                                                 "tpu.batch_launch",
-                                                queries=len(taken)):
+                                                queries=len(taken),
+                                                train=train):
                             st = launch_flat_batch(
                                 self.resident, [p.flat for p in taken],
                                 k=max(p.k for p in taken),
@@ -1364,6 +1420,7 @@ class _PackQueue:
                         if not p.future.done():
                             p.future.set_exception(exc)
                 else:
+                    states.switch("blocked")
                     t_launched = time.perf_counter()
                     for p in taken:
                         p.t_launched = t_launched
@@ -1374,15 +1431,21 @@ class _PackQueue:
                 finally:
                     profiler.tag_stage(None)
         finally:
+            states.close()
             self.inflight.put(None)  # stop the completer
 
     def _complete(self) -> None:
         batcher = self.batcher
+        states = self.complete_states
         while True:
+            states.train = 0
+            states.switch("wait")
             item = self.inflight.get()
             if item is None:
+                states.close()
                 return
             st, taken = item
+            states.train = train = taken[0].train
             trace_parent = next(
                 (p.trace_span for p in taken if p.trace_span), None)
             try:
@@ -1395,12 +1458,14 @@ class _PackQueue:
                 try:
                     with tracing.span_under(trace_parent,
                                             "tpu.batch_finish",
-                                            queries=len(taken)):
+                                            queries=len(taken),
+                                            train=train):
                         results = finish_flat_batch(st)
                 finally:
                     if wd is not None:
                         wd.end(token)
             except Exception as exc:  # noqa: BLE001 — per query
+                states.switch("deliver", queries=len(taken))
                 for p in taken:
                     if not p.future.done():
                         p.future.set_exception(exc)
@@ -1409,6 +1474,7 @@ class _PackQueue:
                     self.cv.notify_all()
                 profiler.tag_stage(None)
                 continue
+            states.switch("deliver", queries=len(taken))
             with batcher._lock:
                 batcher.batches_executed += 1
                 batcher.queries_executed += len(taken)
@@ -1660,6 +1726,11 @@ KERNEL_CONFIG = {"packed_sort": True,
 #: per-(kernel, variant) launch counters → es_tpu_kernel_variant_total
 KERNEL_VARIANT_COUNTS = LabeledCounters("kernel", "variant")
 
+#: device programs dispatched, by launch path and static width
+#: (`full_s32`, `hot_c<prefix_cap>`, `exact_<variant>`: the names the
+#: programs carry on a profiler trace) → es_tpu_kernel_launches_total
+LAUNCH_COUNTS = LabeledCounters("path")
+
 
 def _choose_exact_variant(resident: ResidentPack, batch) -> str:
     """Lowering-time variant pick for one exact-kernel launch (the
@@ -1756,7 +1827,10 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     and the exact subset (msm/AND, big k, many terms). Returns an
     opaque launch state for finish_flat_batch. JAX dispatch is
     asynchronous, so the caller can launch batch N+1 while batch N
-    executes on device (double-buffered serving; VERDICT r3 #1d)."""
+    executes on device (double-buffered serving; VERDICT r3 #1d).
+    On a batcher's launch thread the time spent here is its states
+    `prep`, then `lock`/`put`/`call` around each program's dispatch."""
+    tracing.current_states().switch("prep", queries=len(flats))
     if mesh is None:
         mesh = make_mesh(shape=(1, _n_local_devices()))
     # fault seam: DeviceWedge blocks here — BEFORE any lock or device
@@ -1809,7 +1883,11 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
 
 def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
     """Phase 2: materialize device results; residual tier-H validity
-    failures escalate to the deeper PREFIX_CAP3 prefix, then exact."""
+    failures escalate to the deeper PREFIX_CAP3 prefix, then exact.
+    On a batcher's completer thread the time spent here is its states
+    `device_wait` and `decode`, once per program (an escalation launches
+    from this thread, so its `prep`/`lock`/`put`/`call` are the
+    completer's too)."""
     resident, flats, k, mesh, stages = (st["resident"], st["flats"],
                                         st["k"], st["mesh"], st["stages"])
     out: List[Optional[FlatQueryResult]] = [None] * len(flats)
@@ -1949,6 +2027,7 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     if variant is None:
         variant = _choose_exact_variant(resident, batch)
     KERNEL_VARIANT_COUNTS.inc("exact", variant)
+    LAUNCH_COUNTS.inc(f"exact_{variant}")
     t_disp = time.perf_counter()
     vals, gids, totals = dist.distributed_search_raw(
         pack, batch, k_kernel, mesh, device_arrays=resident.device_arrays,
@@ -1966,10 +2045,13 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
 def _finish_exact(launch: Dict[str, Any],
                   stages: Optional[StageTimes] = None
                   ) -> List[FlatQueryResult]:
+    states = tracing.current_states()
+    states.switch("device_wait")
     t_dev = time.perf_counter()
     vals = np.asarray(launch["vals"])
     gids = np.asarray(launch["gids"])
     totals = np.asarray(launch["totals"])
+    states.switch("decode")
     if stages is not None:
         # variant-tagged: the bench's kernel_compare diffs these rings
         # per variant for device_ms_per_query
@@ -2010,6 +2092,13 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     k_cand = _candidate_k(k)
     k_out = 128 if k_cand == 128 else 1024
     b_bucket = _serving_bucket(len(flats))
+    # the launch path and its static width: the device program's name
+    # (`jit_<path>` on the trace's XLA Modules line) and the label of
+    # this launch's spans and counters
+    path = (f"full_s{full_slots}" if full_slots is not None
+            else f"hot_c{prefix_cap}")
+    states = tracing.current_states()
+    states.switch("prep", queries=len(flats), path=path, rows=b_bucket)
     if full_slots is not None:
         with_rescore = False
         k_cand = k_out  # exact totals: the candidate pool IS the result
@@ -2036,6 +2125,7 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
         variant = _pruned_variant()
     KERNEL_VARIANT_COUNTS.inc("full" if full_slots is not None
                               else "pruned", variant)
+    LAUNCH_COUNTS.inc(path)
     # single-key phase-A sort (PR 15): only when the batch's slot AND
     # rescore-term weights keep the 16-bit impact code monotone — the
     # group-size fit check is static inside make_pruned_search
@@ -2047,18 +2137,25 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
         c_cand=k_cand, k_out=k_out,
         t_window=max(_PRUNE_WINDOW, batch.window),
         t_terms=PRUNE_MAX_TERMS, with_rescore=with_rescore,
-        variant=variant, pack_keys=pack_keys)
+        variant=variant, pack_keys=pack_keys, name=path)
     from jax.sharding import NamedSharding, PartitionSpec as P
     from elasticsearch_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
     sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
     ops = dist.pack_pruned_operands(batch, t_starts, t_lengths, t_weights)
-    t_disp = time.perf_counter()
+    # dispatch, three ways: the wait for the process-wide dispatch lock,
+    # the host→device copy of the operands, and the jitted call (host
+    # dispatch plus whatever the runtime blocks on: donation holds, a
+    # full queue). `batch_dispatch` stays their sum.
+    t_disp = states.switch("lock")
     with dist.DEVICE_DISPATCH_LOCK:
+        states.switch("put")
+        ops_dev = jax.device_put(ops, sbt)
+        states.switch("call", path=path, rows=b_bucket)
         packed = fn(
             resident.imp_device_arrays[0], resident.imp_device_arrays[1],
             resident.device_arrays[0], resident.device_arrays[1],
-            jax.device_put(ops, sbt))
-    t_dev = time.perf_counter()
+            ops_dev)
+    t_dev = states.switch("prep")
     if stages is not None:
         stages.add("batch_prep", t_disp - t_prep)
         stages.add("batch_dispatch", t_dev - t_disp)
@@ -2078,10 +2175,13 @@ def _finish_pruned(launch: Dict[str, Any],
                           launch["k"])
     # one device→host transfer; split host-side (k derived from the
     # packed width — the kernel clamps k_out to its candidate pool)
+    states = tracing.current_states()
+    states.switch("device_wait")
     t_dev = time.perf_counter()
-    vals, gids, totals, cutoff, beta = dist.unpack_pruned(
-        np.asarray(launch["packed"]))
+    packed = np.asarray(launch["packed"])
     t_decode = time.perf_counter()
+    states.switch("decode")
+    vals, gids, totals, cutoff, beta = dist.unpack_pruned(packed)
     if stages is not None:
         stages.add("batch_device_wait", t_decode - t_dev)
         # variant-tagged sibling ring: kernel_compare reads per-variant
@@ -3323,7 +3423,13 @@ class TpuSearchService:
             self.supervisor.c_degraded_served.inc()
             self.supervisor.maybe_recover()
             return None
+        # `lower` keeps the request thread's CPU seconds beside its wall
+        # seconds (sampled): lowering never blocks, so wall minus CPU is
+        # time spent waiting for the GIL or the scheduler
+        cpu_clock = (time.thread_time if self.stages.sample_cpu("lower")
+                     else None)
         t0 = time.perf_counter()
+        c0 = cpu_clock() if cpu_clock else 0.0
         pkey = plan_key(query)
         cache_key = None
         if pkey is not None:
@@ -3331,7 +3437,8 @@ class TpuSearchService:
             cache_key = (index_service.name, gen, pkey)
         cached = self.plans.get(cache_key) if cache_key is not None else None
         if cached is NOT_LOWERABLE:
-            self.stages.add("lower", time.perf_counter() - t0)
+            cpu = cpu_clock() - c0 if cpu_clock else None
+            self.stages.add("lower", time.perf_counter() - t0, cpu=cpu)
             self.fallback += 1
             return None
         cached_rk = None
@@ -3342,9 +3449,11 @@ class TpuSearchService:
             if flat is None:
                 if cache_key is not None:
                     self.plans.put(cache_key, NOT_LOWERABLE)
-                self.stages.add("lower", time.perf_counter() - t0)
+                cpu = cpu_clock() - c0 if cpu_clock else None
+                self.stages.add("lower", time.perf_counter() - t0, cpu=cpu)
                 self.fallback += 1
                 return None
+        lower_cpu = cpu_clock() - c0 if cpu_clock else None
         t1 = time.perf_counter()
         with self._shed_lock:
             is_shed = (index_service.name, flat.field) in self._shed
@@ -3370,7 +3479,7 @@ class TpuSearchService:
             chain = self.packs.get_chain(index_service, flat.field)
             resident = None if chain is None else chain.base
         t2 = time.perf_counter()
-        self.stages.add("lower", t1 - t0)
+        self.stages.add("lower", t1 - t0, cpu=lower_cpu)
         self.stages.add("pack_get", t2 - t1)
         if resident is None:
             # field has no postings anywhere → zero hits, kernel-free
@@ -3520,10 +3629,8 @@ class TpuSearchService:
             "dispatch": max(0.0, t_l - t_t),
             "completion": max(0.0, t_done - t_l),
         }
-        variant = "packed" if KERNEL_CONFIG["packed_sort"] else "ref"
         for name, dt in split.items():
             self.stages.add(f"batch_wait.{name}", dt)
-            self.stages.add(f"batch_wait.{name}.{variant}", dt)
         return split
 
     @staticmethod
@@ -3784,6 +3891,7 @@ class TpuSearchService:
                                KERNEL_CONFIG["compressed_pack"],
                            "pallas": KERNEL_CONFIG["pallas"],
                            "variants": KERNEL_VARIANT_COUNTS.counts()},
+                "launches": LAUNCH_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
                 "supervision": self.supervisor.stats(),
                 "watchdog": self.watchdog.stats(),

@@ -213,9 +213,11 @@ class TestProfiledNodeSmoke:
         # same-thread clock anchors: parts sum to the aggregate (5% is
         # the acceptance bar; the construction makes it ~exact)
         assert parts == pytest.approx(total, rel=0.05)
-        # per-variant rings rode along
-        assert any(k.startswith("batch_wait.queue.")
-                   for k in stages), sorted(stages)
+        # the four parts, and no per-variant sibling of any of them
+        assert {k for k in stages if k.startswith("batch_wait.")} == {
+            f"batch_wait.{p}"
+            for p in ("queue", "window", "dispatch", "completion")
+        }, sorted(stages)
 
     def test_stats_and_prometheus_scrape(self, profiled_node):
         status, stats = _handle(profiled_node, "GET", "/_tpu/stats")
