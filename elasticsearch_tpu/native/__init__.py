@@ -34,7 +34,9 @@ def load(name: str):
         try:
             if (not os.path.exists(so)
                     or os.path.getmtime(so) < os.path.getmtime(src)):
-                tmp = so + ".tmp"
+                # a name of this process's own: test workers that build
+                # at once must not replace each other's half-written file
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     ["cc", "-O3", "-shared", "-fPIC", "-o", tmp, src],
                     check=True, capture_output=True, timeout=60)
@@ -54,7 +56,13 @@ def bind(lib_name: str, symbol: str, restype, argtypes):
     lib = load(lib_name)
     if lib is None:
         return None
-    fn = getattr(lib, symbol)
+    fn = getattr(lib, symbol, None)
+    if fn is None:
+        # a library left from an older source whose mtime says otherwise
+        # (a copied checkout): remove the .so to have it built again
+        logger.warning("native [%s] has no %s; using the python fallback",
+                       lib_name, symbol)
+        return None
     fn.restype = restype
     fn.argtypes = argtypes
     return fn

@@ -1169,13 +1169,15 @@ class _Handler(BaseHTTPRequestHandler):
             data = payload.encode("utf-8")
             ctype = "text/plain; charset=UTF-8"
         else:
-            # dumps_response renders embedded ColumnarHits blocks from
-            # their device-result columns in one pass (no per-hit dicts
-            # on the serving path); plain payloads serialize as before
-            from elasticsearch_tpu.search.serializer import dumps_response
+            # dumps_response_bytes renders embedded ColumnarHits blocks
+            # from their device-result columns (the metadata-only shape
+            # in one native call with the GIL released, no per-hit
+            # Python); plain payloads serialize as before
+            from elasticsearch_tpu.search.serializer import \
+                dumps_response_bytes
             with tracing.stage(stages, "rest_render", annotate=False,
                                cpu=False):
-                data = dumps_response(payload).encode("utf-8")
+                data = dumps_response_bytes(payload)
             ctype = "application/json; charset=UTF-8"
         self.send_response(status)
         self.send_header("Content-Type", ctype)

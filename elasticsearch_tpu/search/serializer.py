@@ -23,6 +23,15 @@ processes (`serving/front.py`): `encode_wire_response` splits the
 envelope around each hits block so the front splices the final bytes on
 its own core.
 
+The REST layer of the node itself goes one step further
+(`dumps_response_bytes`): the metadata-only block is written by ONE
+native call (`es_render_hits`) from the kernel's result columns and the
+pack's `EncodedIds` table, with the GIL released and no Python object
+per hit: no id is gathered or encoded and no score becomes a Python
+float on a request. Which path a block takes is decided from what the
+block is (shape flags, score dtype, table present, finite scores), and
+`RENDER_COUNTS` says how many took each.
+
 `ColumnarHits` is a lazy Sequence: in-process consumers (tests, ccs,
 rank_eval) that index or iterate it see ordinary hit dicts — built once,
 on first touch, via the same assembly loop the planner path uses — while
@@ -39,13 +48,25 @@ import dataclasses
 import json
 import os
 from collections.abc import Sequence
-from typing import Any, Dict, List, Optional, Tuple
+from json.encoder import encode_basestring_ascii
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-__all__ = ["ColumnarHits", "SplicedHits", "SpliceColumns",
-           "assemble_hits_list", "dumps_response", "hits_columns_from_dicts",
+from elasticsearch_tpu.common.metrics import LabeledCounters
+
+__all__ = ["ColumnarHits", "SplicedHits", "SpliceColumns", "EncodedIds",
+           "RENDER_COUNTS", "assemble_hits_list", "dumps_response",
+           "dumps_response_bytes", "hits_columns_from_dicts",
            "splice_hits_bytes", "encode_wire_response", "splice_wire"]
 
 _COMPACT = (",", ":")
+
+#: hits blocks rendered to their final bytes in this process, by path:
+#: `native` (es_render_hits: GIL released, no Python object per hit) or
+#: `python` (encoded columns + splicer, or plain json.dumps) →
+#: /_tpu/stats `render`
+RENDER_COUNTS = LabeledCounters("path")
+for _path in ("native", "python"):
+    RENDER_COUNTS.child(_path)  # both read 0, not absent, before a render
 
 
 def assemble_hits_list(name: str, resident, scores, rows, ords, source,
@@ -106,11 +127,12 @@ class SpliceColumns:
 
 
 _SPLICE_FN = None
+_RENDER_FN = None
 _SPLICE_TRIED = False
 
 
 def _native_splice():
-    global _SPLICE_FN, _SPLICE_TRIED
+    global _SPLICE_FN, _RENDER_FN, _SPLICE_TRIED
     if not _SPLICE_TRIED:
         _SPLICE_TRIED = True
         if not os.environ.get("ES_TPU_NO_NATIVE_SPLICE"):
@@ -120,7 +142,20 @@ def _native_splice():
                 [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
                  ctypes.POINTER(ctypes.c_int32), ctypes.c_char_p,
                  ctypes.c_int32, ctypes.c_char_p, ctypes.c_long])
+            _RENDER_FN = native.bind(
+                "response_splice", "es_render_hits", ctypes.c_long,
+                [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                 ctypes.c_void_p, ctypes.c_int64,
+                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                 ctypes.c_int32, ctypes.c_char_p, ctypes.c_long,
+                 ctypes.c_void_p, ctypes.c_long])
     return _SPLICE_FN
+
+
+def _native_render():
+    """es_render_hits, under the same switch as the splicer: None when
+    ES_TPU_NO_NATIVE_SPLICE is set or the library did not build."""
+    return _RENDER_FN if _native_splice() is not None else None
 
 
 def splice_hits_bytes(cols: SpliceColumns) -> str:
@@ -259,6 +294,55 @@ def hits_columns_from_dicts(hits: List[Dict[str, Any]]
         return None  # unserializable value — plain dumps raises the same
 
 
+@dataclasses.dataclass
+class EncodedIds:
+    """A pack's external ids as JSON literals, encoded once per pack.
+
+    `blob` holds `json.dumps(id)` of every id back to back (uint8),
+    `offsets` (int64[n + 1]) where each starts, indexed like the pack's
+    `id_cat`; `max_len` is the longest literal, for sizing an output
+    buffer without reading the table. Host memory only. numpy is
+    imported where it is used: the serving fronts import this module and
+    nothing but the standard library."""
+
+    blob: Any
+    offsets: Any
+    max_len: int
+
+    @classmethod
+    def build(cls, id_lists: Iterable[Sequence]) -> Optional["EncodedIds"]:
+        """One table over the concatenation of `id_lists` (a pack's
+        per-row id lists, in row order). None when an id is not a
+        string: such a pack renders through the Python path."""
+        import numpy as np
+        parts: List[str] = []
+        try:
+            for ids in id_lists:
+                parts.extend(map(encode_basestring_ascii, ids))
+        except TypeError:
+            return None
+        lengths = np.fromiter(map(len, parts), dtype=np.int64,
+                              count=len(parts))
+        offsets = np.zeros(len(parts) + 1, dtype=np.int64)
+        np.cumsum(lengths, out=offsets[1:])
+        blob = np.frombuffer("".join(parts).encode("ascii"), dtype=np.uint8)
+        return cls(blob, offsets, int(lengths.max(initial=0)))
+
+    @classmethod
+    def concat(cls, tables: Sequence[Optional["EncodedIds"]]
+               ) -> Optional["EncodedIds"]:
+        """The table of a chain of packs, or None when one has none."""
+        import numpy as np
+        if any(t is None for t in tables):
+            return None
+        offsets, end = [tables[0].offsets], tables[0].offsets[-1]
+        for t in tables[1:]:
+            offsets.append(t.offsets[1:] + end)
+            end += t.offsets[-1]
+        return cls(np.concatenate([t.blob for t in tables]),
+                   np.concatenate(offsets), max(t.max_len for t in tables))
+
+
 class ColumnarHits(Sequence):
     """Lazy hits block over kernel result columns.
 
@@ -326,13 +410,16 @@ class ColumnarHits(Sequence):
             return cols
         return hits_columns_from_dicts(self._materialize())
 
+    def _metadata_only(self) -> bool:
+        return (self.source is False and not self.version
+                and not self.seq_no_primary_term)
+
     def _fast_columns(self) -> Optional[SpliceColumns]:
         """Columns straight from the kernel result arrays — the
         metadata-only shape, no per-hit dict ever exists. None when this
         block needs the materialized path (_source / _version / seq_no,
         or non-string ids)."""
-        if not (self.source is False and not self.version
-                and not self.seq_no_primary_term):
+        if not self._metadata_only():
             return None
         if self.resident is None or len(self.scores) == 0:
             return SpliceColumns(0, "[]", "[]", "[]", [])
@@ -354,10 +441,54 @@ class ColumnarHits(Sequence):
         return splice_hits_bytes(cols)
 
     def to_json(self) -> str:
+        RENDER_COUNTS.inc("python")
         cols = self.splice_columns()
         if cols is not None:
             return splice_hits_bytes(cols)
         return json.dumps(self._materialize(), separators=_COMPACT)
+
+    def render_native(self) -> Optional[bytes]:
+        """The bytes of `to_json()` from one native call that runs with
+        the GIL released and touches no Python object per hit: ids come
+        from the resident's `EncodedIds`, scores are formatted in C. None
+        when the block is not the metadata-only shape over float32 scores,
+        was materialized (a consumer may have edited the dicts), its
+        resident has no id table, the library is absent or switched off,
+        or the C side refuses an input (a score that is not finite, a row
+        outside the tables): the caller renders it through `to_json`."""
+        if self._hits is not None or not self._metadata_only():
+            return None
+        table = getattr(self.resident, "id_json", None)
+        fn = _native_render()
+        if table is None or fn is None:
+            return None
+        import numpy as np
+        scores = self.scores
+        if scores.dtype != np.float32:
+            return None
+        scores = np.ascontiguousarray(scores)
+        rows = np.ascontiguousarray(self.rows, dtype=np.int32)
+        ords = np.ascontiguousarray(self.ords, dtype=np.int32)
+        row_offset = self.resident.row_offset
+        n = len(scores)
+        if (len(rows) != n or len(ords) != n
+                or row_offset.dtype != np.int64
+                or not row_offset.flags.c_contiguous):
+            return None
+        name = encode_basestring_ascii(self.name).encode("ascii")
+        # per hit: {"_index": ,"_id": ,"_score": } and a comma are 29
+        # bytes, a score is 25 at most
+        cap = 2 + n * (54 + len(name) + table.max_len)
+        out = np.empty(cap, dtype=np.uint8)
+        rc = fn(table.blob.ctypes.data, table.offsets.ctypes.data,
+                len(table.offsets) - 1, row_offset.ctypes.data,
+                len(row_offset), rows.ctypes.data, ords.ctypes.data,
+                scores.ctypes.data, n, name, len(name),
+                out.ctypes.data, cap)
+        if rc < 0:
+            return None
+        RENDER_COUNTS.inc("native")
+        return out[:rc].tobytes()
 
 
 class SplicedHits(Sequence):
@@ -396,6 +527,7 @@ class SplicedHits(Sequence):
         return hits_columns_from_dicts(self._hits)
 
     def to_json(self) -> str:
+        RENDER_COUNTS.inc("python")
         cols = self.splice_columns()
         if cols is not None:
             return splice_hits_bytes(cols)
@@ -430,6 +562,28 @@ def dumps_response(payload: Any) -> str:
     for token, block in blocks.items():
         text = text.replace(json.dumps(token), block.to_json())
     return text
+
+
+def dumps_response_bytes(payload: Any) -> bytes:
+    """`dumps_response(payload).encode()`, byte for byte, for the REST
+    layer: a block that `ColumnarHits.render_native` takes is written by
+    the native renderer, and the envelope is joined around the blocks as
+    bytes once, so no 60 KB body is decoded, searched and encoded again
+    under the GIL. Any other block renders through `to_json`."""
+    text, blocks = _tokenize(payload)
+    if not blocks:
+        return text.encode("utf-8")
+    out: List[bytes] = []
+    tail = text
+    for token, block in blocks.items():
+        pre, _, tail = tail.partition(json.dumps(token))
+        data = (block.render_native() if isinstance(block, ColumnarHits)
+                else None)
+        out.append(pre.encode("utf-8"))
+        out.append(block.to_json().encode("utf-8")
+                   if data is None else data)
+    out.append(tail.encode("utf-8"))
+    return b"".join(out)
 
 
 def encode_wire_response(payload: Any

@@ -51,6 +51,7 @@ from elasticsearch_tpu.ops import sparse
 from elasticsearch_tpu.parallel import distributed as dist
 from elasticsearch_tpu.parallel.mesh import SHARD_AXIS, make_mesh
 from elasticsearch_tpu.search import dsl
+from elasticsearch_tpu.search.serializer import RENDER_COUNTS, EncodedIds
 
 logger = logging.getLogger("elasticsearch_tpu.tpu_service")
 
@@ -369,6 +370,10 @@ class ResidentPack:
     row_shard: Optional[np.ndarray] = None    # int32[S_pad], -1 = padding
     row_offset: Optional[np.ndarray] = None   # int64[S_pad] into id_cat
     id_cat: Optional[np.ndarray] = None       # object[total_docs] ext ids
+    # the same ids as JSON literals, indexed like id_cat, encoded once
+    # here so that rendering a response encodes none (host memory; None
+    # when an id is not a string: the serializer then renders in Python)
+    id_json: Optional[EncodedIds] = None
     row_segments: Optional[List[Any]] = None  # row → Segment (pinned)
     # terms-tuple → _slots_needed result. The slot count depends only on
     # this pack's postings lengths, so the memo lives (and dies) with the
@@ -462,9 +467,10 @@ class _UnionView:
     """Read-only facade over base + delta packs presenting ONE
     concatenated row/id space to the fetch phase. Pack i's kernel rows
     re-base by ``offsets[i]`` (running sum of padded row counts); id
-    ordinals re-base via concatenated row_offset/id_cat tables. Exposes
-    exactly the members the serializer and columnar fetch consume
-    (resolve_ids / row_origin / row_segments / row_shard / readers)."""
+    ordinals re-base via concatenated row_offset/id_cat/id_json tables.
+    Exposes exactly the members the serializer and columnar fetch consume
+    (resolve_ids / id_json / row_origin / row_segments / row_shard /
+    readers)."""
 
     def __init__(self, packs: List[ResidentPack]):
         self.packs = tuple(packs)
@@ -494,6 +500,7 @@ class _UnionView:
         self.row_shard = np.concatenate(shard_parts)
         self.row_offset = np.concatenate(off_parts)
         self.id_cat = np.concatenate(id_parts)
+        self.id_json = EncodedIds.concat([p.id_json for p in self.packs])
         base = self.packs[0]
         self.pack = base.pack          # stats consumers see the base
         self.readers = base.readers
@@ -1094,7 +1101,9 @@ class IndexPackCache:
                                       else (imp_docs, imp_impacts)),
                             imp_device_arrays=imp_arrays,
                             row_shard=row_shard, row_offset=row_offset,
-                            id_cat=id_cat, row_segments=row_segments,
+                            id_cat=id_cat,
+                            id_json=EncodedIds.build(pack.shard_doc_ids),
+                            row_segments=row_segments,
                             comp_streams=streams, hbm_detail=hbm_detail,
                             group_mesh=(self.mesh if self.group_id
                                         is not None else None),
@@ -3892,6 +3901,7 @@ class TpuSearchService:
                            "pallas": KERNEL_CONFIG["pallas"],
                            "variants": KERNEL_VARIANT_COUNTS.counts()},
                 "launches": LAUNCH_COUNTS.counts(),
+                "render": RENDER_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
                 "supervision": self.supervisor.stats(),
                 "watchdog": self.watchdog.stats(),

@@ -228,3 +228,41 @@ def test_compaction_failure_keeps_chain_serving(svc, seeded_np):  # noqa: F811
             COMPACTION_FAULT_HOOKS.remove(boom)
         events_mod.set_recorder(prev)
         tpu.close()
+
+
+def test_delta_chain_renders_natively_from_the_concatenated_id_table(
+        svc, seeded_np, monkeypatch):  # noqa: F811
+    """A chain's view carries the packs' encoded ids concatenated, so a
+    metadata-only response over base + delta is rendered by the native
+    renderer, byte for byte what the Python path gives."""
+    import json
+
+    from elasticsearch_tpu.search import serializer
+    monkeypatch.setattr(serializer, "_SPLICE_TRIED", False)
+    monkeypatch.delenv("ES_TPU_NO_NATIVE_SPLICE", raising=False)
+    if serializer._native_render() is None:
+        pytest.skip("native renderer unavailable (no C toolchain)")
+    idx = make_corpus(svc, seeded_np, name="dpr", docs=40)
+    tpu = _tpu()
+    body = {"query": {"match": {"body": "alpha sigma"}}, "size": 100,
+            "_source": False}
+    try:
+        assert coordinator.search(svc, "dpr", dict(body), tpu_search=tpu)
+        new_ids = ['s"0', "s\\1", "sé2"]
+        for doc_id in new_ids:
+            idx.shard(idx.shard_for_id(doc_id)).apply_index_on_primary(
+                doc_id, {"body": "alpha sigma", "tag": "t9"})
+        idx.refresh()
+        resp = coordinator.search(svc, "dpr", dict(body), tpu_search=tpu)
+        assert tpu.delta_stats.appends == 1
+        view = resp["hits"]["hits"].resident
+        assert len(view.packs) == 2 and view.id_json is not None
+        assert len(view.id_json.offsets) == len(view.id_cat) + 1
+        native0 = serializer.RENDER_COUNTS.counts()["native"]
+        got = serializer.dumps_response_bytes(resp)
+        assert serializer.RENDER_COUNTS.counts()["native"] == native0 + 1
+        assert got == serializer.dumps_response(resp).encode("utf-8")
+        assert set(new_ids) <= {h["_id"]
+                                for h in json.loads(got)["hits"]["hits"]}
+    finally:
+        tpu.close()
