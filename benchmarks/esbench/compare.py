@@ -59,11 +59,20 @@ def compare_response(resp: Dict[str, Any], total: int, ref_ids: Sequence[str],
     return swaps
 
 
-def served_by_kernel(before: Dict[str, Any], after: Dict[str, Any], sent: int,
-                     chips: int, platform: str) -> List[str]:
-    """`/_tpu/stats` before and after a stream of `sent` requests → the
-    list of what shows that not every one of them was answered by the
-    kernel on the full mesh of the expected platform (empty = all were)."""
+def score_gap(resp: Dict[str, Any], ref_scores: Sequence[float]) -> float:
+    """The widest relative gap between a served score and the reference's
+    at its rank: the number `compare_response` holds to REL_TOL."""
+    return max((abs(hit["_score"] - float(r)) / abs(float(r))
+                for hit, r in zip(resp["hits"]["hits"], ref_scores) if float(r)),
+               default=0.0)
+
+
+def kernel_checks(before: Dict[str, Any], after: Dict[str, Any], sent: int,
+                  chips: int, platform: str) -> List[Tuple[str, Any, Any]]:
+    """`/_tpu/stats` before and after a stream of `sent` requests →
+    (name, got, want) of everything that has to hold for every one of them
+    to have been answered by the kernel on the full mesh of the expected
+    platform."""
     dev = after["devices"]
 
     def delta(*path: str) -> Any:
@@ -72,7 +81,7 @@ def served_by_kernel(before: Dict[str, Any], after: Dict[str, Any], sent: int,
             a, b = a[key], b[key]
         return a - b
 
-    checks: List[Tuple[str, Any, Any]] = [
+    return [
         ("served", delta("served"), sent),
         ("fallback", delta("fallback"), 0),
         ("timeouts", delta("timeouts"), 0),
@@ -88,5 +97,10 @@ def served_by_kernel(before: Dict[str, Any], after: Dict[str, Any], sent: int,
         ("supervision.state", after["supervision"]["state"], "serving"),
         ("supervision.recoveries", delta("supervision", "recoveries"), 0),
     ]
-    return [f"{name}={got!r} (want {want!r})"
-            for name, got, want in checks if got != want]
+
+
+def failures(checks: Sequence[Tuple[str, Any, Any]]) -> List[str]:
+    """→ what shows that not every request was answered by the kernel
+    (empty = all were)."""
+    return [f"{name}={got!r} (want {want!r})" for name, got, want in checks
+            if got != want]

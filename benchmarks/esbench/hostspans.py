@@ -140,7 +140,13 @@ def nested_seconds(inner: Sequence[Interval], outer: Sequence[Interval]) -> floa
 
 def load_host_lines(path: str) -> List[List[Event]]:
     """→ one list of (start_ns, end_ns, name) per host thread, holding
-    the program's annotations and the runtime's `Wait for ...` events."""
+    the program's annotations and the runtime's `Wait for ...` events
+    (read once per file: `run.py` names the gaps, then the readers come)."""
+    return _host_lines(path, os.path.getmtime(path))
+
+
+@lru_cache(maxsize=2)
+def _host_lines(path: str, _mtime: float) -> List[List[Event]]:
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     lines = []
@@ -157,6 +163,16 @@ def load_host_lines(path: str) -> List[List[Event]]:
             if events:
                 lines.append(sorted(events))
     return lines
+
+
+def pauses(path: str) -> List[Event]:
+    """The states of `PRECEDENCE` as `tracered.reduce_trace` wants its
+    pauses: on the trace's clock, in the order of precedence, named
+    without the `batcher.` prefix (`gc.full`, `call`, ..., `hold`, `wait`)."""
+    rank = {name: i for i, name in enumerate(PRECEDENCE)}
+    spans = [ev for line in load_host_lines(path) for ev in line if ev[2] in rank]
+    spans.sort(key=lambda ev: (rank[ev[2]], ev[0]))
+    return [(s, e, name.removeprefix("batcher.")) for s, e, name in spans]
 
 
 def module_name(event_name: str) -> str:

@@ -65,19 +65,26 @@ class Client:
         self.conn: Optional[http.client.HTTPConnection] = None
 
     def connect(self) -> None:
-        """Open the connection if there is none. The node listens with a
-        backlog of 5 (ThreadingHTTPServer's default), so a burst of
-        connects is partly refused: retry, spaced, for up to ~10 s.
-        Connecting is not part of any request's time."""
+        """Open the connection if there is none, and have the node answer
+        on it once. The node listens with a backlog of 5
+        (ThreadingHTTPServer's default): a SYN that finds it full is
+        dropped and sent again a second later, and connects made faster
+        than the node accepts them fill it (512 of them took 63 s, every
+        sixth a second long: PERF.md, PR 28). An answer shows that this
+        connection has been accepted, so a process that connects one by
+        one never has more than one waiting. A refused connect is tried
+        again, spaced, for up to ~10 s. Connecting is not part of any
+        request's time."""
         for _attempt in range(200):
             if self.conn is not None:
                 return
             conn = http.client.HTTPConnection("127.0.0.1", self.port,
                                               timeout=REQUEST_TIMEOUT_S)
             try:
-                conn.connect()
+                conn.request("GET", "/")
+                conn.getresponse().read()
                 self.conn = conn
-            except OSError:
+            except (OSError, http.client.HTTPException):
                 conn.close()
                 time.sleep(0.05)
 

@@ -120,9 +120,10 @@ def describe(path: str) -> List[str]:
 def reduce_trace(path: str, pauses: Sequence[Tuple[float, float, str]] = ()
                  ) -> Optional[Dict[str, Any]]:
     """→ busy_s, window_s (averaged over device planes), op seconds, op
-    counts, module seconds and the longest idle gaps, each named by a
-    host pause (`pauses`: start_ns, end_ns, name on the trace's clock)
-    that covers most of it, else `unattributed`."""
+    counts, module seconds and the ten longest idle gaps, each named by
+    the first host pause (`pauses`: start_ns, end_ns, name on the trace's
+    clock, in the order they are to be tried) that covers most of it,
+    else `unattributed`."""
     planes = load_device_events(path)
     planes = {n: l for n, l in planes.items() if l.get(OPS_LINE)}
     if not planes:
@@ -132,7 +133,7 @@ def reduce_trace(path: str, pauses: Sequence[Tuple[float, float, str]] = ()
     op_counts: Dict[str, int] = {}
     module_seconds: Dict[str, float] = {}
     module_counts: Dict[str, int] = {}
-    gaps: List[Tuple[float, str]] = []
+    gaps: List[Tuple[float, float, str]] = []
     lo = min(ev[0] for l in planes.values() for ev in l[OPS_LINE])
     hi = max(ev[1] for l in planes.values() for ev in l[OPS_LINE])
     for lines in planes.values():
@@ -146,13 +147,13 @@ def reduce_trace(path: str, pauses: Sequence[Tuple[float, float, str]] = ()
         for s, e, name in lines.get(MODULES_LINE, []):
             module_seconds[name] = module_seconds.get(name, 0.0) + (e - s) / 1e9
             module_counts[name] = module_counts.get(name, 0) + 1
-        for s, e, after in gaps_ns(ops, lo, hi):
-            cause = "unattributed"
-            for ps, pe, pname in pauses:
-                if min(e, pe) - max(s, ps) > 0.5 * (e - s):
-                    cause = pname
-                    break
-            gaps.append(((e - s) / 1e9, f"{cause} after {short_name(after, 48)}"))
+        gaps.extend(gaps_ns(ops, lo, hi))
+    longest = sorted(gaps, key=lambda g: g[0] - g[1])[:10]
+
+    def cause(s: float, e: float) -> str:
+        return next((name for ps, pe, name in pauses
+                     if min(e, pe) - max(s, ps) > 0.5 * (e - s)), "unattributed")
+
     n = len(planes)
     return {
         "busy_s": sum(busy) / n, "window_s": sum(window) / n,
@@ -161,5 +162,6 @@ def reduce_trace(path: str, pauses: Sequence[Tuple[float, float, str]] = ()
         "op_counts": op_counts,
         "module_seconds": {k: v / n for k, v in module_seconds.items()},
         "module_counts": module_counts,
-        "idle_gaps": sorted(gaps, reverse=True)[:10],
+        "idle_gaps": [((e - s) / 1e9, f"{cause(s, e)} after {short_name(after, 48)}")
+                      for s, e, after in longest],
     }

@@ -49,7 +49,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
 
-from esbench import compare, corpus, layers, tracered, traffic, window  # noqa: E402
+from esbench import compare, corpus, hostspans, layers, tracered, traffic, window  # noqa: E402
 from esbench.loadgen import now_ns, sleep_until  # noqa: E402
 from esbench.peaks import peaks_for  # noqa: E402
 
@@ -349,24 +349,33 @@ def measure(gens: Generators, conn: http.client.HTTPConnection, spec: Dict[str, 
     return out
 
 
-def check_samples(samples: Dict[int, bytes], ref: Any, k: int) -> Tuple[int, int, List[str]]:
-    """→ (checked, near-tie swaps, first few mismatches)."""
+def sample_queries(seed: int, n_queries: int) -> List[int]:
+    """The queries whose first in-window response a run of `seed` keeps."""
+    return np.random.default_rng([seed, 4]).choice(
+        n_queries, size=min(SAMPLE_QUERIES, n_queries), replace=False).tolist()
+
+
+def check_samples(samples: Dict[int, bytes], ref: Any, k: int
+                  ) -> Tuple[int, int, float, List[str]]:
+    """→ (checked, near-tie swaps, widest relative score gap, mismatches)."""
     ref_k = int(ref["k"])
     if k > ref_k:
         raise BenchFailure(f"size {k} is beyond the stored reference's {ref_k}")
-    swaps, bad = 0, []
+    swaps, gap, bad = 0, 0.0, []
     offsets, docs, scores, totals = (ref["offsets"], ref["docs"], ref["scores"],
                                      ref["totals"])
     for q, body in sorted(samples.items()):
         lo, hi = int(offsets[q]), int(offsets[q + 1])
+        ref_scores = scores[lo:hi].tolist()
         try:
+            resp = json.loads(body)
+            gap = max(gap, compare.score_gap(resp, ref_scores))
             swaps += compare.compare_response(
-                json.loads(body), int(totals[q]),
-                [corpus.doc_id(d) for d in docs[lo:hi].tolist()],
-                scores[lo:hi].tolist(), k)
-        except (compare.Mismatch, KeyError, ValueError) as exc:
+                resp, int(totals[q]),
+                [corpus.doc_id(d) for d in docs[lo:hi].tolist()], ref_scores, k)
+        except (compare.Mismatch, KeyError, ValueError, TypeError) as exc:
             bad.append(f"query {q}: {exc}")
-    return len(samples), swaps, bad[:5]
+    return len(samples), swaps, gap, bad
 
 
 def facts_of(m: Dict[str, Any], seconds: float, setup: Dict[str, float],
@@ -589,8 +598,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                              np.diff(qnpz["offsets"]), compiles))
 
         # ---- the stream(s) -------------------------------------------------
-        sample = np.random.default_rng([args.seed, 4]).choice(
-            n_queries, size=min(SAMPLE_QUERIES, n_queries), replace=False).tolist()
+        sample = sample_queries(args.seed, n_queries)
         plans = probe_plans(args.probe, spec) if args.probe else [(spec, args.seconds)]
         if args.trace or args.probe:
             gc.callbacks.append(gc_timer)
@@ -602,10 +610,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             line = result_line(m, loaded, plan_spec, seconds, setup, manifest, ref,
                                device, trace_dir, gc_timer, args)
             if args.probe:
+                full = [secs for at, secs, name in gc_timer.long_pauses(m["t0_ns"])
+                        if name == "gc_gen2" and at < seconds]
                 log("probe " + json.dumps({
                     "plan": [plan_spec.get("clients"), plan_spec.get("rate_per_s"),
                              seconds, plan_spec["ramp_s"]],
                     "settle_s": m["settle_s"],
+                    "gc_full_in_window": [len(full), sum(full)],
                     "gc_pauses_after_start": gc_timer.long_pauses(m["t_start_ns"]),
                     **line}))
     finally:
@@ -619,6 +630,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         node.close()
         compiles.close()
         shutil.rmtree(run_dir, ignore_errors=True)
+    for name, pair in line["compared"].items():
+        print(f"compared {name} {pair['value']!r} {pair['limit_is']} {pair['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 
@@ -632,12 +646,13 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
     # every request answered 200 was answered by the kernel, and the node
     # served no other: a refused or broken request never reached it
     sent, answered = int(m["ok"].shape[0]), int(m["ok"].sum())
-    problems = compare.served_by_kernel(m["before"], m["after"], answered,
-                                        int(loaded["cell"]["chips"]), device["platform"])
-    checked, swaps, bad = check_samples(m["samples"], ref, int(spec["size"]))
+    kernel = compare.kernel_checks(m["before"], m["after"], answered,
+                                   int(loaded["cell"]["chips"]), device["platform"])
+    problems = compare.failures(kernel)
+    checked, swaps, gap, bad = check_samples(m["samples"], ref, int(spec["size"]))
     if checked == 0:
         problems.append("no sampled response to check")
-    problems += bad
+    problems += bad[:5]
     if m["late_compiles"]:
         problems.append("compilations after the ramp began: " + json.dumps(
             [(n, round(s, 2)) for _t, n, s in m["late_compiles"]]))
@@ -645,13 +660,33 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
                                      m["loop"])
     for p in problems:
         log(f"NOT CORRECT: {p}")
+    # every number compared, beside its limit: (value, limit, which side of
+    # the limit holds); an exact comparison's limit is 0
+    served = next(got for name, got, _want in kernel if name == "served")
+    counters = {name: got for name, got, want in kernel
+                if type(want) is int and want == 0}
+    compared = {
+        "responses_sampled": (checked, 1, "at_least"),
+        "responses_differing": (len(bad), 0, "at_most"),
+        "score_rel_gap_max": (gap, compare.REL_TOL, "at_most"),
+        "answered_not_served": (answered - served, 0, "at_most"),
+        **{name: (got, 0, "at_most") for name, got in counters.items()},
+        "other_kernel_rules_broken": (sum(
+            got != want for name, got, want in kernel
+            if name != "served" and name not in counters), 0, "at_most"),
+        "compiles_after_ramp": (len(m["late_compiles"]), 0, "at_most"),
+    }
 
     reduced = None
     if trace_dir is not None:
         path = tracered.newest_xplane(trace_dir)
+        # the launch thread's states name the idle gaps (they share the
+        # trace's clock); the harness's own collector clock, laid on it to
+        # ~0.1 s, names what a program without annotations leaves
         pauses = [(at * 1e9, (at + secs) * 1e9, name) for at, secs, name
                   in gc_timer.long_pauses(m["trace_zero_ns"], 0.005)]
-        reduced = tracered.reduce_trace(path, pauses) if path else None
+        reduced = (tracered.reduce_trace(path, hostspans.pauses(path) + pauses)
+                   if path else None)
         if reduced is None and not args.rehearse:
             raise BenchFailure("the trace holds no device operation")
     facts = facts_of(m, seconds, setup, manifest, spec, reduced, device["kind"])
@@ -673,13 +708,13 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
                           f, indent=1)
 
     metrics: Dict[str, Dict[str, Any]] = {}
-    if args.trace:
+    if args.trace:  # a traced probe reports both kinds: one stream, every number
         for metric in loaded["per_layer"]:
             reader = layers.find_reader(metric["name"])
             value = reader(facts) if reader is not None else None
             if value is not None:
                 metrics[metric["name"]] = {"value": value, "unit": metric["unit"]}
-    else:
+    if not args.trace or args.probe:
         lat = window.latencies_ms(m["due_ns"], m["done_ns"], m["ok"], t0, t1)
         values = {
             "qps": window.completed_per_s(m["done_ns"], m["ok"], t0, t1),
@@ -696,6 +731,10 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
     out_device = dict(device, memory_peak_bytes=m["memory_peak_bytes"])
     log(f"stream: {sent} sent, {answered} answered 200, {checked} sampled responses "
         f"held to the reference ({swaps} near-tie swaps), window {counts}")
+    slices = np.histogram(m["done_ns"][m["ok"]],
+                          bins=np.arange(t0, t1 + 1, int(5e9)))[0]
+    log("completions a second in each 5 s of the window: "
+        + " ".join(f"{n / 5:.0f}" for n in slices))
     line: Dict[str, Any] = {"correct": not problems, **counts, "metrics": metrics,
                             "device": out_device}
     if reduced is not None:
@@ -705,6 +744,8 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
         line["breakdown"] = {
             "device_ops": [[tracered.short_name(n), s] for n, s in top],
             "idle_gaps": [[name, secs] for secs, name in reduced["idle_gaps"]]}
+    line["compared"] = {name: {"value": value, "limit": limit, "limit_is": side}
+                        for name, (value, limit, side) in compared.items()}
     if args.rehearse:
         # a rehearsal proves the command; nothing it timed is a measurement
         log("rehearsal, not a measurement: " + json.dumps(

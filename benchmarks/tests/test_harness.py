@@ -229,14 +229,18 @@ def _stats(served=0, fallback=0, timeouts=0, tripped=False, platform="tpu"):
             "supervision": {"state": "serving", "recoveries": 0}}
 
 
+def _served_by_kernel(*args):
+    return compare.failures(compare.kernel_checks(*args))
+
+
 def test_no_hidden_fallback_rule():
     before = _stats(served=100, fallback=3)            # warm-up's own history
-    assert compare.served_by_kernel(before, _stats(served=150, fallback=3), 50, 1, "tpu") == []
-    bad = compare.served_by_kernel(before, _stats(served=149, fallback=4), 50, 1, "tpu")
+    assert _served_by_kernel(before, _stats(served=150, fallback=3), 50, 1, "tpu") == []
+    bad = _served_by_kernel(before, _stats(served=149, fallback=4), 50, 1, "tpu")
     assert any(b.startswith("served=49") for b in bad) and any("fallback=1" in b for b in bad)
-    assert compare.served_by_kernel(before, _stats(served=150, fallback=3, tripped=True),
+    assert _served_by_kernel(before, _stats(served=150, fallback=3, tripped=True),
                                     50, 1, "tpu")
-    assert compare.served_by_kernel(before, _stats(served=150, fallback=3, platform="cpu"),
+    assert _served_by_kernel(before, _stats(served=150, fallback=3, platform="cpu"),
                                     50, 1, "tpu")
 
 
@@ -411,6 +415,28 @@ def test_every_workload_resolves_to_files_that_exist():
         assert cfg["guarantees"] and cfg["assumed"]
 
 
+def test_every_list_names_a_cell_and_every_cell_has_its_files():
+    """What a PR that adds a cell is refused for: a metric other than
+    `setup_s` with no `workloads` list, a list that names a cell that is
+    gone, a closed mix never warmed at its own client count."""
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        if m["name"] == "setup_s":      # every cell's: the driver refuses a list on it
+            assert "workloads" not in m
+            continue
+        assert m.get("workloads"), m["name"]
+        assert set(m["workloads"]) <= cells, m["name"]
+        assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
+    files = {c["name"]: c["file"] for c in BENCH["configs"]}
+    for w in BENCH["workloads"]:
+        assert os.path.isfile(os.path.join(ROOT, files[w["config"]])), w["name"]
+        path = os.path.join(BENCH_DIR, "traffic", w["traffic"] + ".json")
+        assert os.path.isfile(path), w["name"]
+        spec = traffic.load_traffic(path)
+        if spec["loop"] == "closed":
+            assert spec["warm_clients"][-1][0] == spec["clients"], w["traffic"]
+
+
 def test_a_fourth_cell_is_one_traffic_file_and_one_entry(tmp_path):
     """A later PR adds a cell with a traffic file and an entry in
     `workloads`, and edits no file that is there."""
@@ -425,7 +451,7 @@ def test_a_fourth_cell_is_one_traffic_file_and_one_entry(tmp_path):
     bench["workloads"].append({"name": name, "config": "msmarco-1chip",
                                "traffic": "or10-closed64", "chips": 1, "why": "throw-away"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m and "msmarco-1chip.or1000-closed384" in m["workloads"]:
+        if "msmarco-1chip.or1000-closed384" in m.get("workloads", []):
             m["workloads"].append(name)
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(bench))

@@ -205,6 +205,26 @@ def test_the_recorded_annotated_trace_gives_its_hand_count(tmp_path, monkeypatch
     assert layers.find_reader("dispatch_buffer_wait_ms.closed")(facts) >= 0.0
 
 
+def test_the_breakdowns_idle_gaps_carry_the_launch_threads_state():
+    """What `run.py` hands `tracered.reduce_trace` as `pauses`: the
+    ledger's `breakdown.idle_gaps` then name a state, not `unattributed`."""
+    path = os.path.join(TESTDATA, "annotated_trace.xplane.pb")
+    pauses = hostspans.pauses(path)
+    states = [n.removeprefix("batcher.") for n in hostspans.PRECEDENCE]
+    ranks = [states.index(n) for _s, _e, n in pauses]
+    assert ranks == sorted(ranks) and ranks[0] == 0      # precedence order, gc.full first
+    gaps = tracered.reduce_trace(path, pauses)["idle_gaps"]
+    assert len(gaps) == 10
+    assert [s for s, _n in gaps] == sorted((s for s, _n in gaps), reverse=True)
+    secs, name = gaps[0]
+    # the one long gap: 77 ms, most of it under the idle queue's `wait`
+    assert secs == pytest.approx(0.07700777) and name.startswith("wait after %")
+    assert {n.split(" after ")[0] for _s, n in gaps} <= set(states) | {"unattributed"}
+    bare = tracered.reduce_trace(path)["idle_gaps"]
+    assert [s for s, _n in bare] == [s for s, _n in gaps]
+    assert all(n.startswith("unattributed after ") for _s, n in bare)
+
+
 def _stage(seconds, count, cpu=None, cpu_count=None):
     out = {"seconds": seconds, "count": count}
     if cpu is not None:
@@ -260,8 +280,8 @@ def test_stage_readers_on_made_up_facts():
 
 def test_the_new_entries_only_add_to_the_benchmark():
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW_METRICS):] == [n + ".closed" for n in NEW_METRICS]
-    assert len(names) == 16 + len(NEW_METRICS) == len(set(names))
+    assert names[16:16 + len(NEW_METRICS)] == [n + ".closed" for n in NEW_METRICS]
+    assert len(names) >= 16 + len(NEW_METRICS) and len(names) == len(set(names))
     layers_named = {m["layer"] for m in BENCH["per_layer"]}
     assert "host (all Python threads)" in layers_named
     assert len(BENCH["workloads"]) == 1 and len(BENCH["configs"]) == 1

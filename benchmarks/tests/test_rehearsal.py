@@ -22,7 +22,7 @@ with open(os.path.join(ROOT, "BENCHMARK.json"), "r", encoding="utf-8") as _f:
     BENCH = json.load(_f)
 
 CELLS = [w["name"] for w in BENCH["workloads"]]
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
