@@ -623,7 +623,8 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS)
+                EXACT_ENTRY_COUNTS, KERNEL_CONFIG, KERNEL_VARIANT_COUNTS,
+                LAUNCH_COUNTS, ROUTE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
@@ -638,6 +639,14 @@ class Node:
             # es_tpu_kernel_launches_total{path=...}
             for labels, counter in LAUNCH_COUNTS.items():
                 yield ("kernel.launches", labels, counter)
+            # queries by the way the launch routing sent them, and the
+            # exact launches' posting entries, real and as dispatched:
+            # es_tpu_kernel_route_total{route=...},
+            # es_tpu_kernel_exact_entries_total{kind=...}
+            for labels, counter in ROUTE_COUNTS.items():
+                yield ("kernel.route", labels, counter)
+            for labels, counter in EXACT_ENTRY_COUNTS.items():
+                yield ("kernel.exact_entries", labels, counter)
             for stage, seconds, count, ring, cpu in \
                     svc.stages.metrics_view():
                 lb = {"stage": stage}

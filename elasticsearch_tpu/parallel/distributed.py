@@ -66,6 +66,14 @@ def _named(fn, name: str):
     return fn
 
 
+def exact_program_name(variant: str, rows: int, slots: int,
+                       t_window: int) -> str:
+    """The exact kernel's program under its static shape (batch rows,
+    slots a row, run-sum window): `jit_<this>` on a trace's `XLA Modules`
+    line, and the label of the launch's spans and counters."""
+    return f"exact_{variant}_b{rows}_s{slots}_w{t_window}"
+
+
 CHUNK_CAP = 4096  # max postings chunk per slot; flat arrays pad by this much
 FUSE_ROWS = 8     # max segment rows fused into one phase-A sort pool
 # phase-A gather/sort element budget per fused group (× ~8 bytes × a
@@ -759,17 +767,22 @@ def make_local_search(*, max_len: int, d_pad: int, p_pad: int, k: int,
     return jax.jit(_named(step, f"local_{variant}"))
 
 
-@lru_cache(maxsize=64)
+@lru_cache(maxsize=256)
 def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
                             p_pad: int, k: int, t_window: int,
                             with_counts: bool = False,
                             variant: str = "ref",
-                            delta: bool = False):
+                            delta: bool = False,
+                            name: Optional[str] = None):
     """SPMD search step over a (data, shards) mesh: local sorted-merge
     per device, then all_gather over "shards" + final top-k on device
     (SURVEY.md §5.8: the P3 reduce rides ICI). lru_cached by (mesh, bucket
     signature) so the query path hits the jit cache instead of re-tracing
-    every batch. The program is `jit_exact_<variant>` on a trace."""
+    every batch. The program is `jit_<name>` on a trace:
+    `distributed_search_raw` names it after its static shape
+    (`exact_program_name`), one jitted step a shape; without a name it
+    is `jit_exact_<variant>` whatever it is called with."""
+    name = name or f"exact_{variant}"
 
     def tail(vals_b, gids_b, totals_b):
         all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1, tiled=True)
@@ -805,7 +818,7 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
                     + ((spec_post,) if delta else ()))
         mapped = shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        return jax.jit(_named(mapped, f"exact_{variant}"))
+        return jax.jit(_named(mapped, name))
 
     def body(flat_docs, flat_impact, starts, lengths, weights, min_count):
         s_l = flat_docs.shape[0]
@@ -822,7 +835,7 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
         in_specs=(spec_post, spec_post, spec_sbt, spec_sbt, spec_sbt,
                   P(DATA_AXIS)),
         out_specs=out_specs)
-    return jax.jit(_named(mapped, f"exact_{variant}"))
+    return jax.jit(_named(mapped, name))
 
 
 def prepare_term_ranges(pack: StackedShardPack,
@@ -1221,10 +1234,12 @@ def distributed_search_raw(pack: StackedShardPack, batch: QueryBatch,
     elif t_window < batch.window:
         raise ValueError(f"t_window={t_window} < needed {batch.window}")
     delta = compressed and len(device_arrays) == 6
+    rows = int(batch.starts.shape[1])
+    name = exact_program_name(variant, rows, batch.t_slots, t_window)
     fn = make_distributed_search(
         mesh, max_len=batch.max_len, d_pad=pack.d_pad, p_pad=pack.p_pad,
         k=k, t_window=t_window, with_counts=with_counts, variant=variant,
-        delta=delta)
+        delta=delta, name=name)
     sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
     db = NamedSharding(mesh, P(DATA_AXIS))
     if compressed and batch.res_starts is None:
@@ -1246,8 +1261,7 @@ def distributed_search_raw(pack: StackedShardPack, batch: QueryBatch,
         # the delta form's per-block doc bases (a 6th pack array) go
         # after the query operands
         n_pack = 5 if compressed else 2
-        states.switch("call", path=f"exact_{variant}",
-                      rows=int(batch.starts.shape[1]))
+        states.switch("call", path=name, rows=rows)
         vals, ids, totals = fn(*device_arrays[:n_pack], *query_ops,
                                *device_arrays[n_pack:])
     states.switch("prep")

@@ -380,6 +380,9 @@ class ResidentPack:
     # pack — a rebuild starts fresh, no invalidation protocol needed.
     slots_memo: Dict[Tuple[str, ...], int] = dataclasses.field(
         default_factory=dict)
+    # number of terms → _widest_slots result, on the same terms
+    widest_slots_memo: Dict[int, int] = dataclasses.field(
+        default_factory=dict)
     # compressed resident format (PERF.md round 11): host-side 16-bit
     # streams + residual tables. When set, device_arrays is the 5-tuple
     # from device_put_compressed (6-tuple with the delta doc stream's
@@ -1399,6 +1402,21 @@ class _PackQueue:
                     return
                 if not taken:
                     continue
+                # the pipeline's depth, kept before the dispatch: at most
+                # PIPELINE_DEPTH trains launched and not yet finished. A
+                # train holds its results on the device from its dispatch
+                # until the completer has copied them, so this bounds that
+                # memory too, at a level a full pipeline reaches in every
+                # run; the queue's bound alone does not (the completer
+                # dequeues before it materializes, and `put` blocks after
+                # the dispatch), and the high-water mark then hangs on
+                # the size of a fourth and fifth train
+                with self.cv:
+                    if self.n_inflight >= self.PIPELINE_DEPTH:
+                        states.switch("blocked")
+                    while (self.n_inflight >= self.PIPELINE_DEPTH
+                           and not self.closed):
+                        self.cv.wait(timeout=0.25)
                 trace_parent = next(
                     (p.trace_span for p in taken if p.trace_span), None)
                 try:
@@ -1435,7 +1453,8 @@ class _PackQueue:
                         p.t_launched = t_launched
                     with self.cv:
                         self.n_inflight += 1
-                    # blocks when PIPELINE_DEPTH batches are in flight
+                    # never blocks: fewer than PIPELINE_DEPTH were in
+                    # flight when this train was dispatched
                     self.inflight.put((st, taken))
                 finally:
                     profiler.tag_stage(None)
@@ -1474,6 +1493,7 @@ class _PackQueue:
                     if wd is not None:
                         wd.end(token)
             except Exception as exc:  # noqa: BLE001 — per query
+                item = st = None
                 states.switch("deliver", queries=len(taken))
                 for p in taken:
                     if not p.future.done():
@@ -1483,6 +1503,9 @@ class _PackQueue:
                     self.cv.notify_all()
                 profiler.tag_stage(None)
                 continue
+            # the train's device results have been copied: let them go
+            # before the worker is told that it may launch the next
+            item = st = None
             states.switch("deliver", queries=len(taken))
             with batcher._lock:
                 batcher.batches_executed += 1
@@ -1735,10 +1758,29 @@ KERNEL_CONFIG = {"packed_sort": True,
 #: per-(kernel, variant) launch counters → es_tpu_kernel_variant_total
 KERNEL_VARIANT_COUNTS = LabeledCounters("kernel", "variant")
 
-#: device programs dispatched, by launch path and static width
-#: (`full_s32`, `hot_c<prefix_cap>`, `exact_<variant>`: the names the
-#: programs carry on a profiler trace) → es_tpu_kernel_launches_total
+#: device programs dispatched, by launch path and static shape
+#: (`full_s32`, `hot_c<prefix_cap>`,
+#: `exact_<variant>_b<rows>_s<slots>_w<window>`: the names the programs
+#: carry on a profiler trace) → es_tpu_kernel_launches_total
 LAUNCH_COUNTS = LabeledCounters("path")
+
+#: queries by the way `launch_flat_batch` sent them: the pruned groups as
+#: launched (after folding), the exact kernel by the first reason that
+#: holds, and `exact_escalated` for a pruned query whose validity bound
+#: failed twice (counted under its pruned route before)
+#: → es_tpu_kernel_route_total
+ROUTES = ("pruned_full_s32", "pruned_full_s128", "pruned_hot",
+          "exact_terms", "exact_min_count", "exact_k", "exact_no_impacts",
+          "exact_escalated")
+ROUTE_COUNTS = LabeledCounters("route")
+#: posting entries under the exact launches' slots (`real`) and the
+#: entries their static shape sorts, rows × slots × chunk length
+#: (`padded`) → es_tpu_kernel_exact_entries_total
+EXACT_ENTRY_COUNTS = LabeledCounters("kind")
+for _label in ROUTES:
+    ROUTE_COUNTS.child(_label)  # all read 0, not absent, before a query
+for _label in ("real", "padded"):
+    EXACT_ENTRY_COUNTS.child(_label)
 
 
 def _choose_exact_variant(resident: ResidentPack, batch) -> str:
@@ -1828,6 +1870,146 @@ def _full_bucket(slots: int) -> Optional[int]:
     return None
 
 
+# ---------------------------------------------------------------------------
+# the exact kernel's programs: a closed set
+# ---------------------------------------------------------------------------
+# Every static dimension of an exact launch is pinned to a member of a
+# small ladder, so the programs a pack can meet are few and can be listed
+# before any query arrives (`exact_program_set`): batch rows by
+# `_serving_bucket`, slots a row by `_exact_slot_pin`, the run-sum window
+# by `_exact_window`, the kernel's k by `_exact_k_kernel`, the chunk
+# length at CHUNK_CAP. `_launch_exact` pins with the same three functions
+# that the listing is made of.
+EXACT_MIN_SLOTS = 8
+#: the listing covers queries of up to this many terms
+EXACT_MAX_TERMS = 16
+
+
+def _exact_window(window: int) -> int:
+    """The run-sum window an exact launch compiles for: the next power
+    of two from _PRUNE_WINDOW that holds the widest query's terms.
+    `segmented_run_sum` doubles its step while it is below the window,
+    so every window in (w/2, w] runs the very same steps: the pin costs
+    nothing and changes no bit of the result."""
+    return dist._shape_bucket(window, _PRUNE_WINDOW)
+
+
+def _exact_slot_pin(t_slots: int, t_window: int) -> int:
+    """The slots a row an exact launch compiles for: powers of two from
+    EXACT_MIN_SLOTS; a launch that holds a query of more than
+    PRUNE_MAX_TERMS terms (window past _PRUNE_WINDOW) starts at the
+    pruned path's narrowest full width instead, so that such queries
+    meet one slot count where their terms would give two."""
+    return dist._shape_bucket(
+        t_slots, EXACT_MIN_SLOTS if t_window <= _PRUNE_WINDOW
+        else FULL_SLOT_BUCKETS[0])
+
+
+def _exact_k_kernel(k: int) -> int:
+    return 128 if k <= 128 else (1024 if k <= 1024
+                                 else _batch_bucket(k, 16384))
+
+
+def _exact_variants(resident: ResidentPack) -> Tuple[str, ...]:
+    """The exact kernel's variants a launch on this pack can pick
+    (`_choose_exact_variant` per launch: the setting is the ceiling,
+    the batch's weights decide between the two of a pair)."""
+    if resident.comp_streams is not None:
+        pair: Tuple[str, ...] = ("compressed", "compressed_exact")
+        return (("pallas",) + pair) if KERNEL_CONFIG["pallas"] else pair
+    if KERNEL_CONFIG["packed_sort"] and sparse.packable(resident.pack.d_pad):
+        return ("packed", "ref")
+    return ("ref",)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExactProgram:
+    """One compiled signature of the exact kernel on a pack."""
+
+    rows: int
+    slots: int
+    t_window: int
+    with_counts: bool
+    k_kernel: int
+    variant: str
+
+    @property
+    def label(self) -> str:
+        return dist.exact_program_name(self.variant, self.rows, self.slots,
+                                       self.t_window)
+
+
+def _widest_slots(resident: ResidentPack, n_terms: int) -> int:
+    """The most slots a query of `n_terms` distinct terms can need on
+    this pack: the `n_terms` longest postings rows of a shard row, each
+    in chunks of CHUNK_CAP (`_slots_needed` of the worst such query).
+    Memoized with the pack, like `_slots_needed`: `/_tpu/stats` asks on
+    every call."""
+    cached = resident.widest_slots_memo.get(n_terms)
+    if cached is not None:
+        return cached
+    worst = n_terms
+    for rstart in resident.pack.row_starts:
+        lens = np.diff(np.asarray(rstart))
+        if lens.size == 0:
+            continue
+        slots = np.maximum(1, -(-lens // dist.CHUNK_CAP))
+        top = min(n_terms, slots.size)
+        worst = max(worst, int(np.partition(slots, slots.size - top)[-top:].sum())
+                    + (n_terms - top))
+    resident.widest_slots_memo[n_terms] = worst
+    return worst
+
+
+def exact_program_set(resident: ResidentPack, k: int, max_batch: int = 128,
+                      max_terms: int = EXACT_MAX_TERMS,
+                      max_slots: Optional[int] = None
+                      ) -> List[ExactProgram]:
+    """Every program `_launch_exact` can dispatch on this pack at `k`
+    for trains of up to `max_batch` queries of up to `max_terms` terms
+    (and, with `max_slots`, of at most so many slots): a function of the
+    pack, `k` and the node's constants alone. Serving compiles nothing
+    outside it; `prewarm` compiles members of it."""
+    rows_set = sorted({_serving_bucket(n) for n in (1, 9, 65, max_batch)
+                       if n <= max_batch})
+    k_kernel = _exact_k_kernel(k)
+    variants = _exact_variants(resident)
+    by_window: Dict[int, int] = {}   # window pin → the most terms under it
+    for n_terms in range(1, max_terms + 1):
+        by_window[_exact_window(n_terms)] = n_terms
+    out: List[ExactProgram] = []
+    fewest = 1
+    for t_window, most in sorted(by_window.items()):
+        widest = _widest_slots(resident, most)
+        if max_slots is not None:
+            widest = max(fewest, min(widest, max_slots))
+        pin = _exact_slot_pin(fewest, t_window)
+        last = _exact_slot_pin(widest, t_window)
+        while pin <= last:
+            out.extend(ExactProgram(rows, pin, t_window, with_counts,
+                                    k_kernel, variant)
+                       for rows in rows_set
+                       for with_counts in (False, True)
+                       for variant in variants)
+            pin *= 2
+        fewest = most + 1
+    return out
+
+
+def _exact_reason(flat: FlatQuery, k: int, can_prune: bool) -> Optional[str]:
+    """Why a query goes to the exact kernel (its ROUTE_COUNTS label, the
+    first that holds), or None for the pruned path."""
+    if flat.min_count != 1:
+        return "exact_min_count"
+    if k > PRUNE_MAX_K:
+        return "exact_k"
+    if len(flat.terms) > PRUNE_MAX_TERMS:
+        return "exact_terms"
+    if not can_prune:
+        return "exact_no_impacts"
+    return None
+
+
 def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
                       k: int, mesh=None,
                       stages: Optional[StageTimes] = None) -> Dict[str, Any]:
@@ -1845,11 +2027,16 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     # fault seam: DeviceWedge blocks here — BEFORE any lock or device
     # work — so a "wedged" launch holds nothing the watchdog needs
     _dispatch_fault_point(mesh)
-    pruned_idx = [i for i, f in enumerate(flats)
-                  if f.min_count == 1 and k <= PRUNE_MAX_K
-                  and len(f.terms) <= PRUNE_MAX_TERMS
-                  and resident.imp_device_arrays is not None]
-    exact_idx = [i for i in range(len(flats)) if i not in set(pruned_idx)]
+    can_prune = resident.imp_device_arrays is not None
+    pruned_idx: List[int] = []
+    exact_idx: List[int] = []
+    for i, f in enumerate(flats):
+        reason = _exact_reason(f, k, can_prune)
+        if reason is None:
+            pruned_idx.append(i)
+        else:
+            exact_idx.append(i)
+            ROUTE_COUNTS.inc(reason)
     # route each query to the smallest exact-sort width that holds its
     # FULL postings; overflow goes to the prefix+rescore path
     full_groups: Dict[int, List[int]] = {b: [] for b in FULL_SLOT_BUCKETS}
@@ -1860,11 +2047,13 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
             hot_idx.append(i)
         else:
             full_groups[b].append(i)
-    # a tiny group isn't worth its own launch's fixed cost (~100 ms in
-    # round 5's records, unmeasured on today's machine): fold it into
-    # the next WIDER bucket when that bucket launches anyway (always
-    # correct — wider holds everything; folding into an EMPTY wider
-    # bucket would save nothing and widen the sort for nothing)
+    # a tiny group isn't worth a launch of its own (a `jit_full_s128`
+    # launch of the few rows it usually has reads 33 ms on the chip:
+    # PERF_LEDGER.jsonl, `device_full_s128_ms_per_launch`, PR 26-28):
+    # fold it into the next WIDER bucket when that bucket launches
+    # anyway (always correct — wider holds everything; folding into an
+    # EMPTY wider bucket would save nothing and widen the sort for
+    # nothing)
     buckets = list(FULL_SLOT_BUCKETS)
     for bi, b in enumerate(buckets[:-1]):
         if 0 < len(full_groups[b]) < 16 and full_groups[buckets[bi + 1]]:
@@ -1876,10 +2065,12 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
                           "exact_idx": exact_idx}
     for b, idxs in full_groups.items():
         if idxs:
+            ROUTE_COUNTS.inc(f"pruned_full_s{b}", n=len(idxs))
             st[f"full_launch_{b}"] = _launch_pruned(
                 resident, [flats[i] for i in idxs], k, mesh,
                 stages=stages, full_slots=b)
     if hot_idx:
+        ROUTE_COUNTS.inc("pruned_hot", n=len(hot_idx))
         st["hot_launch"] = _launch_pruned(
             resident, [flats[i] for i in hot_idx], k, mesh,
             prefix_cap=PREFIX_CAP2, stages=stages)
@@ -1936,6 +2127,7 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
         for j, i in enumerate(st["exact_idx"]):
             out[i] = results[j]
     if tier3_idx:
+        ROUTE_COUNTS.inc("exact_escalated", n=len(tier3_idx))
         t0 = time.perf_counter()
         results = _execute_exact(resident,
                                  [flats[i] for i in tier3_idx], k, mesh,
@@ -1996,28 +2188,38 @@ def _columnar_results(resident: ResidentPack, vals: np.ndarray,
 def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
                   k: int, mesh,
                   stages: Optional[StageTimes] = None,
-                  variant: Optional[str] = None) -> Dict[str, Any]:
+                  variant: Optional[str] = None,
+                  program: Optional[ExactProgram] = None) -> Dict[str, Any]:
     """Full-postings kernel, async dispatch: exact scores, exact totals
-    (tier 3 for OR queries whose validity bounds failed twice; tier 1
-    for msm/AND). Every jit dimension is BUCKETED — batch (8/64/pow2),
-    kernel k (128/1024/pow2), slot count (pow2 ≥ 8), window (≥ 8), chunk
-    length (pinned CHUNK_CAP) — so steady-state serving re-uses a
-    handful of compiled signatures (cold ones compile once ever,
-    persisted by the compilation cache)."""
-    import dataclasses as _dc
-
+    (tier 1 for msm/AND, big k and queries of more than PRUNE_MAX_TERMS
+    terms; tier 3 for OR queries whose validity bounds failed twice).
+    Every jit dimension is pinned to a member of a ladder: batch rows
+    (`_serving_bucket`: 8/64/128), kernel k (`_exact_k_kernel`:
+    128/1024/pow2), slots a row (`_exact_slot_pin`: powers of two from
+    8, from 32 under long queries), run-sum window (`_exact_window`:
+    powers of two from 8), chunk length (CHUNK_CAP). So the programs a
+    pack can meet are the members of `exact_program_set`, each compiled
+    once ever and kept by the compilation cache. `program` (prewarm, the
+    tests) raises the pins to that member's."""
     t_prep = time.perf_counter()
+    states = tracing.current_states()
+    states.switch("prep", queries=len(flats))
     pack = resident.pack
+    rows = _serving_bucket(len(flats))
+    if program is not None:
+        rows, variant = max(rows, program.rows), program.variant
     batch = dist.prepare_query_batch(
         pack, [f.terms for f in flats],
         boosts=[f.boost for f in flats],
         min_counts=[f.min_count for f in flats],
-        pad_batch_to=_serving_bucket(len(flats)),
+        pad_batch_to=rows,
         pad_max_len=dist.CHUNK_CAP,
         compressed=resident.comp_streams)
-    t_pin = 8
-    while t_pin < batch.t_slots:
-        t_pin *= 2
+    t_window = _exact_window(batch.window)
+    t_pin = _exact_slot_pin(batch.t_slots, t_window)
+    if program is not None:
+        t_window = max(t_window, program.t_window)
+        t_pin = max(t_pin, program.slots)
     if t_pin > batch.t_slots:
         s, b, t = batch.starts.shape
         pad = ((0, 0), (0, 0), (0, t_pin - t))
@@ -2027,28 +2229,33 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
             extra = dict(res_starts=np.pad(batch.res_starts, pad),
                          res_lens=np.pad(batch.res_lens, pad),
                          slot_terms=np.pad(batch.slot_terms, pad))
-        batch = _dc.replace(
+        batch = dataclasses.replace(
             batch, starts=np.pad(batch.starts, pad),
             lengths=np.pad(batch.lengths, pad),
             weights=np.pad(batch.weights, pad), t_slots=t_pin, **extra)
-    k_kernel = 128 if k <= 128 else (1024 if k <= 1024
-                                     else _batch_bucket(k, 16384))
     if variant is None:
         variant = _choose_exact_variant(resident, batch)
+    # the launch's static shape: the device program's name (`jit_<label>`
+    # on the trace's XLA Modules line) and the label of its spans and
+    # counters
+    label = dist.exact_program_name(variant, rows, t_pin, t_window)
+    states.note(path=label, rows=rows)
     KERNEL_VARIANT_COUNTS.inc("exact", variant)
-    LAUNCH_COUNTS.inc(f"exact_{variant}")
+    LAUNCH_COUNTS.inc(label)
+    EXACT_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
+    EXACT_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
     t_disp = time.perf_counter()
     vals, gids, totals = dist.distributed_search_raw(
-        pack, batch, k_kernel, mesh, device_arrays=resident.device_arrays,
-        t_window=max(_PRUNE_WINDOW, batch.window), materialize=False,
-        variant=variant)
+        pack, batch, _exact_k_kernel(k), mesh,
+        device_arrays=resident.device_arrays,
+        t_window=t_window, materialize=False, variant=variant)
     if stages is not None:
         stages.add("exact_prep", t_disp - t_prep)
         stages.add(f"exact_dispatch.{variant}",
                    time.perf_counter() - t_disp)
     return {"resident": resident, "n": len(flats), "k": k,
             "vals": vals, "gids": gids, "totals": totals,
-            "variant": variant}
+            "variant": variant, "label": label}
 
 
 def _finish_exact(launch: Dict[str, Any],
@@ -2074,10 +2281,26 @@ def _finish_exact(launch: Dict[str, Any],
 
 def _execute_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
                    k: int, mesh, stages: Optional[StageTimes] = None,
-                   variant: Optional[str] = None) -> List[FlatQueryResult]:
+                   variant: Optional[str] = None,
+                   program: Optional[ExactProgram] = None
+                   ) -> List[FlatQueryResult]:
     return _finish_exact(_launch_exact(resident, flats, k, mesh,
-                                       stages=stages, variant=variant),
+                                       stages=stages, variant=variant,
+                                       program=program),
                          stages=stages)
+
+
+def run_exact_program(resident: ResidentPack, program: ExactProgram,
+                      field: str, mesh) -> None:
+    """Dispatch `program` once on this pack and wait for it: what
+    compiles it (prewarm, the tests of the closed set). The queries are
+    one term the pack lacks, twice (clause counts need two clauses): a
+    slot each, no posting, whatever the pack holds; `_launch_exact`
+    raises slots and window to the program's."""
+    flat = FlatQuery(field, ["\x00warm"] * 2, 1.0,
+                     2 if program.with_counts else 1)
+    _execute_exact(resident, [flat] * program.rows, program.k_kernel, mesh,
+                   program=program)
 
 
 def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
@@ -3775,25 +3998,14 @@ class TpuSearchService:
         # their "packed" variant differs only in the top-k reduction.
         # compressed packs have no impact-sorted copy — the pruned table
         # is unreachable, and the exact kernel runs the compressed pair
-        # (both reachable: per-launch weight fallback picks the exact
-        # decode variant)
+        # (`_exact_variants`: both reachable, per-launch weight fallback
+        # picks the exact decode variant)
         if resident.comp_streams is not None:
             pruned_variants: Tuple[str, ...] = ()
         elif KERNEL_CONFIG["packed_sort"]:
             pruned_variants = ("packed", "ref")
         else:
             pruned_variants = ("ref",)
-        from elasticsearch_tpu.ops import sparse as _sparse
-        if resident.comp_streams is not None:
-            exact_variants: Tuple[str, ...] = ("compressed",
-                                               "compressed_exact")
-            if KERNEL_CONFIG["pallas"]:
-                exact_variants = ("pallas",) + exact_variants
-        elif (KERNEL_CONFIG["packed_sort"]
-                and _sparse.packable(resident.pack.d_pad)):
-            exact_variants = ("packed", "ref")
-        else:
-            exact_variants = ("ref",)
         # dedupe to canonical jit signatures: the kernel is compiled per
         # (batch bucket, candidate-k bucket, width|prefix, variant) —
         # requested k values that bucket identically would recompile
@@ -3814,19 +4026,23 @@ class TpuSearchService:
                                  mesh,
                                  prefix_cap=cap or PREFIX_CAP2,
                                  full_slots=slots, variant=variant)))
-        # exact kernel (msm/AND tier 1, OR tier 3) at its common
-        # bucketed signatures; with_counts=True via min_count=2.
-        # Hot-term slot buckets (t_slots > 8) compile once ever and
-        # persist in the compilation cache.
-        flat_and = FlatQuery(flat.field, flat.terms * 2, 1.0, 2)
+        # exact kernel: the members of the pack's closed set
+        # (`exact_program_set`) that default traffic meets first, msm/AND
+        # of few terms and slots (clause counts on) at the small and the
+        # mid batch bucket. The set's other members (long queries, hot
+        # terms' slot counts, the full bucket) compile on first use,
+        # once ever, and persist in the compilation cache.
         for b_bucket, k in ((8, 10), (64, PRUNE_MAX_K)):
-            for variant in exact_variants:
+            for program in exact_program_set(
+                    resident, k, max_batch=b_bucket,
+                    max_terms=PRUNE_MAX_TERMS, max_slots=EXACT_MIN_SLOTS):
+                if program.rows != b_bucket or not program.with_counts:
+                    continue
                 jobs.append(({"batch": b_bucket, "k": k, "exact": True,
-                              "variant": variant},
-                             lambda b_bucket=b_bucket, k=k,
-                             variant=variant: _execute_exact(
-                                 resident, [flat_and] * b_bucket, k,
-                                 mesh, variant=variant)))
+                              "variant": program.variant,
+                              "program": program.label},
+                             lambda program=program: run_exact_program(
+                                 resident, program, field, mesh)))
         with self._prewarm_lock:
             self._prewarm_progress["total"] += len(jobs)
         # prewarm is BEST-EFFORT per signature: one kernel that the
@@ -3901,12 +4117,31 @@ class TpuSearchService:
                            "pallas": KERNEL_CONFIG["pallas"],
                            "variants": KERNEL_VARIANT_COUNTS.counts()},
                 "launches": LAUNCH_COUNTS.counts(),
+                "route": ROUTE_COUNTS.counts(),
+                "exact_entries": EXACT_ENTRY_COUNTS.counts(),
+                "exact_programs": self.exact_programs(),
                 "render": RENDER_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
                 "supervision": self.supervisor.stats(),
                 "watchdog": self.watchdog.stats(),
                 "devices": self.device_stats(),
                 "stages": self.stages.snapshot()}
+
+    def exact_programs(self, k: int = PRUNE_MAX_K) -> Dict[str, List[str]]:
+        """The /_tpu/stats `exact_programs` block: for each resident
+        pack, the names of the exact kernel's programs that serving can
+        compile on it at `k` (`exact_program_set`; a name stands for its
+        members with and without clause counts)."""
+        caches = [self.packs] + list(getattr(self, "group_caches", {}).values())
+        out: Dict[str, List[str]] = {}
+        for cache in caches:
+            for key in cache.resident_keys():
+                resident = cache.peek(key)
+                if resident is not None:
+                    out[f"{key[0]}/{key[1]}"] = sorted({
+                        p.label for p in exact_program_set(
+                            resident, k, self.batcher.max_batch)})
+        return out
 
     def device_stats(self) -> Dict[str, Any]:
         """The /_tpu/stats `devices` block: the device stamp (platform,
