@@ -623,8 +623,8 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                EXACT_ENTRY_COUNTS, KERNEL_CONFIG, KERNEL_VARIANT_COUNTS,
-                LAUNCH_COUNTS, ROUTE_COUNTS)
+                EXACT_ENTRY_COUNTS, HOLD_EXIT_COUNTS, KERNEL_CONFIG,
+                KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS, ROUTE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
@@ -647,6 +647,10 @@ class Node:
                 yield ("kernel.route", labels, counter)
             for labels, counter in EXACT_ENTRY_COUNTS.items():
                 yield ("kernel.exact_entries", labels, counter)
+            # trains by the reason the launch thread's hold ended:
+            # es_tpu_batcher_hold_exit_total{hold_exit=...}
+            for labels, counter in HOLD_EXIT_COUNTS.items():
+                yield ("batcher.hold_exit", labels, counter)
             for stage, seconds, count, ring, cpu in \
                     svc.stages.metrics_view():
                 lb = {"stage": stage}

@@ -1264,8 +1264,15 @@ class _PackQueue:
     (including a first-compile stall) never delays pack B's queries
     (VERDICT r2 weak #10). Launch and completion are SPLIT so batch N+1
     is prepped and dispatched while batch N still executes on device —
-    JAX async dispatch double-buffers the kernel (VERDICT r3 #1d); the
-    bounded in-flight queue is the backpressure."""
+    JAX async dispatch double-buffers the kernel (VERDICT r3 #1d).
+
+    The backpressure is `n_inflight`, the trains launched and not yet
+    finished, and a train is formed as late as the device allows
+    (`_hold`): none while `PIPELINE_DEPTH` are unfinished, and while the
+    device still has a train queued behind the one it runs the pending
+    queries stay in the open queue and grow to `max_batch`. A query held
+    there loses nothing: launched early it would have waited in the
+    device's queue instead, in a train frozen at the size it had."""
 
     IDLE_EXIT_S = 60.0
     PIPELINE_DEPTH = 3
@@ -1315,6 +1322,63 @@ class _PackQueue:
             self.closed = True
             self.cv.notify_all()
 
+    def _hold(self) -> str:
+        """With `cv` held and queries pending: wait until a train is due
+        → why the hold ended (a `HOLD_EXITS` label). A launch costs the
+        device about the same whatever it carries, so the train is
+        formed as late as the device allows and the queue stays open
+        until then:
+
+        - while `PIPELINE_DEPTH` trains are launched and unfinished
+          nothing is taken (state `blocked`). A train holds its results
+          on the device from its dispatch until the completer has copied
+          them, so this bounds that memory too, at a level a full
+          pipeline reaches in every run;
+        - `full`: `max_batch` are pending and a slot is free: at once;
+        - while two or more are unfinished the device has a train queued
+          behind the one it runs, so it cannot run dry before the next
+          completion: keep accumulating (the completer notifies);
+        - `backlog_low`: only the train the device is running remains,
+          and the next must be prepared now. It goes with half a train,
+          or, after having waited on a busy device, one REFILL window
+          later, so that the just-released cohort (still assembling its
+          responses under the GIL) makes this train instead of
+          fragmenting into the next one;
+        - `idle_window`: nothing is in flight: `window_s` and no more —
+          no refill, no latency floor."""
+        batcher, states = self.batcher, self.launch_states
+        half_train = max(8, batcher.max_batch // 2)
+        deadline = time.monotonic() + batcher.window_s
+        waited_busy = False
+        # (`fail_pending` may empty the queue under a hold: nothing to take)
+        while self.pendings and not self.closed:
+            if self.n_inflight >= self.PIPELINE_DEPTH:
+                if states.state != "blocked":
+                    states.switch("blocked")
+                self.cv.wait(timeout=0.25)
+                continue
+            if states.state != "hold":
+                states.switch("hold")
+            pending = len(self.pendings)
+            if pending >= batcher.max_batch:
+                break
+            now = time.monotonic()
+            if self.n_inflight >= 2 or (
+                    now >= deadline and self.n_inflight > 0
+                    and pending < half_train):
+                waited_busy = True
+                self.cv.wait(timeout=0.25)
+            elif now < deadline:
+                self.cv.wait(timeout=deadline - now)
+            elif waited_busy:
+                waited_busy = False
+                deadline = now + max(0.05, batcher.window_s)
+            else:
+                break
+        if len(self.pendings) >= batcher.max_batch:
+            return "full"
+        return "backlog_low" if self.n_inflight else "idle_window"
+
     def _run(self) -> None:
         batcher = self.batcher
         states = self.launch_states
@@ -1339,55 +1403,16 @@ class _PackQueue:
                     if not retire:
                         if self.closed and not self.pendings:
                             return
-                        # adaptive window: launch a FULL batch any time,
-                        # but while the device is busy with an in-flight
-                        # batch keep accumulating — per-launch cost is
-                        # ~fixed, so more/smaller launches lose (the
-                        # completer notifies when a batch finishes).
-                        # After having waited on a busy device, hold one
-                        # REFILL window so the just-released cohort
-                        # (still assembling its responses under the GIL)
-                        # makes this train instead of fragmenting into
-                        # the next one. An idle device pays only
-                        # window_s — no refill, no latency floor.
-                        states.switch("hold")
-                        deadline = time.monotonic() + batcher.window_s
-                        waited_busy = False
-                        # a HALF-full train launches even while the
-                        # device is busy (pipeline depth > 1 must not
-                        # require a completely full queue — with C
-                        # concurrent clients the queue can never exceed
-                        # C minus in-flight, so gating on max_batch
-                        # serializes trains when C ≈ max_batch)
-                        pipeline_min = max(8, batcher.max_batch // 2)
                         t_cycle = time.perf_counter()
-                        while (len(self.pendings) < batcher.max_batch
-                               and not self.closed):
-                            now = time.monotonic()
-                            if now >= deadline:
-                                if self.n_inflight > 0 and \
-                                        len(self.pendings) < pipeline_min:
-                                    waited_busy = True
-                                    self.cv.wait(timeout=0.25)
-                                    continue
-                                if not waited_busy:
-                                    break
-                                # one refill window after a busy wait:
-                                # the just-released cohort joins THIS
-                                # train — fuller trains beat an instant
-                                # launch (measured: trains shrink to
-                                # ~bucket-half and padding wins without
-                                # this)
-                                waited_busy = False
-                                deadline = now + max(
-                                    0.05, batcher.window_s)
-                                continue
-                            self.cv.wait(timeout=deadline - now)
-                        states.note(pending=len(self.pendings))
+                        hold_exit = self._hold()
+                        states.note(pending=len(self.pendings),
+                                    exit=hold_exit)
                         states.switch("take")
                         taken, self.pendings = _take_fair(
                             self.pendings, batcher.max_batch,
                             batcher.tenant_weight)
+                        if taken:
+                            HOLD_EXIT_COUNTS.inc(hold_exit)
                         self.train_seq += 1
                         states.train = train = self.train_seq
                         t_take = time.perf_counter()
@@ -1402,21 +1427,6 @@ class _PackQueue:
                     return
                 if not taken:
                     continue
-                # the pipeline's depth, kept before the dispatch: at most
-                # PIPELINE_DEPTH trains launched and not yet finished. A
-                # train holds its results on the device from its dispatch
-                # until the completer has copied them, so this bounds that
-                # memory too, at a level a full pipeline reaches in every
-                # run; the queue's bound alone does not (the completer
-                # dequeues before it materializes, and `put` blocks after
-                # the dispatch), and the high-water mark then hangs on
-                # the size of a fourth and fifth train
-                with self.cv:
-                    if self.n_inflight >= self.PIPELINE_DEPTH:
-                        states.switch("blocked")
-                    while (self.n_inflight >= self.PIPELINE_DEPTH
-                           and not self.closed):
-                        self.cv.wait(timeout=0.25)
                 trace_parent = next(
                     (p.trace_span for p in taken if p.trace_span), None)
                 try:
@@ -1454,7 +1464,9 @@ class _PackQueue:
                     with self.cv:
                         self.n_inflight += 1
                     # never blocks: fewer than PIPELINE_DEPTH were in
-                    # flight when this train was dispatched
+                    # flight when this train was taken (the state keeps
+                    # its name: the stage is then there to be read even
+                    # where `_hold` never met a full pipeline)
                     self.inflight.put((st, taken))
                 finally:
                     profiler.tag_stage(None)
@@ -1523,10 +1535,11 @@ class _PackQueue:
 
 class MicroBatcher:
     """Coalesces concurrent queries per resident pack into single kernel
-    launches (SURVEY.md §2.3 P4). Queries arriving within `window_s` (or
-    until `max_batch`) share a launch; k pads to the max requested.
-    Each pack has its own queue + worker, so launches for different
-    packs overlap."""
+    launches (SURVEY.md §2.3 P4). On an idle device queries arriving
+    within `window_s` (or until `max_batch`) share a launch; on a busy
+    one a train is formed as late as the device allows
+    (`_PackQueue._hold`); k pads to the max requested. Each pack has its
+    own queue + worker, so launches for different packs overlap."""
 
     def __init__(self, window_s: float = 0.01, max_batch: int = 128):
         self.window_s = window_s
@@ -1777,8 +1790,14 @@ ROUTE_COUNTS = LabeledCounters("route")
 #: entries their static shape sorts, rows × slots × chunk length
 #: (`padded`) → es_tpu_kernel_exact_entries_total
 EXACT_ENTRY_COUNTS = LabeledCounters("kind")
+#: trains by the reason their hold ended (`_PackQueue._hold`): one count
+#: a train taken → es_tpu_batcher_hold_exit_total
+HOLD_EXITS = ("full", "backlog_low", "idle_window")
+HOLD_EXIT_COUNTS = LabeledCounters("hold_exit")
 for _label in ROUTES:
     ROUTE_COUNTS.child(_label)  # all read 0, not absent, before a query
+for _label in HOLD_EXITS:
+    HOLD_EXIT_COUNTS.child(_label)
 for _label in ("real", "padded"):
     EXACT_ENTRY_COUNTS.child(_label)
 
@@ -4118,6 +4137,7 @@ class TpuSearchService:
                            "variants": KERNEL_VARIANT_COUNTS.counts()},
                 "launches": LAUNCH_COUNTS.counts(),
                 "route": ROUTE_COUNTS.counts(),
+                "hold_exit": HOLD_EXIT_COUNTS.counts(),
                 "exact_entries": EXACT_ENTRY_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "render": RENDER_COUNTS.counts(),

@@ -238,6 +238,11 @@ def test_stats_gain_keys_and_nothing_else_changes_shape(loaded):
     assert 'es_tpu_search_tpu_stage_cpu_seconds_total{stage="batcher.prep"}' \
         in loaded["prom"]
     assert 'es_tpu_kernel_launches_total{path="full_s32"}' in loaded["prom"]
+    # trains by the reason their hold ended (process-wide, as `launches`)
+    assert set(stats["hold_exit"]) == set(tpu_service.HOLD_EXITS)
+    assert sum(stats["hold_exit"].values()) >= stats["batches"] > 0
+    assert 'es_tpu_batcher_hold_exit_total{hold_exit="idle_window"}' \
+        in loaded["prom"]
 
 
 def test_a_traced_request_holds_batcher_prep_with_its_train(loaded):
