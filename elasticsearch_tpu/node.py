@@ -41,7 +41,7 @@ class Node:
         # whatever value happened to be live
         self._base_settings = dict(self.settings.get_as_dict())
         # logging is part of node construction, not the CLI: embedded
-        # users (bench, tests, Python API) get the same handlers/levels.
+        # users (tests, Python API) get the same handlers/levels.
         # Owner-scoped so two embedded nodes don't reset each other.
         from elasticsearch_tpu.common.logging import configure
         configure(self.settings, owner=id(self))
@@ -114,13 +114,13 @@ class Node:
                 # <checkout>/.jax_cache)
                 compile_cache_dir=self.settings.get(
                     "search.tpu_serving.compile_cache_dir"),
-                # packed-key device kernels (PERF.md round 8): single
+                # packed-key device kernels: single
                 # uint32 sort key + hierarchical top-k, with automatic
                 # per-launch exact-f32 fallback when the pack/batch
                 # overflows the packed layout
                 packed_sort=self.settings.get_bool(
                     "search.tpu_serving.kernel.packed_sort", True),
-                # compressed resident packs (PERF.md round 11): 16-bit
+                # compressed resident packs: 16-bit
                 # impact/doc/rank streams + residual tables + block-max
                 # metadata + delta doc stream; ~3x fewer HBM bytes/doc
                 # at identical result bits. Default ON since PR 15;
@@ -128,13 +128,6 @@ class Node:
                 # chip. Incompressible packs fall back to raw residency
                 compressed_pack=self.settings.get_bool(
                     "search.tpu_serving.kernel.compressed_pack", True),
-                # fused Pallas merge kernel (PR 15): the whole compressed
-                # hot loop as one kernel — off by default; runs under the
-                # interpreter off-TPU, and on a TPU backend the node
-                # refuses to start with it on (the kernel does not
-                # compile there: ops/pallas_merge.TPU_REFUSAL)
-                pallas=self.settings.get_bool(
-                    "search.tpu_serving.kernel.pallas", False),
                 # supervision: dispatches overdue past this deadline are
                 # failed typed and trip batcher recovery (0 disables)
                 launch_deadline_ms=self.settings.get_float(
@@ -629,8 +622,6 @@ class Node:
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
                    1 if KERNEL_CONFIG["compressed_pack"] else 0, "gauge")
-            yield ("search.tpu.kernel_pallas", nl,
-                   1 if KERNEL_CONFIG["pallas"] else 0, "gauge")
             # per-(kernel, variant) launch counts:
             # es_tpu_kernel_variant_total{kernel=...,variant=...}
             for labels, counter in KERNEL_VARIANT_COUNTS.items():

@@ -2,8 +2,8 @@
 
 This is the parity oracle (SURVEY.md §7.2 phase 3: "Parity harness: same
 corpus through a knowledge-equivalent reimplementation of the formula —
-score-level diff") and doubles as the CPU baseline scorer for bench.py.
-It mirrors the reference hot path (§3.3) doc-at-a-time semantics:
+score-level diff"). It mirrors the reference hot path (§3.3)
+doc-at-a-time semantics:
 
   per segment: for each query term with df>0
       idf = ln(1 + (N - n + 0.5)/(n + 0.5))           # SHARD-level N, n

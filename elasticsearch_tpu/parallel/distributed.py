@@ -721,7 +721,7 @@ def _local_body(flat_docs, flat_impact, starts, lengths, weights, min_count,
 
 
 def _merge_topk(vals_b, gids_b, k: int, variant: str = "ref"):
-    if variant in ("packed", "compressed", "pallas"):
+    if variant in ("packed", "compressed"):
         top_vals, pos = sparse.hierarchical_top_k(
             vals_b, min(k, vals_b.shape[1]))
     else:
@@ -735,7 +735,7 @@ def make_local_search(*, max_len: int, d_pad: int, p_pad: int, k: int,
                       t_window: int, with_counts: bool = False,
                       variant: str = "ref"):
     """Single-device search step: S shards × B queries → global top-k.
-    Used by the bench on one chip and as the compile-check entry point.
+    The compile-check entry point (`__graft_entry__.py`).
     lru_cached so repeated bucket signatures reuse the jitted step (and
     its XLA compile cache) instead of re-tracing per call."""
 
@@ -1211,7 +1211,7 @@ def distributed_search_raw(pack: StackedShardPack, batch: QueryBatch,
                            variant: str = "ref"):
     """One distributed query step, RAW outputs: numpy (vals [B,k'],
     gids int64 [B,k'], totals [B]) with no per-hit host decoding — the
-    serving path decodes the whole batch vectorized (VERDICT r3 #1).
+    serving path decodes the whole batch vectorized.
     materialize=False returns the jax arrays of the ASYNC dispatch
     without blocking (pipelined serving; np.asarray them to wait).
 

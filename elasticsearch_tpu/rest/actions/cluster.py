@@ -50,7 +50,7 @@ def register(controller: RestController, node) -> None:
             "cluster_uuid": node.cluster_uuid,
             "version": {"number": VERSION,
                         "build_flavor": "tpu",
-                        "lucene_version": "n/a (XLA/Pallas kernels)"},
+                        "lucene_version": "n/a (XLA kernels)"},
             "tagline": "You Know, for Search — on TPUs",
         }
 

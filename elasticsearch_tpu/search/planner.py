@@ -53,11 +53,10 @@ MAX_SLOTS_PER_PASS = 32
 def choose_kernel_variant(d_pad: int,
                           weights: Optional[np.ndarray] = None,
                           enabled: bool = True,
-                          compressed: bool = False,
-                          pallas: bool = False) -> str:
+                          compressed: bool = False) -> str:
     """Pick the device-kernel variant for one lowered pack/batch.
 
-    Lowering-time decision (PERF.md round 8): "packed" — the single
+    Lowering-time decision: "packed" — the single
     uint32-key sort + hierarchical top-k + exact-f32 rescore — whenever
     the pack's doc axis and the batch's slot weights fit the 16-bit
     packed layout (sparse.packable); otherwise the exact-f32 reference
@@ -67,24 +66,17 @@ def choose_kernel_variant(d_pad: int,
     impact code could turn a positive contribution into code 0 and
     perturb TotalHits).
 
-    compressed=True (the resident pack holds only the 16-bit streams,
-    PERF.md round 11): the same packable() predicate decides between
+    compressed=True (the resident pack holds only the 16-bit streams):
+    the same packable() predicate decides between
     "compressed" (quantized sort keys + block-max pruning, needs the
     monotone lower-bound guarantee on weights) and "compressed_exact"
     (per-lane residual-table decode then the exact-f32 pipeline — the
     automatic fallback for weights that would violate the bound). A
     compressed pack has no f32 posting copy, so "ref"/"packed" are not
-    reachable from it.
-
-    pallas=True (PR 15): prefer the fused Pallas spelling of the
-    compressed pipeline — one kernel for gather, merge, in-kernel
-    block-max skip and top-k, bit-identical to "compressed". It has the
-    same packable() requirement, so the fallback chain stays typed:
-    weights not packable → the same "compressed_exact" choice as
-    pallas=False. Never errors."""
+    reachable from it. Never errors."""
     if compressed:
         if sparse.packable(d_pad, weights):
-            return "pallas" if pallas else "compressed"
+            return "compressed"
         return "compressed_exact"
     if enabled and sparse.packable(d_pad, weights):
         return "packed"

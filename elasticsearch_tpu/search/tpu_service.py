@@ -57,8 +57,8 @@ logger = logging.getLogger("elasticsearch_tpu.tpu_service")
 
 
 class StageTimes:
-    """Accumulated per-stage wall time on the serving path (VERDICT r3
-    #1a: measure where the time goes before optimizing it). Reported via
+    """Accumulated per-stage wall time on the serving path (measure
+    where the time goes before optimizing it). Reported via
     TpuSearchService.stats()["stages"] and the profile/_nodes/stats trees.
 
     Besides the running (seconds, count) totals, each stage keeps a
@@ -364,7 +364,7 @@ class ResidentPack:
     # PREFIX_CAP entries and bounds what it skipped
     imp_host: Optional[Tuple[np.ndarray, np.ndarray]] = None
     imp_device_arrays: Optional[Tuple] = None
-    # vectorized hit resolution (VERDICT r3 #1): one fancy-index resolves
+    # vectorized hit resolution: one fancy-index resolves
     # a whole [B, k] kernel result to external ids/shards — no per-hit
     # Python on the serving path
     row_shard: Optional[np.ndarray] = None    # int32[S_pad], -1 = padding
@@ -383,7 +383,7 @@ class ResidentPack:
     # number of terms → _widest_slots result, on the same terms
     widest_slots_memo: Dict[int, int] = dataclasses.field(
         default_factory=dict)
-    # compressed resident format (PERF.md round 11): host-side 16-bit
+    # compressed resident format: host-side 16-bit
     # streams + residual tables. When set, device_arrays is the 5-tuple
     # from device_put_compressed (6-tuple with the delta doc stream's
     # base column, PR 15), there is no f32 posting copy on device and no
@@ -393,12 +393,20 @@ class ResidentPack:
     # per-pack HBM accounting detail for /_tpu/stats and the Prometheus
     # pack families: raw vs resident bytes, ratio, block metadata, docs
     hbm_detail: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    # placement (fault-domain) residency: when this pack is one replica
-    # of an R-way placement, the group's sub-mesh its arrays live on —
-    # launches MUST use it (a strict subset of the full mesh). None =
-    # single-group serving, launches use the batcher's mesh unchanged.
-    group_mesh: Optional[Any] = None
+    # placement (fault-domain) residency: the replica group whose cache
+    # built this pack (None = single-group serving)
     group_id: Optional[int] = None
+    # the mesh the arrays were placed on. A remesh takes no build lock,
+    # so a build it overtakes ends on the mesh of before: such a pack is
+    # never swapped in (`IndexPackCache._swap_in_locked`)
+    mesh: Optional[Any] = None
+
+    @property
+    def group_mesh(self) -> Optional[Any]:
+        """A group-placed pack's arrays live on its group's sub-mesh, a
+        strict subset of the full mesh: launches MUST use it. None =
+        single-group serving, launches use the batcher's mesh."""
+        return self.mesh if self.group_id is not None else None
 
     @property
     def compressed(self) -> bool:
@@ -669,31 +677,7 @@ class IndexPackCache:
                 if entry is not None and entry.reader_key == reader_key:
                     self.hits += 1
                     return entry
-                self.misses += 1
-            entry = self._build(readers, field, reader_key)
-            old = None
-            dropped: List[ResidentPack] = []
-            with self._lock:
-                if entry is not None:
-                    old = self._cache.get(key)
-                    if old is not None and self._breaker is not None:
-                        self._breaker.release(old.hbm_bytes)
-                    self._cache[key] = entry
-                    self._last_bytes[key] = int(entry.hbm_bytes)
-                    # a full rebuild covers everything the chain did —
-                    # the folded deltas drain to exactly zero
-                    dropped = self._drop_deltas_locked(key)
-                    self._set_chain_meta_locked(key, readers, reader_key)
-            if entry is not None:
-                events.emit("pack.build", index=key[0], field=key[1],
-                            hbm_bytes=int(entry.hbm_bytes),
-                            compressed=entry.compressed,
-                            rebuild=old is not None,
-                            group=self.group_id)
-            if self.on_evict is not None:
-                for stale in ([old] if old is not None else []) + dropped:
-                    self.on_evict(stale)
-            return entry
+            return self._build_and_swap(key, readers, field, reader_key)
         finally:
             build_lock.release()
 
@@ -815,30 +799,53 @@ class IndexPackCache:
         """Full build + swap, chain reset. Caller holds the build lock."""
         with self._lock:
             self.misses += 1
-        entry = self._build(readers, field, reader_key)
-        old = None
-        dropped: List[ResidentPack] = []
-        with self._lock:
-            if entry is not None:
-                old = self._cache.get(key)
-                if old is not None and self._breaker is not None:
-                    self._breaker.release(old.hbm_bytes)
-                self._cache[key] = entry
-                self._last_bytes[key] = int(entry.hbm_bytes)
-                dropped = self._drop_deltas_locked(key)
-                self._set_chain_meta_locked(key, readers, reader_key)
+        while True:
+            entry = self._build(readers, field, reader_key)
+            with self._lock:
+                rebuild = key in self._cache
+                evicted = self._swap_in_locked(key, entry, readers,
+                                               reader_key)
+            if evicted is not None:
+                break  # else: a remesh overtook the build; build again
         if entry is not None:
             events.emit("pack.build", index=key[0], field=key[1],
                         hbm_bytes=int(entry.hbm_bytes),
-                        compressed=entry.compressed,
-                        rebuild=old is not None, group=self.group_id)
+                        compressed=entry.compressed, rebuild=rebuild,
+                        group=self.group_id)
         if self.on_evict is not None:
-            for stale in ([old] if old is not None else []) + dropped:
+            for stale in evicted:
                 self.on_evict(stale)
         return entry
 
+    def _swap_in_locked(self, key, entry: Optional[ResidentPack], readers,
+                        reader_key) -> Optional[List[ResidentPack]]:
+        """Make a full build the resident of `key`: the pack it replaces
+        and every delta chained on it are released (a full build covers
+        everything the chain did, so they drain to exactly zero) and
+        returned for the caller's `on_evict`. None, with the entry's own
+        charge released, where a remesh swapped the mesh under the
+        build: its arrays sit on the mesh of before. Caller holds
+        `_lock`."""
+        if entry is None:
+            return []
+        if entry.mesh is not self._mesh:
+            if self._breaker is not None:
+                self._breaker.release(entry.hbm_bytes)
+            return None
+        evicted: List[ResidentPack] = []
+        old = self._cache.get(key)
+        if old is not None:
+            if self._breaker is not None:
+                self._breaker.release(old.hbm_bytes)
+            evicted.append(old)
+        self._cache[key] = entry
+        self._last_bytes[key] = int(entry.hbm_bytes)
+        evicted += self._drop_deltas_locked(key)
+        self._set_chain_meta_locked(key, readers, reader_key)
+        return evicted
+
     def _append_delta(self, key, base: ResidentPack, fresh, readers,
-                      field: str, reader_key) -> PackChain:
+                      field: str, reader_key) -> Optional[PackChain]:
         """Build one immutable delta pack from the uncovered segments
         and chain it on the base. Caller holds the build lock."""
         docs = sum(v.segment.num_docs for views in fresh.values()
@@ -849,23 +856,37 @@ class IndexPackCache:
                     segments=sum(len(v) for v in fresh.values()))
         delta = self._build_delta(readers, fresh, field, reader_key)
         want_compact = False
+        chain = None
         with self._lock:
-            if delta is not None:
-                self._deltas.setdefault(key, []).append(delta)
-            # even a field-less delta advances coverage: the chain now
-            # answers for this reader set
-            self._set_chain_meta_locked(key, readers, reader_key)
-            meta = self._chain_meta[key]
-            deltas = list(self._deltas.get(key, ()))
-            if deltas:
-                base_ = self._cache[key]
-                meta.union = _UnionView([base_] + deltas)
-                meta.union.reader_key = reader_key
-                total_docs = sum(
-                    int(p.hbm_detail.get("docs", 0)) for p in deltas)
-                want_compact = (len(deltas) > self.delta_max_packs
-                                or total_docs > self.delta_max_docs)
-            chain = self._chain_locked(key)
+            # a teardown (`invalidate_all`, `invalidate`) does not take
+            # the build lock: where it dropped the base while the delta
+            # was built, the delta covers nothing that is resident
+            orphaned = self._cache.get(key) is not base
+            if orphaned:
+                if delta is not None and self._breaker is not None:
+                    self._breaker.release(delta.hbm_bytes)
+            else:
+                if delta is not None:
+                    self._deltas.setdefault(key, []).append(delta)
+                # even a field-less delta advances coverage: the chain now
+                # answers for this reader set
+                self._set_chain_meta_locked(key, readers, reader_key)
+                meta = self._chain_meta[key]
+                deltas = list(self._deltas.get(key, ()))
+                if deltas:
+                    meta.union = _UnionView([base] + deltas)
+                    meta.union.reader_key = reader_key
+                    total_docs = sum(
+                        int(p.hbm_detail.get("docs", 0)) for p in deltas)
+                    want_compact = (len(deltas) > self.delta_max_packs
+                                    or total_docs > self.delta_max_docs)
+                chain = self._chain_locked(key)
+        if orphaned:
+            if delta is not None and self.on_evict is not None:
+                self.on_evict(delta)
+            entry = self._build_and_swap(key, readers, field, reader_key)
+            return None if entry is None else PackChain(
+                entry, (), entry, reader_key)
         if delta is not None:
             if self.delta_stats is not None:
                 self.delta_stats.appends += 1
@@ -896,13 +917,14 @@ class IndexPackCache:
             return None
         k1 = readers[0][1].k1
         b = readers[0][1].b
-        n_sh = self.mesh.shape[SHARD_AXIS]
+        mesh = self.mesh  # read once: a remesh may swap it under us
+        n_sh = mesh.shape[SHARD_AXIS]
         s_pad = ((len(segments) + n_sh - 1) // n_sh) * n_sh
         pack = dist.build_delta_pack(segments, field, live_docs=live,
                                      k1=k1, b=b, pad_shards_to=s_pad,
                                      row_groups=groups)
         return self._place_pack(pack, field, readers, reader_key,
-                                row_origin, row_segments,
+                                row_origin, row_segments, mesh,
                                 label=f"delta[{field}]",
                                 compressible=False)
 
@@ -946,17 +968,13 @@ class IndexPackCache:
                 events.incident("compaction_failure", index=key[0],
                                 field=field, error=str(exc))
                 return False
-            evicted: List[ResidentPack] = []
             with self._lock:
-                if entry is not None:
-                    old = self._cache.get(key)
-                    if old is not None and self._breaker is not None:
-                        self._breaker.release(old.hbm_bytes)
-                        evicted.append(old)
-                    self._cache[key] = entry
-                    self._last_bytes[key] = int(entry.hbm_bytes)
-                    evicted += self._drop_deltas_locked(key)
-                    self._set_chain_meta_locked(key, readers, reader_key)
+                evicted = self._swap_in_locked(key, entry, readers,
+                                               reader_key)
+            if evicted is None:
+                # a remesh overtook the fold: its teardown dropped the
+                # chain, and recovery re-attains residency
+                return False
             if self.on_evict is not None:
                 for stale in evicted:
                     self.on_evict(stale)
@@ -992,28 +1010,29 @@ class IndexPackCache:
             return None
         k1 = readers[0][1].k1
         b = readers[0][1].b
-        # pad rows to a multiple of the mesh's shards axis
-        n_sh = self.mesh.shape[SHARD_AXIS]
+        # pad rows to a multiple of the mesh's shards axis (the mesh is
+        # read once: a remesh may swap it under the build)
+        mesh = self.mesh
+        n_sh = mesh.shape[SHARD_AXIS]
         s_pad = ((len(segments) + n_sh - 1) // n_sh) * n_sh
         pack = dist.build_stacked_pack(segments, field, live_docs=live,
                                        k1=k1, b=b, pad_shards_to=s_pad,
                                        row_groups=groups)
         return self._place_pack(pack, field, readers, reader_key,
-                                row_origin, row_segments,
+                                row_origin, row_segments, mesh,
                                 label=f"pack[{field}]", compressible=True)
 
     def _place_pack(self, pack, field: str, readers, reader_key: Tuple,
-                    row_origin, row_segments, *, label: str,
+                    row_origin, row_segments, mesh, *, label: str,
                     compressible: bool) -> ResidentPack:
-        """Charge the breaker, place `pack` on device, build resolution
+        """Charge the breaker, place `pack` on `mesh`, build resolution
         tables. `compressible=False` (delta packs) forces the raw format:
         deltas are small and short-lived — compaction folds them into
         the compressed base, so per-delta stream compression would buy
         bytes at the cost of append latency."""
         # what the uncompressed resident image costs: doc-sorted pack +
         # the impact-sorted copy (same two arrays re-ordered) — the
-        # baseline both /_tpu/stats' compression_ratio and the bench's
-        # hbm_bytes_per_doc compare against
+        # baseline /_tpu/stats' compression_ratio compares against
         raw_bytes = (pack.nbytes_device() + pack.flat_docs.nbytes
                      + pack.flat_impact.nbytes)
         n_docs = int(sum(len(ids) for ids in pack.shard_doc_ids))
@@ -1035,7 +1054,7 @@ class IndexPackCache:
                 self._breaker.add_estimate_bytes_and_maybe_break(
                     hbm, label=label)
             try:
-                arrays = dist.device_put_compressed(streams, self.mesh)
+                arrays = dist.device_put_compressed(streams, mesh)
             except Exception:
                 if self._breaker is not None:
                     self._breaker.release(hbm)
@@ -1050,10 +1069,10 @@ class IndexPackCache:
                 self._breaker.add_estimate_bytes_and_maybe_break(
                     hbm, label=label)
             try:
-                arrays = dist.device_put_pack(pack, self.mesh)
+                arrays = dist.device_put_pack(pack, mesh)
                 imp_arrays = dist.device_put_pack(
                     dataclasses.replace(pack, flat_docs=imp_docs,
-                                        flat_impact=imp_impacts), self.mesh)
+                                        flat_impact=imp_impacts), mesh)
             except Exception:
                 if self._breaker is not None:  # undo the charge on failure
                     self._breaker.release(hbm)
@@ -1108,9 +1127,7 @@ class IndexPackCache:
                             id_json=EncodedIds.build(pack.shard_doc_ids),
                             row_segments=row_segments,
                             comp_streams=streams, hbm_detail=hbm_detail,
-                            group_mesh=(self.mesh if self.group_id
-                                        is not None else None),
-                            group_id=self.group_id)
+                            group_id=self.group_id, mesh=mesh)
 
     def invalidate(self, index_name: str) -> None:
         evicted = []
@@ -1261,10 +1278,10 @@ def _take_fair(pendings: List[_Pending], cap: int,
 class _PackQueue:
     """One pack's pending queries + a launch worker + a completion
     thread. Packs batch independently, so pack A's kernel launch
-    (including a first-compile stall) never delays pack B's queries
-    (VERDICT r2 weak #10). Launch and completion are SPLIT so batch N+1
-    is prepped and dispatched while batch N still executes on device —
-    JAX async dispatch double-buffers the kernel (VERDICT r3 #1d).
+    (including a first-compile stall) never delays pack B's queries.
+    Launch and completion are SPLIT so batch N+1 is prepped and
+    dispatched while batch N still executes on device — JAX async
+    dispatch double-buffers the kernel.
 
     The backpressure is `n_inflight`, the trains launched and not yet
     finished, and a train is formed as late as the device allows
@@ -1675,8 +1692,8 @@ class FlatQueryResult:
     """Per-query kernel result, COLUMNAR: parallel numpy arrays best-first
     (scores f32[n], pack rows int32[n], local ordinals int32[n]). The
     serving path consumes the columns directly — external ids resolve via
-    one fancy-index (`resident.resolve_ids`), never per-hit Python
-    (VERDICT r3 #1). `hits` is the legacy tuple view for cold paths."""
+    one fancy-index (`resident.resolve_ids`), never per-hit Python.
+    `hits` is the legacy tuple view for cold paths."""
 
     scores: np.ndarray
     rows: np.ndarray
@@ -1739,34 +1756,26 @@ PRUNE_MAX_K = 1000
 PRUNE_MAX_TERMS = 8          # > 8 query terms → exact path
 _PRUNE_WINDOW = 8
 
-# device-kernel variant selection (PERF.md round 8). packed_sort=True
-# routes launches through the single-packed-key sort + hierarchical
-# top-k kernels; choose_kernel_variant still falls back to "ref"
-# per-launch whenever the pack/batch overflows the 16-bit packed layout
+# device-kernel variant selection. packed_sort=True routes launches
+# through the single-packed-key sort + hierarchical top-k kernels;
+# choose_kernel_variant still falls back to "ref" per-launch whenever the pack/batch overflows the 16-bit packed layout
 # (the setting is the ceiling, packability is the floor). Process-wide
 # because the jitted kernels and their prewarmed signatures are too
 # (`search.tpu_serving.kernel.packed_sort`).
 KERNEL_CONFIG = {"packed_sort": True,
                  # compressed_pack=True builds RESIDENT packs in the
-                 # 16-bit stream format (PERF.md round 11): ~2.7× fewer
+                 # 16-bit stream format: ~2.7× fewer
                  # HBM bytes/doc, exact scores via residual tables,
                  # device-side block-max pruning. Default ON since PR 15
                  # (two rounds of parity sweeps + the SLO harness behind
                  # it; chip_smoke.py holds its two variants to the numpy
                  # reference on the chip). Build-time:
-                 # toggling only affects packs built afterwards (the
-                 # bench invalidates between phases). Incompressible
-                 # packs (d_pad ≥ 2^16, non-finite impacts, > 65535
-                 # distinct impacts per term) silently stay in the raw
-                 # format (`search.tpu_serving.kernel.compressed_pack`).
-                 "compressed_pack": True,
-                 # pallas=True serves compressed packs through the fused
-                 # Pallas kernel (ops/pallas_merge) — bit-identical to
-                 # "compressed" under the interpreter. Off by default, and
-                 # refused at node start on a TPU backend: the Pallas TPU
-                 # lowering does not compile it (pallas_merge.TPU_REFUSAL;
-                 # `search.tpu_serving.kernel.pallas`).
-                 "pallas": False}
+                 # toggling only affects packs built afterwards.
+                 # Incompressible packs (d_pad ≥ 2^16, non-finite
+                 # impacts, > 65535 distinct impacts per term) silently
+                 # stay in the raw format
+                 # (`search.tpu_serving.kernel.compressed_pack`).
+                 "compressed_pack": True}
 
 #: per-(kernel, variant) launch counters → es_tpu_kernel_variant_total
 KERNEL_VARIANT_COUNTS = LabeledCounters("kernel", "variant")
@@ -1810,8 +1819,7 @@ def _choose_exact_variant(resident: ResidentPack, batch) -> str:
     return choose_kernel_variant(resident.pack.d_pad, batch.weights,
                                  enabled=KERNEL_CONFIG["packed_sort"],
                                  compressed=resident.comp_streams
-                                 is not None,
-                                 pallas=KERNEL_CONFIG["pallas"])
+                                 is not None)
 
 
 def _pruned_variant() -> str:
@@ -1819,8 +1827,7 @@ def _pruned_variant() -> str:
     hierarchical top-k half (unconditionally safe); whether a launch
     ALSO packs (gid, impact code) into one sort key is a separate
     per-launch gate (pack_keys in _launch_pruned: the group's gid range
-    must fit 16 bits and the batch weights must be packable).
-    Setting-gated so the bench can A/B it."""
+    must fit 16 bits and the batch weights must be packable)."""
     return "packed" if KERNEL_CONFIG["packed_sort"] else "ref"
 
 
@@ -1934,8 +1941,7 @@ def _exact_variants(resident: ResidentPack) -> Tuple[str, ...]:
     (`_choose_exact_variant` per launch: the setting is the ceiling,
     the batch's weights decide between the two of a pair)."""
     if resident.comp_streams is not None:
-        pair: Tuple[str, ...] = ("compressed", "compressed_exact")
-        return (("pallas",) + pair) if KERNEL_CONFIG["pallas"] else pair
+        return ("compressed", "compressed_exact")
     if KERNEL_CONFIG["packed_sort"] and sparse.packable(resident.pack.d_pad):
         return ("packed", "ref")
     return ("ref",)
@@ -2037,7 +2043,7 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     and the exact subset (msm/AND, big k, many terms). Returns an
     opaque launch state for finish_flat_batch. JAX dispatch is
     asynchronous, so the caller can launch batch N+1 while batch N
-    executes on device (double-buffered serving; VERDICT r3 #1d).
+    executes on device (double-buffered serving).
     On a batcher's launch thread the time spent here is its states
     `prep`, then `lock`/`put`/`call` around each program's dispatch."""
     tracing.current_states().switch("prep", queries=len(flats))
@@ -2288,8 +2294,7 @@ def _finish_exact(launch: Dict[str, Any],
     totals = np.asarray(launch["totals"])
     states.switch("decode")
     if stages is not None:
-        # variant-tagged: the bench's kernel_compare diffs these rings
-        # per variant for device_ms_per_query
+        # variant-tagged, like `exact_dispatch.<variant>`
         stages.add(f"exact_device_wait.{launch['variant']}",
                    time.perf_counter() - t_dev)
     return _columnar_results(launch["resident"], vals, gids, totals,
@@ -2410,7 +2415,6 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     if stages is not None:
         stages.add("batch_prep", t_disp - t_prep)
         stages.add("batch_dispatch", t_dev - t_disp)
-        stages.add(f"batch_dispatch.{variant}", t_dev - t_disp)
     return {"resident": resident, "flats": flats, "k": k,
             "packed": packed, "variant": variant}
 
@@ -2435,13 +2439,9 @@ def _finish_pruned(launch: Dict[str, Any],
     vals, gids, totals, cutoff, beta = dist.unpack_pruned(packed)
     if stages is not None:
         stages.add("batch_device_wait", t_decode - t_dev)
-        # variant-tagged sibling ring: kernel_compare reads per-variant
-        # device time from here without disturbing the canonical stage
-        stages.add(f"batch_device_wait.{launch['variant']}",
-                   t_decode - t_dev)
 
-    # vectorized batch decode (VERDICT r3 #1): clamp each query to its
-    # first min(n_valid, k) entries, then check the WAND validity bound
+    # vectorized batch decode: clamp each query to its first
+    # min(n_valid, k) entries, then check the WAND validity bound
     # with scalar numpy reads — no per-hit Python
     decoded = _columnar_results(
         resident, vals, gids.astype(np.int64), totals, len(flats),
@@ -3049,18 +3049,13 @@ class TpuSearchService:
                  compile_cache_dir: Optional[str] = None,
                  packed_sort: bool = True,
                  compressed_pack: bool = True,
-                 pallas: bool = False,
                  launch_deadline_ms: float = 120_000.0,
                  device_health: Optional[Dict[str, Any]] = None,
                  placement: Optional[Dict[str, Any]] = None,
                  delta: Optional[Dict[str, Any]] = None):
         _ensure_compile_cache(compile_cache_dir)
-        if pallas:
-            from elasticsearch_tpu.ops import pallas_merge
-            pallas_merge.require_servable()
         KERNEL_CONFIG["packed_sort"] = bool(packed_sort)
         KERNEL_CONFIG["compressed_pack"] = bool(compressed_pack)
-        KERNEL_CONFIG["pallas"] = bool(pallas)
         self.packs = IndexPackCache(mesh=mesh, breaker=breaker)
         self.plans = PlanCache(max_entries=plan_cache_size)
         self.batch_timeout_s = batch_timeout_s
@@ -3600,38 +3595,14 @@ class TpuSearchService:
         self.supervisor.trigger(reason)
 
     def set_kernel_packed_sort(self, enabled: bool) -> None:
-        """Flip the packed-sort kernel variant at runtime (the bench's
-        kernel_compare mode A/Bs through this; per-launch packability
-        fallback still applies when enabling)."""
+        """Flip the packed-sort kernel variant at runtime: the seam
+        through which tests reach "ref" on a pack small enough to pack
+        (per-launch packability fallback still applies when enabling)."""
         KERNEL_CONFIG["packed_sort"] = bool(enabled)
 
     @property
     def kernel_packed_sort(self) -> bool:
         return KERNEL_CONFIG["packed_sort"]
-
-    def set_kernel_compressed_pack(self, enabled: bool) -> None:
-        """Flip compressed-pack residency at runtime. BUILD-time: only
-        packs built after the flip change format — callers that need the
-        new format now (the bench's kernel_compare) also invalidate."""
-        KERNEL_CONFIG["compressed_pack"] = bool(enabled)
-
-    @property
-    def kernel_compressed_pack(self) -> bool:
-        return KERNEL_CONFIG["compressed_pack"]
-
-    def set_kernel_pallas(self, enabled: bool) -> None:
-        """Flip the fused-Pallas serving variant at runtime (launch-time:
-        the next lowering pass picks it up; choose_kernel_variant still
-        falls back to "compressed_exact" when the batch isn't packable).
-        Refused on a TPU backend, where the kernel does not compile."""
-        if enabled:
-            from elasticsearch_tpu.ops import pallas_merge
-            pallas_merge.require_servable()
-        KERNEL_CONFIG["pallas"] = bool(enabled)
-
-    @property
-    def kernel_pallas(self) -> bool:
-        return KERNEL_CONFIG["pallas"]
 
     def invalidate_index(self, index_name: str) -> None:
         """Drop resident packs AND lowered plans of a deleted/closed
@@ -3794,7 +3765,7 @@ class TpuSearchService:
             # exceeds the cap the query plans instead and the compiled
             # kernel serves later probes) further tightened by the
             # request's own deadline. A stalled kernel must never pin an
-            # HTTP thread for minutes (VERDICT r2 weak: 300s wait).
+            # HTTP thread for minutes.
             wait = self.batch_timeout_s
             deadline_limited = (timeout_s is not None
                                 and timeout_s < self.batch_timeout_s)
@@ -3915,8 +3886,8 @@ class TpuSearchService:
         """Build the (index, field) resident pack and compile every
         steady-state serving signature NOW, instead of on the first
         query (the reference's index-warmer seam, `IndicesWarmer` /
-        `index.warmer`; VERDICT r3 #3: first-compile must not stall or
-        degrade production traffic). Returns timing info.
+        `index.warmer`: first-compile must not stall or degrade
+        production traffic). Returns timing info.
 
         The signature table is DEDUPED by canonical jit signature
         (batch bucket × candidate-k bucket × width/prefix) — the raw
@@ -4012,8 +3983,7 @@ class TpuSearchService:
                 table.append((b_bucket, k, None, PREFIX_CAP3))
         # both kernel variants warm when packed sorting is on: "ref"
         # stays reachable (per-launch packability fallback, the runtime
-        # toggle, the bench A/B) and must never cold-compile inside the
-        # batch completer. Pruned kernels never pack their gid keys, so
+        # toggle) and must never cold-compile inside the batch completer. Pruned kernels never pack their gid keys, so
         # their "packed" variant differs only in the top-k reduction.
         # compressed packs have no impact-sorted copy — the pruned table
         # is unreachable, and the exact kernel runs the compressed pair
@@ -4133,7 +4103,6 @@ class TpuSearchService:
                 "kernel": {"packed_sort": KERNEL_CONFIG["packed_sort"],
                            "compressed_pack":
                                KERNEL_CONFIG["compressed_pack"],
-                           "pallas": KERNEL_CONFIG["pallas"],
                            "variants": KERNEL_VARIANT_COUNTS.counts()},
                 "launches": LAUNCH_COUNTS.counts(),
                 "route": ROUTE_COUNTS.counts(),

@@ -113,10 +113,7 @@ def _multiprocess_timeout(request):
 
 @pytest.fixture(autouse=True)
 def _compressed_pack_slack_guard(request, monkeypatch):
-    # pallas tests read the same compressed streams through the same
-    # dynamic_slice windows — identical clamp trap, identical guard
-    if (request.node.get_closest_marker("compressed_pack") is None
-            and request.node.get_closest_marker("pallas") is None):
+    if request.node.get_closest_marker("compressed_pack") is None:
         yield
         return
     from elasticsearch_tpu.ops import sparse as _sparse

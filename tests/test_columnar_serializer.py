@@ -66,6 +66,44 @@ def test_fast_json_hostile_ids_round_trip(corpus):
         tpu.close()
 
 
+def test_columnar_block_equals_per_hit_dicts_over_two_shards(tmp_path,
+                                                             seeded_np):
+    """100 hits from two shards: the columnar block parses to the hits
+    the per-hit dict path gives, in the same order."""
+    words = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta",
+             "theta", "iota", "kappa", "lamda", "mu"]
+    svc = IndicesService(str(tmp_path))
+    idx = svc.create_index(
+        "corpus", Settings.of({"index": {"number_of_shards": 2}}),
+        {"properties": {"body": {"type": "text"}}})
+    for i in range(300):
+        picks = seeded_np.integers(0, len(words),
+                                   int(seeded_np.integers(4, 14)))
+        doc_id = f"d{i}"
+        idx.shard(idx.shard_for_id(doc_id)).apply_index_on_primary(
+            doc_id, {"body": " ".join(words[int(w)] for w in picks)})
+    idx.refresh()
+    tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0)
+    try:
+        resp = _search(svc, tpu, {
+            "query": {"match": {"body": "alpha beta gamma delta"}},
+            "size": 100, "_source": False})
+        hits = resp["hits"]["hits"]
+        assert isinstance(hits, ColumnarHits)
+        assert len(hits) > 20
+        assert len({int(r) for r in hits.rows}) == 2  # both shards
+        args = ("corpus", hits.resident, hits.scores, hits.rows,
+                hits.ords, False, False, False)
+        fast = json.loads(ColumnarHits(*args).to_json())
+        slow = json.loads(json.dumps(assemble_hits_list(*args)))
+        assert [h["_id"] for h in fast] == [h["_id"] for h in slow]
+        assert [h["_score"] for h in fast] == \
+               pytest.approx([h["_score"] for h in slow])
+    finally:
+        tpu.close()
+        svc.close()
+
+
 def test_dumps_response_matches_plain_dumps(corpus):
     svc, idx = corpus
     tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0)

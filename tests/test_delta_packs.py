@@ -76,6 +76,39 @@ def test_append_only_refresh_rides_a_delta(svc, seeded_np):  # noqa: F811
         tpu.close()
 
 
+def test_a_teardown_under_a_delta_build_orphans_the_delta(
+        svc, seeded_np, monkeypatch):  # noqa: F811
+    """The supervisor's teardown takes no build lock: where it drops the
+    base while a reader builds a delta on it, the reader is answered
+    from a full rebuild, and the breaker holds that base and no more."""
+    idx = make_corpus(svc, seeded_np, name="dp1b", docs=60)
+    breaker = CircuitBreaker("hbm", 1 << 30)
+    tpu = _tpu(breaker=breaker)
+    try:
+        q = dsl.MatchQuery(field="body", query="alpha sigma")
+        assert tpu.try_search(idx, q, k=100) is not None
+        _append(idx, 0, 25)
+        idx.refresh()
+        build_delta = tpu.packs._build_delta
+
+        def teardown_then_build(*args):
+            assert tpu.packs.invalidate_all() == [("dp1b", "body")]
+            assert breaker.used == 0
+            return build_delta(*args)
+
+        monkeypatch.setattr(tpu.packs, "_build_delta", teardown_then_build)
+        r1 = tpu.try_search(idx, q, k=100)
+        assert r1 is not None
+        assert {f"s{i}" for i in range(25)} <= set(_ids(r1))
+        assert tpu.packs.misses == 2 and tpu.delta_stats.appends == 0
+        st = tpu.stats()
+        assert st["deltas"]["packs"] == 0
+        resident = tpu.packs.peek(("dp1b", "body"))
+        assert breaker.used == resident.hbm_bytes > 0
+    finally:
+        tpu.close()
+
+
 def test_tombstones_force_full_rebuild(svc, seeded_np):  # noqa: F811
     idx = make_corpus(svc, seeded_np, name="dp2", docs=40)
     tpu = _tpu()

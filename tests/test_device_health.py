@@ -219,6 +219,42 @@ def node(tmp_path):
     n.close()
 
 
+def test_a_remesh_under_a_pack_build_builds_again_on_the_new_mesh(
+        node, monkeypatch):
+    """The supervisor's remesh takes no build lock. A build it overtakes
+    ends on the mesh it started on: that pack is released, not swapped
+    in, and the key is built again on the mesh that serves."""
+    import jax
+
+    from elasticsearch_tpu.common.breaker import CircuitBreaker
+    from elasticsearch_tpu.parallel import distributed as dist
+    from elasticsearch_tpu.search.tpu_service import IndexPackCache
+
+    breaker = CircuitBreaker("hbm", 1 << 30)
+    cache = IndexPackCache(breaker=breaker)
+    full = cache.mesh
+    survivors = make_mesh(devices=jax.devices()[:7])
+    build = dist.build_stacked_pack
+    remeshes = []
+
+    def remesh_then_build(*args, **kw):
+        if not remeshes:  # between the build's reading of the mesh
+            remeshes.append(cache.invalidate_all())  # and its placing
+            cache.set_mesh(survivors)
+        return build(*args, **kw)
+
+    monkeypatch.setattr(dist, "build_stacked_pack", remesh_then_build)
+    idx = node.indices.indices["lib"]
+    entry = cache.get(idx, "title")
+    assert remeshes == [[]] and cache.misses == 1
+    assert entry.mesh is survivors and entry.mesh is not full
+    assert entry.pack.num_shards % 7 == 0
+    assert cache.peek(("lib", "title")) is entry
+    assert breaker.used == entry.hbm_bytes > 0
+    assert cache.invalidate_all() == [("lib", "title")]
+    assert breaker.used == 0
+
+
 class TestShedContract:
     def test_exception_shape_and_retry_after_header(self):
         exc = PackShedException("pack shed for N-1 headroom",

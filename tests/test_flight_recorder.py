@@ -3,8 +3,8 @@ monotonicity, journal rotation/retention on disk, incident snapshot
 capture with debounce and deterministic flush, context stamping
 (trace_id/tenant), the near-free recorder-off path, the REST query
 surface (`/_tpu/events`, `/_tpu/incidents`), SampleRing exemplars in
-`/_tpu/stats`, the bench regression gate, and byte-compatibility of the
-new payloads across the serving-front wire path."""
+`/_tpu/stats`, and byte-compatibility of the new payloads across the
+serving-front wire path."""
 
 from __future__ import annotations
 
@@ -445,90 +445,3 @@ def test_front_wire_incident_and_exemplar_payloads_byte_compatible():
                      "exemplar_trace_id": "t-xyz"}}}
     spliced, direct = _roundtrip(stats)
     assert spliced == direct
-
-
-# ---------------------------------------------------------------------
-# bench regression gate
-# ---------------------------------------------------------------------
-
-def _bench_round(stages_p99, kernel_ms, rest_qps=None):
-    parsed = {"stages": {k: {"seconds": 1.0, "count": 10, "p99_ms": v}
-                         for k, v in stages_p99.items()},
-              "kernel_compare": {k: {"device_ms_per_query": v}
-                                 for k, v in kernel_ms.items()}}
-    if rest_qps is not None:
-        parsed["rest_qps"] = rest_qps
-    return {"n": 1, "cmd": "x", "rc": 0, "tail": "", "parsed": parsed}
-
-
-def test_bench_compare_gates_regressions(tmp_path):
-    from elasticsearch_tpu.benchmark import compare
-    old = tmp_path / "BENCH_r01.json"
-    new = tmp_path / "BENCH_r02.json"
-    old.write_text(json.dumps(_bench_round(
-        {"kernel": 10.0, "assemble": 2.0}, {"packed": 5.0})))
-    # within threshold → OK
-    new.write_text(json.dumps(_bench_round(
-        {"kernel": 11.0, "assemble": 2.1}, {"packed": 5.5})))
-    assert compare.main([str(old), str(new)]) == 0
-    assert compare.main([str(tmp_path)]) == 0  # auto-discovery
-    # >15% p99 regression → FAIL
-    new.write_text(json.dumps(_bench_round(
-        {"kernel": 12.0, "assemble": 2.0}, {"packed": 5.0})))
-    assert compare.main([str(old), str(new)]) == 1
-    assert compare.main([str(tmp_path)]) == 1
-    # >15% device-ms regression → FAIL
-    new.write_text(json.dumps(_bench_round(
-        {"kernel": 10.0, "assemble": 2.0}, {"packed": 6.0})))
-    assert compare.main([str(old), str(new)]) == 1
-    # metrics present in only one round are ignored (old rounds
-    # predate the kernel-compare block)
-    new.write_text(json.dumps(_bench_round(
-        {"kernel": 10.0, "brand_new_stage": 99.0}, {})))
-    assert compare.main([str(old), str(new)]) == 0
-
-
-def test_bench_compare_rest_qps_and_skip_notes(tmp_path, capsys):
-    from elasticsearch_tpu.benchmark import compare
-    old = tmp_path / "BENCH_r01.json"
-    new = tmp_path / "BENCH_r02.json"
-    # rest_qps gates with the sign INVERTED: a throughput drop is the
-    # regression, a rise never is
-    old.write_text(json.dumps(_bench_round(
-        {}, {}, rest_qps={"single_process": 100.0, "fronts": 200.0})))
-    new.write_text(json.dumps(_bench_round(
-        {}, {}, rest_qps={"single_process": 80.0, "fronts": 400.0})))
-    assert compare.main([str(old), str(new)]) == 1
-    new.write_text(json.dumps(_bench_round(
-        {}, {}, rest_qps={"single_process": 95.0, "fronts": 400.0})))
-    assert compare.main([str(old), str(new)]) == 0
-    capsys.readouterr()
-    # a round missing the rest_qps phase entirely, and rounds with
-    # differing kernel-variant sets, skip with a note — no KeyError,
-    # no phantom regression
-    old.write_text(json.dumps(_bench_round(
-        {"kernel": 10.0}, {"packed": 5.0, "pallas": 2.0},
-        rest_qps={"single_process": 100.0, "fronts": 200.0})))
-    new.write_text(json.dumps(_bench_round(
-        {"kernel": 10.5}, {"packed": 5.1})))
-    assert compare.main([str(old), str(new)]) == 0
-    out = capsys.readouterr().out
-    assert "note — skipped 3 metric(s) only in the old round" in out
-    assert "kernel.pallas.device_ms_per_query" in out
-    assert "rest_qps.single_process" in out
-    # ... and when NOTHING is shared, the notes still explain why
-    new.write_text(json.dumps(_bench_round({"fresh": 1.0}, {})))
-    assert compare.main([str(old), str(new)]) == 0
-    out = capsys.readouterr().out
-    assert "nothing to gate" in out and "note — skipped" in out
-
-
-def test_bench_compare_graceful_with_missing_rounds(tmp_path):
-    from elasticsearch_tpu.benchmark import compare
-    assert compare.main([str(tmp_path)]) == 0  # no rounds at all
-    (tmp_path / "BENCH_r01.json").write_text("{}")
-    assert compare.main([str(tmp_path)]) == 0  # one round
-    # suffixed variants (different config) are never auto-compared
-    (tmp_path / "BENCH_r01_scale.json").write_text("not json")
-    assert compare.find_rounds(str(tmp_path)) == \
-        [str(tmp_path / "BENCH_r01.json")]

@@ -111,6 +111,51 @@ class TestServingCacheCoherence:
         finally:
             tpu.close()
 
+    def test_cached_repeat_is_a_hit_that_lowers_nothing(self, svc,
+                                                        seeded_np,
+                                                        monkeypatch):
+        """What the plan cache and the slot memo save is counted, not
+        timed: the repeat of a shape is one hit, no miss, no call of
+        `lower_query`, and `_slots_needed` answers from the memo
+        without walking a vocab."""
+        idx = make_corpus(svc, seeded_np, docs=300)
+        lowered = []
+        real_lower = svc_mod.lower_query
+
+        def counting_lower(q, mapper):
+            lowered.append(q)
+            return real_lower(q, mapper)
+
+        monkeypatch.setattr(svc_mod, "lower_query", counting_lower)
+        tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0)
+        try:
+            coordinator.search(svc, "corpus", dict(BODY), tpu_search=tpu)
+            first = dict(tpu.plans.stats())
+            n_lowered = len(lowered)
+            assert first["misses"] == 1 and n_lowered >= 1
+            coordinator.search(svc, "corpus", dict(BODY), tpu_search=tpu)
+            repeat = tpu.plans.stats()
+            assert repeat["hits"] == first["hits"] + 1
+            assert repeat["misses"] == first["misses"]
+            assert len(lowered) == n_lowered
+            assert tpu.stats()["stages"]["lower"]["count"] == 2
+
+            resident = tpu.packs.get(idx, "body")
+            key = ("corpus", idx.mapper.generation,
+                   plan_key(dsl.MatchQuery(field="body",
+                                           query="alpha beta")))
+            flat, rk = tpu.plans.get(key)
+            assert rk == resident.reader_key
+            slots = svc_mod._slots_needed(resident, flat)
+            memo_key = tuple(flat.terms)
+            assert resident.slots_memo[memo_key] == slots
+            # the memo is what answers a repeat: a marked entry comes
+            # back as it was put
+            resident.slots_memo[memo_key] = slots + 7
+            assert svc_mod._slots_needed(resident, flat) == slots + 7
+        finally:
+            tpu.close()
+
     def test_mapping_update_changes_generation_key(self, svc, seeded_np):
         idx = make_corpus(svc, seeded_np)
         tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0)

@@ -306,20 +306,27 @@ def test_lost_primary_without_replica_goes_red_not_empty(cluster):
     holder = next(n for n in cluster
                   if n.node_id == state.primary("frag", 0).node_id)
     _handle(cluster[0], "PUT", "/frag/_doc/1", body={"a": 1})
+    holder_id = holder.node_id
     holder.close()
     live = [n for n in cluster if n is not holder]
-    # the shard must go red (unassigned), never a fresh empty primary
-    deadline = time.monotonic() + 20
+    # the shard must go red (unassigned), never a fresh empty primary.
+    # The master removes the node in one state update and fails the
+    # node's copies in the next: wait for both (a liveness bound: fault
+    # detection plus, where the holder was the master, an election)
+    def primary():
+        return live[0].cluster.applied_state().primary("frag", 0)
+
+    deadline = time.monotonic() + 60
     while time.monotonic() < deadline:
         h = live[0].cluster.health()
-        if h["status"] == "red" and h["number_of_nodes"] == 2:
+        if (h["status"] == "red" and h["number_of_nodes"] == 2
+                and primary().node_id != holder_id):
             break
         time.sleep(0.1)
     h = live[0].cluster.health()
-    assert h["status"] == "red", h
-    state = live[0].cluster.applied_state()
-    p = state.primary("frag", 0)
-    assert p.node_id is None or p.state != "STARTED"
+    assert h["status"] == "red" and h["number_of_nodes"] == 2, h
+    p = primary()
+    assert p.node_id is None or p.state != "STARTED", p
 
 
 def test_replica_reads_spread_and_fail_over(cluster):

@@ -251,7 +251,7 @@ def assert_variants_identical(flat_docs, flat_imp, rows, mins, d_pad, k,
 
 class TestPackedParity:
     """Packed single-key variant vs reference: the acceptance bar is
-    bit-identical scores, doc ids, and totals (ISSUE 4 / PERF round 8)."""
+    bit-identical scores, doc ids, and totals."""
 
     def test_parity_small(self, seeded_np):
         # tier-1 sized: a handful of random corpora incl. tie-heavy
@@ -338,10 +338,11 @@ class TestPackedParity:
 
 
 class TestTotals:
-    def test_totals_exceed_k_both_variants(self, seeded_np):
+    @pytest.mark.parametrize("variant", sparse.KERNEL_VARIANTS)
+    def test_totals_exceed_k_both_variants(self, seeded_np, variant):
         """TotalHits must be the FULL match count, computed before top-k
         truncation (regression: with_totals used to see only k rows),
-        and identical for both variants vs the numpy oracle."""
+        and identical for every variant vs the numpy oracle."""
         d_pad = 600
         # deterministic postings: term t matches 200 docs starting at 3t
         sizes = [200, 200, 200]
@@ -362,19 +363,18 @@ class TestTotals:
         expected = brute_force(rows, flat_docs, flat_imp, d_pad, mins)
         k = 5  # far below the expected match counts
         assert len(expected[0]) > k and len(expected[1]) > k
-        for variant in sparse.KERNEL_VARIANTS:
-            _, _, totals = run_kernel(flat_docs, flat_imp, rows, mins,
-                                      d_pad, k, with_counts=True,
-                                      with_totals=True, variant=variant,
-                                      ext=ext)
-            assert totals.tolist() == [len(e) for e in expected]
+        _, _, totals = run_kernel(flat_docs, flat_imp, rows, mins,
+                                  d_pad, k, with_counts=True,
+                                  with_totals=True, variant=variant,
+                                  ext=ext)
+        assert totals.tolist() == [len(e) for e in expected]
 
 
 def host_skip_rate(plan, code16, block_max, blk, slot_terms, k):
     """Numpy replica of the kernel's block-max skip decision (same
     formula, same clamps) → fraction of valid 128-lane groups skipped.
-    The device mask isn't observable from outside the jit, so tests and
-    the bench measure engagement through this mirror."""
+    The device mask isn't observable from outside the jit, so tests
+    measure engagement through this mirror."""
     blksz = sparse.COMPRESSED_BLOCK
     n_grp = (plan.max_len + blksz - 1) // blksz
     r, t = plan.starts.shape
@@ -667,48 +667,23 @@ class TestDeltaDocStream:
         assert sparse.delta_doc_reason(flat_docs, rs) is None
 
     @pytest.mark.compressed_pack
-    def test_delta_parity_all_variants(self, seeded_np):
+    @pytest.mark.parametrize("min_count, chunk_cap", [
+        (1, 4096), (3, 4096),
+        # tiny chunks: slot cursors land on arbitrary (dbs, dlo) splits
+        (1, 64)])
+    def test_delta_parity_all_variants(self, seeded_np, min_count,
+                                       chunk_cap):
         """A delta-eligible corpus pushes every compressed variant
-        (incl. pallas) through the in-kernel u8 decode; results must
-        stay bit-identical to the reference, chunked or not."""
+        through the in-kernel u8 decode; results must stay
+        bit-identical to the reference, chunked or not."""
         d_pad = 256
         flat_docs, flat_imp, ext = make_flat(seeded_np, 5, d_pad, 200)
         rs = row_starts_of(ext, flat_docs.size)
         assert sparse.delta_doc_reason(flat_docs, rs) is None
         ws = [1.3, 0.7, 2.2, 0.4, 1.9]
         rows = [[(ext[t][0], ext[t][1], ws[t], t) for t in range(5)]]
-        for mc in (1, 3):
-            assert_variants_identical(flat_docs, flat_imp, rows, [mc],
-                                      d_pad, 40, ext=ext)
-        # tiny chunks: slot cursors land on arbitrary (dbs, dlo) splits
-        assert_variants_identical(flat_docs, flat_imp, rows, [1],
-                                  d_pad, 40, ext=ext, chunk_cap=64)
-
-
-@pytest.mark.pallas
-class TestPallasKernel:
-    """variant="pallas" dispatch seams. Bitwise parity itself rides the
-    5-variant sweeps above ("pallas" is in COMPRESSED_VARIANTS), which
-    run the kernel under interpret=True on the CPU mesh."""
-
-    def test_pallas_in_variant_tuples(self):
-        assert "pallas" in sparse.KERNEL_VARIANTS
-        assert "pallas" in sparse.COMPRESSED_VARIANTS
-
-    def test_pallas_totals_and_counts(self, seeded_np):
-        d_pad = 500
-        flat_docs, flat_imp, ext = make_flat(seeded_np, 3, d_pad, 200)
-        rows = [[(ext[t][0], ext[t][1], 1.5, t) for t in range(3)]]
-        rv, rd, rt = run_kernel(flat_docs, flat_imp, rows, [2], d_pad,
-                                30, with_counts=True, with_totals=True,
-                                variant="ref")
-        pv, pd_, pt = run_kernel(flat_docs, flat_imp, rows, [2], d_pad,
-                                 30, with_counts=True, with_totals=True,
-                                 variant="pallas", ext=ext)
-        np.testing.assert_array_equal(rv.view(np.uint32),
-                                      pv.view(np.uint32))
-        np.testing.assert_array_equal(rd, pd_)
-        np.testing.assert_array_equal(rt, pt)
+        assert_variants_identical(flat_docs, flat_imp, rows, [min_count],
+                                  d_pad, 40, ext=ext, chunk_cap=chunk_cap)
 
 
 class TestHierarchicalTopK:
@@ -735,6 +710,76 @@ class TestHierarchicalTopK:
             fv, fp = jax.lax.top_k(score, 5)
             np.testing.assert_array_equal(np.asarray(hv), np.asarray(fv))
             np.testing.assert_array_equal(np.asarray(hp), np.asarray(fp))
+
+
+class TestServingWidth:
+    """The 32-slot full-precision serving bucket, 32 x CHUNK_CAP =
+    131,072 lanes a row: what holds there is checked as equalities. A
+    speed is a cell's to read (`device_full_s32_ms_per_launch`)."""
+
+    ROWS = 2
+    T_SLOTS = 32
+    MAX_LEN = 4096
+    K = 128
+
+    def test_packed_bit_identical_to_ref_at_serving_width(self, seeded_np):
+        d_pad, df = 60000, 3500
+        flat_len = (self.T_SLOTS + 1) * self.MAX_LEN  # chunk-cap slack
+        fd = np.full(flat_len, d_pad, dtype=np.int32)
+        fi = np.zeros(flat_len, dtype=np.float32)
+        starts = np.zeros((self.ROWS, self.T_SLOTS), np.int32)
+        lengths = np.full((self.ROWS, self.T_SLOTS), df, np.int32)
+        weights = np.zeros((self.ROWS, self.T_SLOTS), np.float32)
+        for t in range(self.T_SLOTS):
+            pos = t * df
+            fd[pos:pos + df] = np.sort(seeded_np.choice(
+                d_pad, df, replace=False)).astype(np.int32)
+            fi[pos:pos + df] = seeded_np.uniform(
+                0.1, 1.0, df).astype(np.float32)
+            starts[:, t] = pos
+            weights[:, t] = seeded_np.uniform(0.5, 3.0)
+        operands = tuple(jnp.asarray(x) for x in (
+            fd, fi, starts, lengths, weights,
+            np.ones(self.ROWS, np.int32)))
+
+        def run(variant):
+            return [np.asarray(x) for x in sparse.sorted_merge_topk(
+                *operands, max_len=self.MAX_LEN, d_pad=d_pad, k=self.K,
+                t_window=self.T_SLOTS, with_counts=False,
+                with_totals=True, variant=variant)]
+
+        rv, rd, rt = run("ref")
+        pv, pd_, pt = run("packed")
+        np.testing.assert_array_equal(rv.view(np.uint32),
+                                      pv.view(np.uint32))
+        np.testing.assert_array_equal(rd, pd_)
+        np.testing.assert_array_equal(rt, pt)
+
+    def test_topk_dispatch_equals_flat_and_splits_only_on_tpu(
+            self, seeded_np, monkeypatch):
+        import jax
+        width = self.T_SLOTS * self.MAX_LEN
+        score = jnp.asarray(seeded_np.normal(
+            size=(self.ROWS, width)).astype(np.float32))
+        fv, fp = jax.lax.top_k(score, self.K)
+        hv, hp = sparse.hierarchical_top_k(score, self.K)
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(hv))
+        np.testing.assert_array_equal(np.asarray(fp), np.asarray(hp))
+
+        def top_k_operand_shapes():
+            jaxpr = jax.make_jaxpr(
+                lambda s: sparse.hierarchical_top_k(s, self.K))(score)
+            return [eqn.invars[0].aval.shape for eqn in jaxpr.eqns
+                    if eqn.primitive.name == "top_k"]
+
+        # the choice is made at trace time from the backend: flat here
+        # (XLA:CPU's TopK is already a selection), per block where
+        # top_k lowers to a sort of the full width
+        assert top_k_operand_shapes() == [(self.ROWS, width)]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert top_k_operand_shapes() == [
+            (self.ROWS, self.T_SLOTS, self.MAX_LEN),
+            (self.ROWS, self.T_SLOTS * self.K)]
 
 
 class TestPlanSlots:

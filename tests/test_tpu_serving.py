@@ -194,9 +194,8 @@ class TestEquivalence:
 
 class TestPerPackQueues:
     def test_slow_pack_does_not_block_other_packs(self, monkeypatch):
-        """VERDICT r2 weak #10: pack A's kernel launch (e.g. a compile
-        stall) must not delay pack B's queries — each pack batches on
-        its own worker."""
+        """Pack A's kernel launch (e.g. a compile stall) must not delay
+        pack B's queries — each pack batches on its own worker."""
         import threading
         import time as _time
 
@@ -584,8 +583,8 @@ def test_grouped_phase_a_many_segments(svc, seeded_np):
 
 
 class TestKernelVariant:
-    """Round-8 packed-sort knob: lowering-time variant choice, the
-    runtime toggle, and the stats surface (PERF.md round 8)."""
+    """The packed-sort knob: lowering-time variant choice, the
+    runtime toggle, and the stats surface."""
 
     def test_choose_kernel_variant_gates(self):
         from elasticsearch_tpu.ops.sparse import PACKED_DOC_LIMIT
@@ -601,7 +600,7 @@ class TestKernelVariant:
         # setting off → fallback regardless of packability
         assert choose_kernel_variant(1000, ok_w, enabled=False) == "ref"
 
-    def test_choose_kernel_variant_compressed_and_pallas(self):
+    def test_choose_kernel_variant_compressed(self):
         from elasticsearch_tpu.search.planner import choose_kernel_variant
         ok_w = np.array([0.5, 2.0], dtype=np.float32)
         # compressed pack: packable weights → quantized-sort variant,
@@ -611,13 +610,8 @@ class TestKernelVariant:
                                      compressed=True) == "compressed"
         assert choose_kernel_variant(
             1000, np.array([1e31]), compressed=True) == "compressed_exact"
-        # pallas rides the compressed gate
-        assert choose_kernel_variant(1000, ok_w, compressed=True,
-                                     pallas=True) == "pallas"
-        # hostile weights beat the pallas request (exact path first)
         assert choose_kernel_variant(
-            1000, np.array([-1.0]), compressed=True,
-            pallas=True) == "compressed_exact"
+            1000, np.array([-1.0]), compressed=True) == "compressed_exact"
 
     @staticmethod
     def _moved(before, after, variant):
@@ -666,31 +660,3 @@ class TestKernelVariant:
             # restore the defaults for the rest of the suite
             svc_mod.KERNEL_CONFIG["packed_sort"] = True
             svc_mod.KERNEL_CONFIG["compressed_pack"] = True
-
-
-def test_pallas_knob_refused_at_node_start_on_a_tpu_backend(tmp_path,
-                                                            monkeypatch):
-    """The Pallas TPU lowering refuses the kernel (PERF.md "Bring-up on
-    the chip"); with the knob on, every query would be answered by the
-    planner behind an HTTP 200 — so a TPU-backed node fails at start,
-    with the compiler's reason, and the runtime setter refuses too."""
-    import jax
-
-    from elasticsearch_tpu.common.errors import IllegalArgumentException
-    from elasticsearch_tpu.common.settings import Settings
-    from elasticsearch_tpu.node import Node
-    from elasticsearch_tpu.ops import pallas_merge
-
-    on = Settings.of({"search.tpu_serving.kernel.pallas": True})
-    node = Node(str(tmp_path / "cpu"), settings=on)  # interpreter: fine
-    try:
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        with pytest.raises(IllegalArgumentException,
-                           match="Pallas TPU lowering"):
-            node.tpu_search.set_kernel_pallas(True)
-        node.tpu_search.set_kernel_pallas(False)
-    finally:
-        node.close()
-    with pytest.raises(IllegalArgumentException) as err:
-        Node(str(tmp_path / "tpu"), settings=on)
-    assert pallas_merge.TPU_REFUSAL in str(err.value)

@@ -202,11 +202,11 @@ def test_a_lone_query_on_an_idle_queue_pays_window_s(monkeypatch):
 def test_a_closed_loop_of_three_trains_callers_rides_full_trains(
         monkeypatch, max_batch):
     """3 x max_batch callers, each back `return_s` after its answer, on a
-    serial device that takes 10 x as long for a train whatever it carries:
+    serial device that takes 20 x as long for a train whatever it carries:
     taken early, the callers spread over four trains (three launched, one
     taken and waiting: fill 0.75); taken as late as the device allows they
     ride full ones."""
-    device_s, return_s, run_s = 0.1, 0.01, 1.5
+    device_s, return_s, run_s = 0.2, 0.01, 3.0
     lock = threading.Lock()
     free_at = [0.0]
     trains = []
@@ -228,11 +228,12 @@ def test_a_closed_loop_of_three_trains_callers_rides_full_trains(
     batcher = MicroBatcher(window_s=0.0, max_batch=max_batch)
     exits_before = HOLD_EXIT_COUNTS.counts()
     pack = object()
-    t0 = time.monotonic()
-    stop_at = t0 + run_s
+    go = threading.Event()
+    stop_at = [0.0]
 
     def caller():
-        while time.monotonic() < stop_at:
+        go.wait()
+        while time.monotonic() < stop_at[0]:
             assert batcher.submit(pack, None, 1).result(timeout=10.0) == "r"
             time.sleep(return_s)
 
@@ -241,12 +242,17 @@ def test_a_closed_loop_of_three_trains_callers_rides_full_trains(
     try:
         for t in callers:
             t.start()
+        # the clock starts with every caller alive: on a loaded machine
+        # 144 threads take longer to start than the ramp allows them
+        t0 = time.monotonic()
+        stop_at[0] = t0 + run_s
+        go.set()
         for t in callers:
             t.join(timeout=30.0)
         # the ramp (the first trains go as the idle rule sends them) and the
         # drain (callers leaving) are not the closed loop
         steady = [n for at, n in trains
-                  if t0 + 4 * device_s <= at < stop_at - 2 * device_s]
+                  if t0 + 4 * device_s <= at < stop_at[0] - 2 * device_s]
         assert len(steady) >= 5, trains
         assert sum(steady) / len(steady) >= 0.95 * max_batch, steady
         # one reason a train, and the three sum to the trains executed
