@@ -616,8 +616,9 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                EXACT_ENTRY_COUNTS, HOLD_EXIT_COUNTS, KERNEL_CONFIG,
-                KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS, ROUTE_COUNTS)
+                EXACT_ENTRY_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
+                KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
+                ROUTE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
@@ -631,13 +632,17 @@ class Node:
             for labels, counter in LAUNCH_COUNTS.items():
                 yield ("kernel.launches", labels, counter)
             # queries by the way the launch routing sent them, and the
-            # exact launches' posting entries, real and as dispatched:
+            # exact and the full-postings launches' posting entries, real
+            # and as dispatched:
             # es_tpu_kernel_route_total{route=...},
-            # es_tpu_kernel_exact_entries_total{kind=...}
+            # es_tpu_kernel_exact_entries_total{kind=...},
+            # es_tpu_kernel_full_entries_total{kind=...}
             for labels, counter in ROUTE_COUNTS.items():
                 yield ("kernel.route", labels, counter)
             for labels, counter in EXACT_ENTRY_COUNTS.items():
                 yield ("kernel.exact_entries", labels, counter)
+            for labels, counter in FULL_ENTRY_COUNTS.items():
+                yield ("kernel.full_entries", labels, counter)
             # trains by the reason the launch thread's hold ended:
             # es_tpu_batcher_hold_exit_total{hold_exit=...}
             for labels, counter in HOLD_EXIT_COUNTS.items():

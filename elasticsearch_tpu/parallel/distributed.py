@@ -31,7 +31,7 @@ import dataclasses
 import math
 import threading
 from functools import lru_cache, partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1166,6 +1166,27 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
         in_specs=(spec_post, spec_post, spec_post, spec_post, spec_sbt),
         out_specs=P(DATA_AXIS, None))
     return jax.jit(_named(mapped, name))
+
+
+@lru_cache(maxsize=256)
+def _compiled(fn, structs):
+    return fn.lower(*structs).compile()
+
+
+def compile_pruned_program(fn, mesh: Mesh, arrays: Sequence[Any], rows: int,
+                           width: int):
+    """`fn` (of `make_pruned_search`) compiled ahead of time for the
+    resident `arrays` it is called with and an operand of `rows` queries
+    × `width` columns (`pack_pruned_operands`): the executable, kept for
+    the process. Nothing runs on the device, and `jax.jit`'s own cache
+    does not learn of it: a launch that must never compile calls what
+    this returns, with arrays of exactly these shapes and shardings."""
+    sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
+    structs = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+                    for a in arrays)
+    ops = jax.ShapeDtypeStruct((arrays[0].shape[0], rows, width), jnp.float32,
+                               sharding=sbt)
+    return _compiled(fn, structs + (ops,))
 
 
 def unpack_pruned(packed: np.ndarray, k_keep: Optional[int] = None):

@@ -36,9 +36,10 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import logging
+import math
 import threading
 import time
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -382,6 +383,10 @@ class ResidentPack:
         default_factory=dict)
     # number of terms → _widest_slots result, on the same terms
     widest_slots_memo: Dict[int, int] = dataclasses.field(
+        default_factory=dict)
+    # (mesh, k bucket, variant, max_batch) → the executables of
+    # `full_program_set`, by (slots, rows): `_ready_full_programs`
+    full_ready: Dict[Tuple, Dict[Tuple[int, int], Any]] = dataclasses.field(
         default_factory=dict)
     # compressed resident format: host-side 16-bit
     # streams + residual tables. When set, device_arrays is the 5-tuple
@@ -1465,7 +1470,8 @@ class _PackQueue:
                                 self.resident, [p.flat for p in taken],
                                 k=max(p.k for p in taken),
                                 mesh=mesh,
-                                stages=batcher.stages)
+                                stages=batcher.stages,
+                                max_batch=batcher.max_batch)
                     finally:
                         if wd is not None:
                             wd.end(token)
@@ -1748,7 +1754,23 @@ class FlatQueryResult:
 # exact on validity failures. Measured at 2.6M docs: exact-at-width
 # ≈ prefix-at-the-same-width minus the whole rescore phase, and the
 # 23%-invalid escalation storm of prefix@16k disappears.
-FULL_SLOT_BUCKETS = (32, 128)   # sort widths 131k / 524k (x CHUNK_CAP)
+FULL_SLOT_BUCKETS = (16, 32, 128)   # sort widths 65k / 131k / 524k a shard row
+#: the row buckets a rung launches at. Every loaded program keeps several
+#: MB of HBM for its code (PERF.md section 5), so the ladder has a
+#: program only where it pays: the narrow rung where a launch is tall,
+#: the middle one where it is short (a taller group goes in chunks of
+#: eight rows: a launch costs the device about as much as the lanes it
+#: sorts), and the widest wherever its few queries fall, as before
+FULL_ROW_BUCKETS = {16: (64, 128), 32: (8,), 128: (8, 64, 128)}
+#: the rungs whose programs a node compiles before it serves from them
+#: (`full_program_set`); the widest compiles on first use
+FULL_READY_SLOTS = 32
+#: what a launch costs beyond its lanes, in slot-rows of one shard row
+#: (the unit of `_split_full_train`'s model: a row of the batch bucket ×
+#: a slot of the rung × a shard row the device holds): fixed device time
+#: (2.5 ms) and the launch thread's host time (6 ms) ÷ the device time
+#: of a slot-row (11-18 µs), from the chip table of PERF.md section 5
+FULL_LAUNCH_SLOT_ROWS = 512
 PREFIX_CAP = 4096               # base prefix for ad-hoc prefix runs
 PREFIX_CAP2 = 16384             # hot-tier prefix (queries over-width)
 PREFIX_CAP3 = 65536             # escalation prefix
@@ -1791,7 +1813,8 @@ LAUNCH_COUNTS = LabeledCounters("path")
 #: holds, and `exact_escalated` for a pruned query whose validity bound
 #: failed twice (counted under its pruned route before)
 #: → es_tpu_kernel_route_total
-ROUTES = ("pruned_full_s32", "pruned_full_s128", "pruned_hot",
+ROUTES = ("pruned_full_s16", "pruned_full_s32", "pruned_full_s128",
+          "pruned_hot",
           "exact_terms", "exact_min_count", "exact_k", "exact_no_impacts",
           "exact_escalated")
 ROUTE_COUNTS = LabeledCounters("route")
@@ -1799,6 +1822,10 @@ ROUTE_COUNTS = LabeledCounters("route")
 #: entries their static shape sorts, rows × slots × chunk length
 #: (`padded`) → es_tpu_kernel_exact_entries_total
 EXACT_ENTRY_COUNTS = LabeledCounters("kind")
+#: the same pair for the full-postings launches (`full_s<slots>`): Σ of
+#: the slots' lengths, and rows × slots × chunk length × shard rows
+#: dispatched → es_tpu_kernel_full_entries_total
+FULL_ENTRY_COUNTS = LabeledCounters("kind")
 #: trains by the reason their hold ended (`_PackQueue._hold`): one count
 #: a train taken → es_tpu_batcher_hold_exit_total
 HOLD_EXITS = ("full", "backlog_low", "idle_window")
@@ -1809,6 +1836,7 @@ for _label in HOLD_EXITS:
     HOLD_EXIT_COUNTS.child(_label)
 for _label in ("real", "padded"):
     EXACT_ENTRY_COUNTS.child(_label)
+    FULL_ENTRY_COUNTS.child(_label)
 
 
 def _choose_exact_variant(resident: ResidentPack, batch) -> str:
@@ -1896,6 +1924,139 @@ def _full_bucket(slots: int) -> Optional[int]:
     return None
 
 
+def _group_launches(n: int, slots: int, shard_rows: int
+                    ) -> Tuple[int, List[int]]:
+    """`n` queries at one rung → (modelled cost, the rows of each launch):
+    chunks of one of the rung's row buckets, whichever costs least by
+    Σ launches (rows × slots × `shard_rows` + FULL_LAUNCH_SLOT_ROWS)."""
+    return min(
+        (chunks * (rows * slots * shard_rows + FULL_LAUNCH_SLOT_ROWS),
+         [rows] * chunks)
+        for rows in FULL_ROW_BUCKETS[slots]
+        for chunks in (-(-n // rows),))
+
+
+def _split_full_train(groups: Dict[int, List[int]], shard_rows: int = 1
+                      ) -> List[Tuple[int, List[int]]]:
+    """A train's full-path queries, grouped by the narrowest rung that
+    holds each → the launches, as (slots, queries). A group launches at
+    its own rung or rides at a wider one (always correct: wider holds
+    everything), and a rung's queries go in chunks of one of its row
+    buckets (`_group_launches`); of the few such splits, the one that
+    costs least by the model: a launch takes the device about as long
+    as the lanes it sorts on the shard rows a device holds, whatever it
+    carries, and the host a constant (PERF.md section 5). The first of
+    equals keeps a group at its own rung."""
+    rungs = FULL_SLOT_BUCKETS
+    best_cost, best = math.inf, []
+    for ride in itertools.product(*(rungs[i:] for i in range(len(rungs)))):
+        merged: Dict[int, List[int]] = {}
+        for own, at in zip(rungs, ride):
+            if groups.get(own):
+                merged[at] = merged.get(at, []) + groups[own]
+        plans = [(b, idxs, *_group_launches(len(idxs), b, shard_rows))
+                 for b, idxs in sorted(merged.items())]
+        cost = sum(plan[2] for plan in plans)
+        if cost < best_cost:
+            best_cost, best = cost, plans
+    launches = []
+    for b, idxs, _cost, chunks in best:
+        at = 0
+        for rows in chunks:
+            launches.append((b, idxs[at:at + rows]))
+            at += rows
+    return launches
+
+
+# ---------------------------------------------------------------------------
+# the full-postings ladder's programs: a closed set, compiled before use
+# ---------------------------------------------------------------------------
+# A train's split depends on the needs of the queries it happens to hold,
+# so a rung × row bucket that no earlier train met can be the next one's:
+# a compile there would stall a train under load (seconds on the chip,
+# a cache replay included). The first full-path launch on a pack at a k
+# bucket therefore compiles every program of the rungs up to
+# FULL_READY_SLOTS ahead of time and keeps the executables, which is what
+# the launches call: nothing is executed to make them, and `jax.jit`'s
+# own cache, which would compile on a first call, is not on the path.
+
+@dataclasses.dataclass(frozen=True)
+class FullProgram:
+    """One compiled signature of the full-postings program on a pack."""
+
+    rows: int
+    slots: int
+    k_out: int
+    variant: str
+
+    @property
+    def label(self) -> str:
+        return f"full_s{self.slots}_b{self.rows}"
+
+
+def _pruned_k_out(k: int) -> int:
+    return 128 if _candidate_k(k) == 128 else 1024
+
+
+def full_program_set(resident: ResidentPack, k: int, max_batch: int = 128,
+                     variant: Optional[str] = None) -> List[FullProgram]:
+    """Every program of the rungs up to FULL_READY_SLOTS that
+    `launch_flat_batch` can dispatch on this pack at `k` for trains of up
+    to `max_batch` queries: a function of the pack (one that has the
+    pruned path at all), `k` and the node's constants alone."""
+    if resident.imp_device_arrays is None:
+        return []
+    reach = {_serving_bucket(n) for n in (1, 9, 65, max_batch)
+             if n <= max_batch}
+    return [FullProgram(rows, slots, _pruned_k_out(k),
+                        variant or _pruned_variant())
+            for slots in FULL_SLOT_BUCKETS if slots <= FULL_READY_SLOTS
+            for rows in FULL_ROW_BUCKETS[slots] if rows in reach]
+
+
+def _make_full_search(resident: ResidentPack, mesh, slots: int, k_out: int,
+                      variant: str):
+    pack = resident.pack
+    return dist.make_pruned_search(
+        mesh, max_len=dist.CHUNK_CAP, d_pad=pack.d_pad, p_pad=pack.p_pad,
+        c_cand=k_out, k_out=k_out, t_window=_PRUNE_WINDOW,
+        t_terms=PRUNE_MAX_TERMS, with_rescore=False, variant=variant,
+        pack_keys=False, name=f"full_s{slots}")
+
+
+_FULL_READY_LOCK = threading.Lock()
+
+
+def _ready_full_programs(resident: ResidentPack, mesh, k: int,
+                         max_batch: int, variant: str
+                         ) -> Dict[Tuple[int, int], Any]:
+    """(slots, rows) → the executable, for every member of
+    `full_program_set`: compiled at the first call for (pack, mesh, k
+    bucket, variant), on as many threads as there are members (XLA
+    compiles with the GIL released), remembered with the pack after."""
+    key = (mesh, _pruned_k_out(k), variant, max_batch)
+    with _FULL_READY_LOCK:
+        ready = resident.full_ready.get(key)
+        if ready is not None:
+            return ready
+        programs = full_program_set(resident, k, max_batch, variant)
+        arrays = resident.imp_device_arrays + tuple(resident.device_arrays)
+        fns = {p.slots: _make_full_search(resident, mesh, p.slots, p.k_out,
+                                          variant) for p in programs}
+
+        def compile_one(program: FullProgram):
+            return dist.compile_pruned_program(
+                fns[program.slots], mesh, arrays, program.rows,
+                3 * program.slots + 3 * PRUNE_MAX_TERMS + 1)
+
+        with ThreadPoolExecutor(max_workers=len(programs),
+                                thread_name_prefix="tpu-full-ready") as pool:
+            ready = resident.full_ready[key] = dict(zip(
+                ((p.slots, p.rows) for p in programs),
+                pool.map(compile_one, programs)))
+        return ready
+
+
 # ---------------------------------------------------------------------------
 # the exact kernel's programs: a closed set
 # ---------------------------------------------------------------------------
@@ -1907,6 +2068,8 @@ def _full_bucket(slots: int) -> Optional[int]:
 # length at CHUNK_CAP. `_launch_exact` pins with the same three functions
 # that the listing is made of.
 EXACT_MIN_SLOTS = 8
+#: where the ladder starts under a query of more than PRUNE_MAX_TERMS terms
+EXACT_LONG_MIN_SLOTS = 32
 #: the listing covers queries of up to this many terms
 EXACT_MAX_TERMS = 16
 
@@ -1923,12 +2086,12 @@ def _exact_window(window: int) -> int:
 def _exact_slot_pin(t_slots: int, t_window: int) -> int:
     """The slots a row an exact launch compiles for: powers of two from
     EXACT_MIN_SLOTS; a launch that holds a query of more than
-    PRUNE_MAX_TERMS terms (window past _PRUNE_WINDOW) starts at the
-    pruned path's narrowest full width instead, so that such queries
-    meet one slot count where their terms would give two."""
+    PRUNE_MAX_TERMS terms (window past _PRUNE_WINDOW) starts at
+    EXACT_LONG_MIN_SLOTS instead, so that such queries meet one slot
+    count where their terms would give two."""
     return dist._shape_bucket(
         t_slots, EXACT_MIN_SLOTS if t_window <= _PRUNE_WINDOW
-        else FULL_SLOT_BUCKETS[0])
+        else EXACT_LONG_MIN_SLOTS)
 
 
 def _exact_k_kernel(k: int) -> int:
@@ -2037,7 +2200,8 @@ def _exact_reason(flat: FlatQuery, k: int, can_prune: bool) -> Optional[str]:
 
 def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
                       k: int, mesh=None,
-                      stages: Optional[StageTimes] = None) -> Dict[str, Any]:
+                      stages: Optional[StageTimes] = None,
+                      max_batch: int = 128) -> Dict[str, Any]:
     """Phase 1 of a micro-batch: host prep + ASYNC kernel dispatch for
     the tier-E pruned subset (rescore-free), the tier-H pruned subset,
     and the exact subset (msm/AND, big k, many terms). Returns an
@@ -2045,7 +2209,9 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     asynchronous, so the caller can launch batch N+1 while batch N
     executes on device (double-buffered serving).
     On a batcher's launch thread the time spent here is its states
-    `prep`, then `lock`/`put`/`call` around each program's dispatch."""
+    `prep`, then `lock`/`put`/`call` around each program's dispatch.
+    `max_batch`: the most queries a train of this caller holds (it
+    bounds the programs made ready: `full_program_set`)."""
     tracing.current_states().switch("prep", queries=len(flats))
     if mesh is None:
         mesh = make_mesh(shape=(1, _n_local_devices()))
@@ -2072,28 +2238,18 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
             hot_idx.append(i)
         else:
             full_groups[b].append(i)
-    # a tiny group isn't worth a launch of its own (a `jit_full_s128`
-    # launch of the few rows it usually has reads 33 ms on the chip:
-    # PERF_LEDGER.jsonl, `device_full_s128_ms_per_launch`, PR 26-28):
-    # fold it into the next WIDER bucket when that bucket launches
-    # anyway (always correct — wider holds everything; folding into an
-    # EMPTY wider bucket would save nothing and widen the sort for
-    # nothing)
-    buckets = list(FULL_SLOT_BUCKETS)
-    for bi, b in enumerate(buckets[:-1]):
-        if 0 < len(full_groups[b]) < 16 and full_groups[buckets[bi + 1]]:
-            full_groups[buckets[bi + 1]].extend(full_groups[b])
-            full_groups[b] = []
+    full_launches = []
+    for b, idxs in _split_full_train(
+            full_groups,
+            max(1, resident.pack.num_shards // mesh.shape[SHARD_AXIS])):
+        ROUTE_COUNTS.inc(f"pruned_full_s{b}", n=len(idxs))
+        full_launches.append((idxs, _launch_pruned(
+            resident, [flats[i] for i in idxs], k, mesh,
+            stages=stages, full_slots=b, max_batch=max_batch)))
     st: Dict[str, Any] = {"resident": resident, "flats": flats, "k": k,
                           "mesh": mesh, "stages": stages,
-                          "full_groups": full_groups, "hot_idx": hot_idx,
-                          "exact_idx": exact_idx}
-    for b, idxs in full_groups.items():
-        if idxs:
-            ROUTE_COUNTS.inc(f"pruned_full_s{b}", n=len(idxs))
-            st[f"full_launch_{b}"] = _launch_pruned(
-                resident, [flats[i] for i in idxs], k, mesh,
-                stages=stages, full_slots=b)
+                          "full_launches": full_launches,
+                          "hot_idx": hot_idx, "exact_idx": exact_idx}
     if hot_idx:
         ROUTE_COUNTS.inc("pruned_hot", n=len(hot_idx))
         st["hot_launch"] = _launch_pruned(
@@ -2118,11 +2274,8 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
     out: List[Optional[FlatQueryResult]] = [None] * len(flats)
     tier3_idx: List[int] = []
     escalate: List[int] = []
-    for b, idxs in st["full_groups"].items():
-        if not idxs:
-            continue
-        results, invalid = _finish_pruned(st[f"full_launch_{b}"],
-                                          stages=stages)
+    for idxs, launch in st["full_launches"]:
+        results, invalid = _finish_pruned(launch, stages=stages)
         for j, i in enumerate(idxs):
             out[i] = results[j]
         # full-postings runs are exact ⇒ beta 0 ⇒ no invalids; if the
@@ -2332,11 +2485,13 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
                    stages: Optional[StageTimes] = None,
                    with_rescore: bool = True,
                    full_slots: Optional[int] = None,
-                   variant: Optional[str] = None) -> Dict[str, Any]:
+                   variant: Optional[str] = None,
+                   max_batch: int = 128) -> Dict[str, Any]:
     """One fused ASYNC launch. Two modes:
     - full_slots=N: FULL-postings sorted-merge at the N-slot width —
       run totals are exact BM25, no rescore (SURVEY.md §5.7 applied as
-      width buckets instead of prefixes);
+      width buckets instead of prefixes); a rung up to FULL_READY_SLOTS
+      calls its executable of `_ready_full_programs`;
     - prefix mode (block-max, §7.3#3): candidate generation over
       impact-sorted prefixes + EXACT on-device re-score (binary search
       in the doc-sorted postings). Only [B, k] crosses device→host."""
@@ -2346,8 +2501,11 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     pack = resident.pack
     imp_docs, imp_impacts = resident.imp_host
     k_cand = _candidate_k(k)
-    k_out = 128 if k_cand == 128 else 1024
-    b_bucket = _serving_bucket(len(flats))
+    k_out = _pruned_k_out(k)
+    # a rung launches at its own row buckets (a batch taller than them
+    # all at the general ones, compiled on first use, as before)
+    b_bucket = next((rows for rows in FULL_ROW_BUCKETS.get(full_slots, ())
+                     if rows >= len(flats)), _serving_bucket(len(flats)))
     # the launch path and its static width: the device program's name
     # (`jit_<path>` on the trace's XLA Modules line) and the label of
     # this launch's spans and counters
@@ -2382,18 +2540,29 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     KERNEL_VARIANT_COUNTS.inc("full" if full_slots is not None
                               else "pruned", variant)
     LAUNCH_COUNTS.inc(path)
-    # single-key phase-A sort (PR 15): only when the batch's slot AND
-    # rescore-term weights keep the 16-bit impact code monotone — the
-    # group-size fit check is static inside make_pruned_search
-    pack_keys = (variant == "packed" and with_rescore
-                 and sparse.packable(pack.d_pad, batch.weights)
-                 and sparse.packable(pack.d_pad, t_weights))
-    fn = dist.make_pruned_search(
-        mesh, max_len=batch.max_len, d_pad=pack.d_pad, p_pad=pack.p_pad,
-        c_cand=k_cand, k_out=k_out,
-        t_window=max(_PRUNE_WINDOW, batch.window),
-        t_terms=PRUNE_MAX_TERMS, with_rescore=with_rescore,
-        variant=variant, pack_keys=pack_keys, name=path)
+    if full_slots is not None:
+        FULL_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
+        FULL_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
+        # (a caller that forces a rung its queries do not fit, which the
+        # routing never does, gets the jitted program at their width)
+        ready = (_ready_full_programs(resident, mesh, k, max_batch, variant)
+                 if full_slots <= FULL_READY_SLOTS
+                 and batch.t_slots == full_slots else {})
+        fn = ready.get((full_slots, b_bucket)) or _make_full_search(
+            resident, mesh, full_slots, k_out, variant)
+    else:
+        # single-key phase-A sort (PR 15): only when the batch's slot AND
+        # rescore-term weights keep the 16-bit impact code monotone — the
+        # group-size fit check is static inside make_pruned_search
+        pack_keys = (variant == "packed" and with_rescore
+                     and sparse.packable(pack.d_pad, batch.weights)
+                     and sparse.packable(pack.d_pad, t_weights))
+        fn = dist.make_pruned_search(
+            mesh, max_len=batch.max_len, d_pad=pack.d_pad, p_pad=pack.p_pad,
+            c_cand=k_cand, k_out=k_out,
+            t_window=max(_PRUNE_WINDOW, batch.window),
+            t_terms=PRUNE_MAX_TERMS, with_rescore=with_rescore,
+            variant=variant, pack_keys=pack_keys, name=path)
     from jax.sharding import NamedSharding, PartitionSpec as P
     from elasticsearch_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
     sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
@@ -3953,8 +4122,6 @@ class TpuSearchService:
     def _compile_signatures(self, resident: ResidentPack, field: str,
                             compiled: List[Dict[str, Any]],
                             workers: int) -> None:
-        from concurrent.futures import ThreadPoolExecutor
-
         # a placement-group replica compiles against its group's
         # sub-mesh — warming it on the full mesh would populate a jit
         # cache serving never reads
@@ -3965,13 +4132,23 @@ class TpuSearchService:
                 terms = [next(iter(v))]
                 break
         flat = FlatQuery(field, terms or ["_warm_"], 1.0, 1)
+        # the full-postings rungs are warmed by a term the pack lacks: a
+        # zero-length slot, which fits every rung (a real term may need
+        # more slots than the rung it is forced to here)
+        fits = FlatQuery(field, ["\x00warm"], 1.0, 1)
         buckets = [8, 64, _serving_bucket(self.batcher.max_batch)]
         buckets = sorted(set(buckets))
-        table = []   # (batch, k, slots|None, prefix|None)
+        # (batch, k, slots|None, prefix|None). The full-postings rungs at
+        # the row buckets they launch at (FULL_ROW_BUCKETS): the first
+        # run of one up to FULL_READY_SLOTS compiles the whole of
+        # `full_program_set` ahead of time, as serving's first launch
+        # does; each member is then run once here, like the rest
+        table = []
         for b_bucket in buckets:
             for k in (10, PRUNE_MAX_K):
                 for slots in FULL_SLOT_BUCKETS:
-                    table.append((b_bucket, k, slots, None))
+                    if b_bucket in FULL_ROW_BUCKETS[slots]:
+                        table.append((b_bucket, k, slots, None))
                 table.append((b_bucket, k, None, PREFIX_CAP2))
         # the PREFIX_CAP3 escalation runs inline in the batch
         # completer with clients waiting — it must NEVER compile
@@ -4011,8 +4188,9 @@ class TpuSearchService:
                               "prefix": cap, "variant": variant},
                              lambda b_bucket=b_bucket, k=k, slots=slots,
                              cap=cap, variant=variant: _execute_pruned(
-                                 resident, [flat] * b_bucket, k,
-                                 mesh,
+                                 resident,
+                                 [flat if slots is None else fits] * b_bucket,
+                                 k, mesh,
                                  prefix_cap=cap or PREFIX_CAP2,
                                  full_slots=slots, variant=variant)))
         # exact kernel: the members of the pack's closed set
@@ -4108,7 +4286,9 @@ class TpuSearchService:
                 "route": ROUTE_COUNTS.counts(),
                 "hold_exit": HOLD_EXIT_COUNTS.counts(),
                 "exact_entries": EXACT_ENTRY_COUNTS.counts(),
+                "full_entries": FULL_ENTRY_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
+                "full_programs": self.full_programs(),
                 "render": RENDER_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
                 "supervision": self.supervisor.stats(),
@@ -4116,11 +4296,7 @@ class TpuSearchService:
                 "devices": self.device_stats(),
                 "stages": self.stages.snapshot()}
 
-    def exact_programs(self, k: int = PRUNE_MAX_K) -> Dict[str, List[str]]:
-        """The /_tpu/stats `exact_programs` block: for each resident
-        pack, the names of the exact kernel's programs that serving can
-        compile on it at `k` (`exact_program_set`; a name stands for its
-        members with and without clause counts)."""
+    def _programs(self, program_set, k: int) -> Dict[str, List[str]]:
         caches = [self.packs] + list(getattr(self, "group_caches", {}).values())
         out: Dict[str, List[str]] = {}
         for cache in caches:
@@ -4128,9 +4304,22 @@ class TpuSearchService:
                 resident = cache.peek(key)
                 if resident is not None:
                     out[f"{key[0]}/{key[1]}"] = sorted({
-                        p.label for p in exact_program_set(
+                        p.label for p in program_set(
                             resident, k, self.batcher.max_batch)})
         return out
+
+    def exact_programs(self, k: int = PRUNE_MAX_K) -> Dict[str, List[str]]:
+        """The /_tpu/stats `exact_programs` block: for each resident
+        pack, the names of the exact kernel's programs that serving can
+        compile on it at `k` (`exact_program_set`; a name stands for its
+        members with and without clause counts)."""
+        return self._programs(exact_program_set, k)
+
+    def full_programs(self, k: int = PRUNE_MAX_K) -> Dict[str, List[str]]:
+        """The /_tpu/stats `full_programs` block: for each resident pack,
+        the full-postings programs that its first full-path launch at
+        `k` compiles before it is answered (`full_program_set`)."""
+        return self._programs(full_program_set, k)
 
     def device_stats(self) -> Dict[str, Any]:
         """The /_tpu/stats `devices` block: the device stamp (platform,

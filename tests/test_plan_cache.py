@@ -242,7 +242,8 @@ class TestServingCacheCoherence:
         idx = make_corpus(svc, seeded_np)
         tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0)
 
-        def boom(resident, flats, k, mesh=None, stages=None):
+        def boom(resident, flats, k, mesh=None, stages=None,
+                 max_batch=128):
             raise RuntimeError("injected kernel failure")
 
         monkeypatch.setattr(svc_mod, "launch_flat_batch", boom)

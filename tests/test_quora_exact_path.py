@@ -299,10 +299,16 @@ def test_route_entries_and_launch_labels_count_what_was_sent(quora):
                 for key in after[block]
                 if after[block][key] != before[block].get(key, 0)}
 
+    # a train of one rides at 32 slots: the narrow rung has no program
+    # of eight rows
     assert rise("route") == {"exact_terms": 5, "pruned_full_s32": 1,
                              "exact_min_count": 1, "exact_k": 1}
     assert rise("launches") == {"exact_ref_b8_s32_w16": 5, "full_s32": 1,
                                 "exact_ref_b8_s8_w8": 2}
+    assert rise("full_entries") == {
+        "real": _postings_under(shards, by_terms[7][0]),
+        "padded": (8 * 32 * dist.CHUNK_CAP
+                   * quora["resident"].pack.num_shards)}
     real = sum(_postings_under(shards, q) for q in sent_exact) \
         + _postings_under(shards, by_terms[7][1][:3]) \
         + _postings_under(shards, by_terms[7][2])
@@ -313,6 +319,7 @@ def test_route_entries_and_launch_labels_count_what_was_sent(quora):
     prom = quora["node"].metrics.prometheus_text()
     assert 'es_tpu_kernel_route_total{route="exact_terms"}' in prom
     assert 'es_tpu_kernel_exact_entries_total{kind="padded"}' in prom
+    assert 'es_tpu_kernel_full_entries_total{kind="real"}' in prom
     assert 'es_tpu_kernel_launches_total{path="exact_ref_b8_s32_w16"}' in prom
 
 
@@ -359,7 +366,7 @@ def test_at_most_pipeline_depth_trains_are_launched_and_unfinished(
     lock = threading.Lock()
     live, high, trains = [0], [0], []
 
-    def launch(resident, flats, k, mesh=None, stages=None):
+    def launch(resident, flats, k, mesh=None, stages=None, max_batch=128):
         with lock:
             live[0] += 1
             high[0] = max(high[0], live[0])

@@ -210,7 +210,8 @@ class TestPerPackQueues:
 
         pack_a, pack_b = _FakePack(), _FakePack()
 
-        def fake_launch(resident, flats, k, mesh=None, stages=None):
+        def fake_launch(resident, flats, k, mesh=None, stages=None,
+                        max_batch=128):
             if resident is pack_a:
                 slow_started.set()
                 assert release_slow.wait(timeout=10.0)
@@ -247,7 +248,8 @@ class TestPerPackQueues:
         release = threading.Event()
         all_submitted = threading.Event()
 
-        def fake_launch(resident, flats, k, mesh=None, stages=None):
+        def fake_launch(resident, flats, k, mesh=None, stages=None,
+                        max_batch=128):
             if not calls:  # hold the FIRST launch open
                 calls.append(len(flats))
                 assert release.wait(timeout=10.0)

@@ -58,7 +58,8 @@ class _Device:
         self.pack = object()
         self.exits_before = HOLD_EXIT_COUNTS.counts()
 
-    def _launch(self, resident, flats, k, mesh=None, stages=None):
+    def _launch(self, resident, flats, k, mesh=None, stages=None,
+                max_batch=128):
         with self._lock:
             self.sizes.append(len(flats))
             self.flats.append(list(flats))
@@ -211,7 +212,7 @@ def test_a_closed_loop_of_three_trains_callers_rides_full_trains(
     free_at = [0.0]
     trains = []
 
-    def launch(resident, flats, k, mesh=None, stages=None):
+    def launch(resident, flats, k, mesh=None, stages=None, max_batch=128):
         with lock:
             now = time.monotonic()
             free_at[0] = max(free_at[0], now) + device_s
@@ -266,7 +267,8 @@ def test_a_closed_loop_of_three_trains_callers_rides_full_trains(
 def test_hold_exit_is_in_the_stats_and_sums_to_batches(monkeypatch):
     monkeypatch.setattr(
         tpu_service, "launch_flat_batch",
-        lambda resident, flats, k, mesh=None, stages=None: len(flats))
+        lambda resident, flats, k, mesh=None, stages=None, max_batch=128:
+        len(flats))
     monkeypatch.setattr(tpu_service, "finish_flat_batch",
                         lambda n: ["r"] * n)
     svc = tpu_service.TpuSearchService(window_s=0.0, batch_timeout_s=30.0)
