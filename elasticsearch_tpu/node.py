@@ -616,7 +616,8 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                EXACT_ENTRY_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
+                CROSS_CHIP_COUNTS, EXACT_ENTRY_COUNTS, FULL_ENTRY_COUNTS,
+                HOLD_EXIT_COUNTS,
                 KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
                 ROUTE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
@@ -643,6 +644,10 @@ class Node:
                 yield ("kernel.exact_entries", labels, counter)
             for labels, counter in FULL_ENTRY_COUNTS.items():
                 yield ("kernel.full_entries", labels, counter)
+            # launches on a mesh of several devices, their rows and
+            # devices: es_tpu_kernel_cross_chip_total{kind=...}
+            for labels, counter in CROSS_CHIP_COUNTS.items():
+                yield ("kernel.cross_chip", labels, counter)
             # trains by the reason the launch thread's hold ended:
             # es_tpu_batcher_hold_exit_total{hold_exit=...}
             for labels, counter in HOLD_EXIT_COUNTS.items():

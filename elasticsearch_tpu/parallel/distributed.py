@@ -785,10 +785,13 @@ def make_distributed_search(mesh: Mesh, *, max_len: int, d_pad: int,
     name = name or f"exact_{variant}"
 
     def tail(vals_b, gids_b, totals_b):
-        all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1, tiled=True)
-        all_ids = jax.lax.all_gather(gids_b, SHARD_AXIS, axis=1, tiled=True)
-        totals = jax.lax.psum(totals_b, SHARD_AXIS)  # TotalHits reduce
-        top_vals, top_ids = _merge_topk(all_vals, all_ids, k, variant)
+        with jax.named_scope("cross_chip_merge"):
+            all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1,
+                                          tiled=True)
+            all_ids = jax.lax.all_gather(gids_b, SHARD_AXIS, axis=1,
+                                         tiled=True)
+            totals = jax.lax.psum(totals_b, SHARD_AXIS)  # TotalHits reduce
+            top_vals, top_ids = _merge_topk(all_vals, all_ids, k, variant)
         return top_vals, top_ids, totals
 
     spec_post = P(SHARD_AXIS, None)
@@ -1070,16 +1073,21 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
 
         # per-device/group approx cutoff: docs cut THERE are bounded by
         # it in the validity check
-        row_cut = jax.lax.pmax(cut_local, SHARD_AXIS)
-        all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1, tiled=True)
-        all_gids = jax.lax.all_gather(gids_b, SHARD_AXIS, axis=1, tiled=True)
-        totals = jax.lax.psum(totals_b, SHARD_AXIS)
-        c = min(c_cand, all_vals.shape[1])
-        if variant == "packed":
-            cand_vals, pos = sparse.hierarchical_top_k(all_vals, c)
-        else:
-            cand_vals, pos = jax.lax.top_k(all_vals, c)
-        cand_gids = jnp.take_along_axis(all_gids, pos, axis=1)  # [B, C]
+        # the cross-chip merge: every device's candidates to every
+        # device, then the global pool's top c on each
+        with jax.named_scope("cross_chip_merge"):
+            row_cut = jax.lax.pmax(cut_local, SHARD_AXIS)
+            all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1,
+                                          tiled=True)
+            all_gids = jax.lax.all_gather(gids_b, SHARD_AXIS, axis=1,
+                                          tiled=True)
+            totals = jax.lax.psum(totals_b, SHARD_AXIS)
+            c = min(c_cand, all_vals.shape[1])
+            if variant == "packed":
+                cand_vals, pos = sparse.hierarchical_top_k(all_vals, c)
+            else:
+                cand_vals, pos = jax.lax.top_k(all_vals, c)
+            cand_gids = jnp.take_along_axis(all_gids, pos, axis=1)  # [B, C]
 
         if with_rescore:
             # ---- phase B: exact re-score of candidates,

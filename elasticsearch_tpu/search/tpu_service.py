@@ -1826,6 +1826,12 @@ EXACT_ENTRY_COUNTS = LabeledCounters("kind")
 #: the slots' lengths, and rows × slots × chunk length × shard rows
 #: dispatched → es_tpu_kernel_full_entries_total
 FULL_ENTRY_COUNTS = LabeledCounters("kind")
+#: launches dispatched on a mesh of more than one device, the query rows
+#: they carried as dispatched and the devices they ran on (one count a
+#: launch, so `devices` ÷ `launches` is the mesh's size): what a reader
+#: needs to reckon the bytes of the cross-chip top-k merge. All three
+#: stay 0 on one device → es_tpu_kernel_cross_chip_total
+CROSS_CHIP_COUNTS = LabeledCounters("kind")
 #: trains by the reason their hold ended (`_PackQueue._hold`): one count
 #: a train taken → es_tpu_batcher_hold_exit_total
 HOLD_EXITS = ("full", "backlog_low", "idle_window")
@@ -1837,6 +1843,17 @@ for _label in HOLD_EXITS:
 for _label in ("real", "padded"):
     EXACT_ENTRY_COUNTS.child(_label)
     FULL_ENTRY_COUNTS.child(_label)
+for _label in ("launches", "rows", "devices"):
+    CROSS_CHIP_COUNTS.child(_label)
+
+
+def _count_cross_chip(mesh, rows: int) -> None:
+    """One launch of `rows` query rows dispatched on `mesh`."""
+    n_devices = int(mesh.devices.size)
+    if n_devices > 1:
+        CROSS_CHIP_COUNTS.inc("launches")
+        CROSS_CHIP_COUNTS.inc("rows", n=rows)
+        CROSS_CHIP_COUNTS.inc("devices", n=n_devices)
 
 
 def _choose_exact_variant(resident: ResidentPack, batch) -> str:
@@ -2422,6 +2439,7 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     LAUNCH_COUNTS.inc(label)
     EXACT_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
     EXACT_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
+    _count_cross_chip(mesh, rows)
     t_disp = time.perf_counter()
     vals, gids, totals = dist.distributed_search_raw(
         pack, batch, _exact_k_kernel(k), mesh,
@@ -2540,6 +2558,7 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     KERNEL_VARIANT_COUNTS.inc("full" if full_slots is not None
                               else "pruned", variant)
     LAUNCH_COUNTS.inc(path)
+    _count_cross_chip(mesh, b_bucket)
     if full_slots is not None:
         FULL_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
         FULL_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
@@ -2570,12 +2589,13 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     # dispatch, three ways: the wait for the process-wide dispatch lock,
     # the host→device copy of the operands, and the jitted call (host
     # dispatch plus whatever the runtime blocks on: donation holds, a
-    # full queue). `batch_dispatch` stays their sum.
+    # full queue). `batch_dispatch` stays their sum; `batch_put` is the
+    # copy alone, the part that grows with the mesh's devices.
     t_disp = states.switch("lock")
     with dist.DEVICE_DISPATCH_LOCK:
-        states.switch("put")
+        t_put = states.switch("put")
         ops_dev = jax.device_put(ops, sbt)
-        states.switch("call", path=path, rows=b_bucket)
+        t_call = states.switch("call", path=path, rows=b_bucket)
         packed = fn(
             resident.imp_device_arrays[0], resident.imp_device_arrays[1],
             resident.device_arrays[0], resident.device_arrays[1],
@@ -2584,6 +2604,7 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     if stages is not None:
         stages.add("batch_prep", t_disp - t_prep)
         stages.add("batch_dispatch", t_dev - t_disp)
+        stages.add("batch_put", t_call - t_put)
     return {"resident": resident, "flats": flats, "k": k,
             "packed": packed, "variant": variant}
 
@@ -4287,6 +4308,7 @@ class TpuSearchService:
                 "hold_exit": HOLD_EXIT_COUNTS.counts(),
                 "exact_entries": EXACT_ENTRY_COUNTS.counts(),
                 "full_entries": FULL_ENTRY_COUNTS.counts(),
+                "cross_chip": CROSS_CHIP_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),
                 "render": RENDER_COUNTS.counts(),
