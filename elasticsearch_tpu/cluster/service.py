@@ -396,8 +396,7 @@ class ClusterService:
                      in self._seen_index_uuids]:
             try:
                 indices.delete_index(name)
-                if self.node.tpu_search is not None:
-                    self.node.tpu_search.invalidate_index(name)
+                self.node.release_index(name)
             except EsException:
                 pass
 
@@ -418,11 +417,10 @@ class ClusterService:
             # IndexClosedException, not ShardNotFound
             was_closed = svc.closed
             svc.closed = (getattr(meta, "state", "open") == "close")
-            if svc.closed and not was_closed \
-                    and self.node.tpu_search is not None:
+            if svc.closed and not was_closed:
                 # release the closed index's resident packs (HBM breaker
                 # bytes + device arrays)
-                self.node.tpu_search.invalidate_index(name)
+                self.node.release_index(name)
             if was_closed and svc.closed:
                 continue  # already reconciled closed; nothing to do
             if meta.mapping:

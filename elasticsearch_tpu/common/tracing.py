@@ -591,13 +591,102 @@ def current_states() -> Any:
     return getattr(_tls, "states", None) or NO_STATES
 
 
+class StandingHeap:
+    """The process's collector policy, the one thing of the collector
+    that is tuned: what a node builds to keep (segments, stored sources,
+    id tables, resident packs, compiled programs) is moved out of the
+    collector's sight with `gc.freeze()` once it is built, so that a
+    full collection walks what requests made and not the index. Frozen
+    objects are still freed by their reference counts; only a cycle
+    among them stays until the next `settle(replaced=True)`, which
+    thaws, collects and freezes again where long-lived state was
+    dropped. One object a process, because the freeze is process-wide:
+    it counts the open nodes, does nothing while there is none, and
+    thaws when the last one closes."""
+
+    def __init__(self) -> None:
+        # reentrant: `opening` and `node_closed` settle under it
+        self._lock = threading.RLock()
+        self.nodes = 0
+        self.freezes = 0
+        self.resettles = 0
+
+    def freeze(self) -> None:
+        """Something long-lived was just built (a pack became resident,
+        a program compiled): a splice of three lists, microseconds, so
+        it may run under traffic. Nothing is collected first."""
+        with self._lock:
+            if self.nodes:
+                gc.freeze()
+                self.freezes += 1
+
+    def settle(self, replaced: bool = False) -> None:
+        """Collect, then freeze, so that no garbage cycle is frozen: a
+        full collection, for where no `_search` waits on it (a node has
+        opened, a base pack was built). `replaced`: long-lived state was
+        dropped too (a base pack generation, an index, a node), so what
+        was frozen is thawed first and its dead cycles go."""
+        with self._lock:
+            if not self.nodes:
+                return
+            if replaced:
+                gc.unfreeze()
+                self.resettles += 1
+            gc.collect()
+            gc.freeze()
+            self.freezes += 1
+
+    @contextlib.contextmanager
+    def opening(self) -> Iterator[None]:
+        """Around a node's construction: when it has ended, the node
+        counts and what it built is collected once and frozen. Where no
+        other node of the process is serving meanwhile, automatic
+        collection pauses for the span: one thread builds millions of
+        objects to keep, and every collection on the way would walk all
+        it has built so far for nothing."""
+        with self._lock:
+            paused = not self.nodes and gc.isenabled()
+            if paused:
+                gc.disable()
+        try:
+            yield
+            with self._lock:
+                self.nodes += 1
+                self.settle()
+        finally:
+            if paused:
+                gc.enable()
+
+    def node_closed(self) -> None:
+        with self._lock:
+            self.nodes -= 1
+            if self.nodes:
+                self.settle(replaced=True)
+            else:
+                gc.unfreeze()
+                gc.collect()
+                # (a collection parks the interpreter's immortal objects
+                # in the permanent generation: thawed too, so that the
+                # count of frozen objects says 0 when nothing is frozen)
+                gc.unfreeze()
+
+    def stats(self) -> Dict[str, int]:
+        return {"freezes": self.freezes, "resettles": self.resettles,
+                "frozen_objects": gc.get_freeze_count()}
+
+
+HEAP = StandingHeap()
+
+
 class GcWatch:
     """Full (generation 2) collections of this process: a `gc.callbacks`
     entry that returns at once for generations 0 and 1 and, for a full
     collection, wraps it in a `gc.full` profiler annotation and counts
-    it. A full collection of a serving node's heap stops every Python
-    thread for as long as it runs; this is the only place the program
-    itself says so. Observes only: nothing of the collector is tuned."""
+    it, however short. A full collection stops every Python thread for
+    as long as it runs; this is where the program itself says so. What
+    a full collection walks is `HEAP`'s to decide (the node's standing
+    heap is frozen, so it is what requests made); `stats` reports both,
+    what was paused and how often the policy engaged."""
 
     def __init__(self) -> None:
         self.full_collections = 0
@@ -632,4 +721,5 @@ class GcWatch:
         return {"full_collections": self.full_collections,
                 "full_pause_seconds": round(self.full_pause_seconds, 4),
                 "longest_pause_ms":
-                    round(self.longest_pause_seconds * 1000.0, 3)}
+                    round(self.longest_pause_seconds * 1000.0, 3),
+                **HEAP.stats()}

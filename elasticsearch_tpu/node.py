@@ -31,6 +31,14 @@ class Node:
                  node_name: str = "node-1",
                  cluster_name: str = "elasticsearch-tpu",
                  settings: Optional[Settings] = None):
+        # everything `_open` builds is here to stay (indices opened,
+        # segments loaded, translog replayed, packs re-attained): it is
+        # collected once and frozen, so no later full collection walks it
+        with tracing.HEAP.opening():
+            self._open(data_path, node_name, cluster_name, settings)
+
+    def _open(self, data_path: str, node_name: str, cluster_name: str,
+              settings: Optional[Settings]) -> None:
         # private copy — dynamic cluster settings mutate node.settings
         # and must never write through to the caller's object or the
         # shared EMPTY singleton
@@ -222,12 +230,12 @@ class Node:
         # tracing: per-request root spans + propagation through the
         # coordinator fan-out and the TPU batch pipeline (sample_rate=0,
         # the default, keeps the hostpath allocation-free)
-        from elasticsearch_tpu.common.tracing import GcWatch, Tracer
         # full collections stop every Python thread of the node: counted
-        # (/_tpu/stats → runtime.gc) and annotated on profiler traces
-        self.gc_watch = GcWatch()
+        # (/_tpu/stats → runtime.gc) and annotated on profiler traces;
+        # what they walk is settled when the constructor ends
+        self.gc_watch = tracing.GcWatch()
         self.gc_watch.install()
-        self.tracer = Tracer(
+        self.tracer = tracing.Tracer(
             sample_rate=self.settings.get_float(
                 "search.tracing.sample_rate", 0.0),
             max_spans=self.settings.get_int(
@@ -1067,6 +1075,15 @@ class Node:
         self._syncer.daemon = True
         self._syncer.start()
 
+    def release_index(self, name: str) -> None:
+        """`name` was closed or deleted: its resident packs and lowered
+        plans go (HBM breaker bytes, pinned readers), and what it held
+        of the frozen heap (segments, sources, packs) is thawed and
+        collected, so that no cycle among them outlives the index."""
+        if self.tpu_search is not None:
+            self.tpu_search.invalidate_index(name)
+        tracing.HEAP.settle(replaced=True)
+
     def close(self) -> None:
         if self._closed:
             return
@@ -1099,6 +1116,9 @@ class Node:
         if ccs_client is not None:
             ccs_client.close()
         self.indices.close()
+        # what this node held goes with it; the last node of the process
+        # hands the whole heap back to the collector
+        tracing.HEAP.node_closed()
 
     # ---------------- in-process dispatch (tests + http) ----------------
 

@@ -42,9 +42,7 @@ def register(controller: RestController, node) -> None:
         for name in resolve_concrete_indices(indices,
                                              req.param("index")):
             indices.delete_index(name)
-            tpu = getattr(node, "tpu_search", None)
-            if tpu is not None:  # drop resident packs + HBM accounting
-                tpu.invalidate_index(name)
+            node.release_index(name)  # resident packs + HBM accounting
         return 200, {"acknowledged": True}
 
     def close_index(req: RestRequest):
@@ -61,9 +59,7 @@ def register(controller: RestController, node) -> None:
         for name in resolve_concrete_indices(indices, req.param("index")):
             indices.close_index(name)
             closed[name] = {"closed": True}
-            tpu = getattr(node, "tpu_search", None)
-            if tpu is not None:
-                tpu.invalidate_index(name)
+            node.release_index(name)
         return 200, {"acknowledged": True, "shards_acknowledged": True,
                      "indices": closed}
 

@@ -388,6 +388,9 @@ class ResidentPack:
     # `full_program_set`, by (slots, rows): `_ready_full_programs`
     full_ready: Dict[Tuple, Dict[Tuple[int, int], Any]] = dataclasses.field(
         default_factory=dict)
+    # the programs this pack has launched, by what names them: a
+    # program's first launch compiled it (`_freeze_first_launch`)
+    launched: set = dataclasses.field(default_factory=set)
     # compressed resident format: host-side 16-bit
     # streams + residual tables. When set, device_arrays is the 5-tuple
     # from device_put_compressed (6-tuple with the delta doc stream's
@@ -820,6 +823,11 @@ class IndexPackCache:
         if self.on_evict is not None:
             for stale in evicted:
                 self.on_evict(stale)
+        # a base build takes seconds and no `_search` waits on it (the
+        # generation before serves meanwhile): the place for the full
+        # collection that keeps garbage cycles out of the frozen heap,
+        # the one it replaced thawed first
+        tracing.HEAP.settle(replaced=bool(evicted))
         return entry
 
     def _swap_in_locked(self, key, entry: Optional[ResidentPack], readers,
@@ -983,6 +991,7 @@ class IndexPackCache:
             if self.on_evict is not None:
                 for stale in evicted:
                     self.on_evict(stale)
+            tracing.HEAP.settle(replaced=True)
             dur = time.monotonic() - t0
             if self.delta_stats is not None:
                 self.delta_stats.compactions += 1
@@ -1122,17 +1131,22 @@ class IndexPackCache:
         for ids in pack.shard_doc_ids:
             id_cat[off: off + len(ids)] = ids
             off += len(ids)
-        return ResidentPack(pack, arrays, row_origin, reader_key, hbm,
-                            readers={num: r for num, r in readers},
-                            imp_host=(None if imp_docs is None
-                                      else (imp_docs, imp_impacts)),
-                            imp_device_arrays=imp_arrays,
-                            row_shard=row_shard, row_offset=row_offset,
-                            id_cat=id_cat,
-                            id_json=EncodedIds.build(pack.shard_doc_ids),
-                            row_segments=row_segments,
-                            comp_streams=streams, hbm_detail=hbm_detail,
-                            group_id=self.group_id, mesh=mesh)
+        resident = ResidentPack(
+            pack, arrays, row_origin, reader_key, hbm,
+            readers={num: r for num, r in readers},
+            imp_host=(None if imp_docs is None
+                      else (imp_docs, imp_impacts)),
+            imp_device_arrays=imp_arrays,
+            row_shard=row_shard, row_offset=row_offset,
+            id_cat=id_cat,
+            id_json=EncodedIds.build(pack.shard_doc_ids),
+            row_segments=row_segments,
+            comp_streams=streams, hbm_detail=hbm_detail,
+            group_id=self.group_id, mesh=mesh)
+        # the pack, its id table and resolution tables are here to stay:
+        # out of the collector's sight, under traffic too (microseconds)
+        tracing.HEAP.freeze()
+        return resident
 
     def invalidate(self, index_name: str) -> None:
         evicted = []
@@ -1184,6 +1198,8 @@ class IndexPackCache:
                 self.on_evict(entry)
             for entry in dropped:
                 self.on_evict(entry)
+        if entries:
+            tracing.HEAP.settle(replaced=True)
         return [key for key, _entry in entries]
 
 
@@ -1856,6 +1872,17 @@ def _count_cross_chip(mesh, rows: int) -> None:
         CROSS_CHIP_COUNTS.inc("devices", n=n_devices)
 
 
+def _freeze_first_launch(resident: ResidentPack, *program: Any) -> None:
+    """Called when a launch has returned: where it was the first of
+    `program` on this pack, it has just been compiled (by `jax.jit`, or
+    ahead of time with the rest of `full_program_set`), and what a
+    compilation leaves (jaxprs, executables, cache entries) lives as
+    long as the pack does: out of the collector's sight."""
+    if program not in resident.launched:
+        resident.launched.add(program)
+        tracing.HEAP.freeze()
+
+
 def _choose_exact_variant(resident: ResidentPack, batch) -> str:
     """Lowering-time variant pick for one exact-kernel launch (the
     planner owns the decision rule; this just feeds it the pack's doc
@@ -2445,6 +2472,7 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
         pack, batch, _exact_k_kernel(k), mesh,
         device_arrays=resident.device_arrays,
         t_window=t_window, materialize=False, variant=variant)
+    _freeze_first_launch(resident, label, _exact_k_kernel(k), mesh)
     if stages is not None:
         stages.add("exact_prep", t_disp - t_prep)
         stages.add(f"exact_dispatch.{variant}",
@@ -2601,6 +2629,7 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
             resident.device_arrays[0], resident.device_arrays[1],
             ops_dev)
     t_dev = states.switch("prep")
+    _freeze_first_launch(resident, path, b_bucket, k_cand, variant, mesh)
     if stages is not None:
         stages.add("batch_prep", t_disp - t_prep)
         stages.add("batch_dispatch", t_dev - t_disp)

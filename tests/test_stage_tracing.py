@@ -19,6 +19,7 @@ import json
 import os
 import threading
 import time
+import weakref
 
 import pytest
 
@@ -225,8 +226,14 @@ def test_rest_request_counts_the_requests_answered(loaded):
 
 def test_stats_gain_keys_and_nothing_else_changes_shape(loaded):
     stats = loaded["stats"]
-    assert set(stats["runtime"]["gc"]) == {
-        "full_collections", "full_pause_seconds", "longest_pause_ms"}
+    gc_stats = stats["runtime"]["gc"]
+    assert set(gc_stats) == {
+        "full_collections", "full_pause_seconds", "longest_pause_ms",
+        "freezes", "resettles", "frozen_objects"}
+    # the node froze what it had built when it opened, and again when the
+    # pack became resident and its programs compiled; nothing was replaced
+    assert gc_stats["freezes"] >= 3
+    assert gc_stats["frozen_objects"] > 0
     # a train of up to eight rides at 32 slots, a taller one of light
     # queries launches at 16 (`FULL_ROW_BUCKETS`)
     assert stats["launches"].get("full_s32", 0) > 0
@@ -292,6 +299,210 @@ def test_gc_watch_counts_full_collections_only(tmp_data_path):
     finally:
         node.close()
     assert node.gc_watch not in gc.callbacks
+
+
+@pytest.fixture
+def heap(monkeypatch):
+    """A policy object of the test's own: a node that another test of this
+    process left open is not among the nodes it counts."""
+    own = tracing.StandingHeap()
+    monkeypatch.setattr(tracing, "HEAP", own)
+    yield own
+    gc.unfreeze()
+
+
+def _gc_stats(node):
+    status, stats = node.handle("GET", "/_tpu/stats")
+    assert status == 200
+    return stats["runtime"]["gc"]
+
+
+def _index_toy(node, name="toy", docs=40):
+    assert node.handle("PUT", f"/{name}", body={
+        "settings": {"number_of_shards": 1},
+        "mappings": {"properties": {"body": {"type": "text"}}}})[0] == 200
+    for i in range(docs):
+        assert node.handle("PUT", f"/{name}/_doc/{i}", body={
+            "body": f"w{i % 7} w{i % 3} common"})[0] in (200, 201)
+    assert node.handle("POST", f"/{name}/_refresh")[0] == 200
+
+
+def _search_toy(node, name="toy"):
+    status, body = node.handle("POST", f"/{name}/_search", body={
+        "query": {"match": {"body": "w1 common"}}, "size": 5})
+    assert status == 200 and body["hits"]["hits"], body
+
+
+def test_an_open_node_has_frozen_its_heap(tmp_data_path, heap):
+    assert heap.stats()["freezes"] == 0
+    node = Node(str(tmp_data_path), settings=Settings.of({}))
+    try:
+        assert gc.get_freeze_count() > 0
+        stats = _gc_stats(node)
+        assert stats["freezes"] >= 1 and stats["resettles"] == 0
+        assert stats["frozen_objects"] == gc.get_freeze_count()
+    finally:
+        node.close()
+    assert gc.get_freeze_count() == 0
+
+
+def test_without_a_node_nothing_is_frozen(heap):
+    gc.unfreeze()
+    heap.freeze()
+    heap.settle()
+    heap.settle(replaced=True)
+    assert gc.get_freeze_count() == 0
+    assert heap.stats() == {"freezes": 0, "resettles": 0,
+                            "frozen_objects": 0}
+
+
+def test_placing_a_pack_and_compiling_its_programs_raise_freezes(
+        tmp_data_path, heap):
+    node = Node(str(tmp_data_path), settings=Settings.of({}))
+    try:
+        _index_toy(node)
+        opened = _gc_stats(node)
+        _search_toy(node)           # builds the pack, compiles a program
+        placed = _gc_stats(node)
+        # `_place_pack`, the base build's settle, the program's first launch
+        assert placed["freezes"] >= opened["freezes"] + 3
+        assert placed["resettles"] == opened["resettles"]
+        assert node.tpu_search.served >= 1
+        _search_toy(node)           # the same program again: nothing new
+        assert _gc_stats(node)["freezes"] == placed["freezes"]
+    finally:
+        node.close()
+
+
+@pytest.mark.parametrize("event", ["pack_replaced", "index_deleted",
+                                   "index_closed"])
+def test_dropping_long_lived_state_resettles(tmp_data_path, heap, event):
+    node = Node(str(tmp_data_path), settings=Settings.of({}))
+    try:
+        _index_toy(node)
+        _search_toy(node)
+        before = _gc_stats(node)
+        if event == "pack_replaced":
+            # a delete changes the live docs: the base pack is built anew
+            assert node.handle("DELETE", "/toy/_doc/1")[0] == 200
+            assert node.handle("POST", "/toy/_refresh")[0] == 200
+            _search_toy(node)
+        elif event == "index_deleted":
+            assert node.handle("DELETE", "/toy")[0] == 200
+        else:
+            assert node.handle("POST", "/toy/_close")[0] == 200
+        after = _gc_stats(node)
+        assert after["resettles"] >= before["resettles"] + 1
+        assert after["frozen_objects"] > 0
+    finally:
+        node.close()
+
+
+def test_two_nodes_share_one_frozen_heap(tmp_path, heap):
+    first = Node(str(tmp_path / "a"), settings=Settings.of({}))
+    second = Node(str(tmp_path / "b"), node_name="node-2",
+                  settings=Settings.of({}))
+    assert heap.nodes == 2
+    second.close()
+    # the node that is left still serves from a frozen heap
+    assert heap.nodes == 1 and gc.get_freeze_count() > 0
+    assert _gc_stats(first)["resettles"] == 1
+    second.close()                  # closing twice counts once
+    assert heap.nodes == 1
+    first.close()
+    assert heap.nodes == 0 and gc.get_freeze_count() == 0
+
+
+def test_collection_pauses_while_the_first_node_of_a_process_opens(heap):
+    assert gc.isenabled()
+    with heap.opening():
+        assert not gc.isenabled()    # one thread builds, nobody serves
+    assert gc.isenabled() and heap.nodes == 1 and gc.get_freeze_count() > 0
+    with heap.opening():
+        assert gc.isenabled()        # the first node may be serving
+    assert heap.nodes == 2
+    heap.node_closed()
+    heap.node_closed()
+    # a node that fails to open is not counted, and collection is back on
+    with pytest.raises(RuntimeError):
+        with heap.opening():
+            assert not gc.isenabled()
+            raise RuntimeError("no such data path")
+    assert gc.isenabled() and heap.nodes == 0
+    assert gc.get_freeze_count() == 0
+
+
+def test_the_policy_counts_every_call_of_many_threads(heap):
+    import sys
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with heap.opening():
+            pass
+        opened = heap.stats()
+
+        def worker():
+            for i in range(40):
+                heap.freeze()
+                if i % 10 == 0:
+                    heap.settle(replaced=i % 20 == 0)
+
+        threads = [threading.Thread(target=worker) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        stats = heap.stats()
+        assert stats["freezes"] == opened["freezes"] + 16 * 44
+        assert stats["resettles"] == 16 * 2
+        assert gc.get_freeze_count() > 0
+    finally:
+        sys.setswitchinterval(old)
+        heap.node_closed()
+    assert gc.get_freeze_count() == 0
+
+
+class _Knot:
+    def __init__(self):
+        self.me = self
+
+
+def test_a_frozen_cycle_lives_until_a_resettle(tmp_data_path, heap):
+    node = Node(str(tmp_data_path), settings=Settings.of({}))
+    try:
+        knot = _Knot()
+        alive = weakref.ref(knot)
+        heap.freeze()
+        del knot
+        gc.collect()
+        assert alive() is not None   # no collection walks frozen objects
+        heap.settle()                # nor does a settle that replaced nothing
+        assert alive() is not None
+        heap.settle(replaced=True)
+        assert alive() is None
+        # what reference counts free needs no collection, frozen or not
+        plain = _Knot()
+        plain.me = None
+        gone = weakref.ref(plain)
+        heap.freeze()
+        del plain
+        assert gone() is None
+    finally:
+        node.close()
+
+
+def test_gc_watch_counts_a_collection_of_a_frozen_heap(tmp_data_path, heap):
+    node = Node(str(tmp_data_path), settings=Settings.of({}))
+    try:
+        assert gc.get_freeze_count() > 0
+        before = node.gc_watch.stats()["full_collections"]
+        gc.collect()
+        assert node.gc_watch.stats()["full_collections"] == before + 1
+        heap.settle(replaced=True)   # the policy's own collections count too
+        assert node.gc_watch.stats()["full_collections"] == before + 2
+    finally:
+        node.close()
 
 
 def test_stage_without_sampling_allocates_no_span(tmp_path, monkeypatch):
