@@ -6,10 +6,19 @@ Generates the configuration's corpus, indexes it through the node's own
 REST surface (`PUT index`, `_bulk`, `_refresh`, `_forcemerge`, `_flush`
 over HTTP on `serve()`), checks the per-shard doc counts against the
 reference's routing, and writes beside the data directory what later runs
-need and must not recompute: every query's reference top-k
-(`reference.npz`) and the query set's strata for the warm-up
-(`queries.npz`). `manifest.json` is written last; a directory without it
-is not an index.
+need and must not recompute: every query's reference top-k under
+`match`'s default operator (`reference.npz`) and the query set's strata
+for the warm-up (`queries.npz`). `manifest.json` is written last; a
+directory without it is not an index.
+
+    python benchmarks/build_index.py --config <file> --out <dir>
+                                     --reference-only --operator and
+
+adds the reference of another operator (`reference-and.npz`, the file
+`esbench.reference.stored_name` names) to a directory that is an index
+already: the corpus and the query set come from the seed again, the node
+is not opened and nothing is indexed. run.py asks for it when a cell's
+traffic sends an operator whose reference the directory lacks.
 
 This process runs with JAX_PLATFORMS=cpu and the TPU serving path off:
 run.py holds the chip while it waits, and nothing here needs a device.
@@ -150,48 +159,84 @@ def build(config: Dict[str, Any], out: str) -> None:
     if got != want:
         raise RuntimeError(f"docs per shard {got} != reference routing {want}")
 
-    t3 = time.monotonic()
-    terms = sorted({t for q in queries for t in q})
-    shard_indexes = reference.build_shard_indexes(
-        corpus.flat, corpus.offsets, shards, terms)
-    offsets, docs, scores, totals, heaviest = [0], [], [], [], []
-    for q in queries:
-        total, d, s = reference.reference_topk(shard_indexes, q, REFERENCE_K)
-        totals.append(total)
-        docs.append(d.astype(np.int32))
-        scores.append(s)
-        offsets.append(offsets[-1] + d.shape[0])
-        heaviest.append(max(sum(sh.postings[t][0].shape[0] for t in q)
-                            for sh in shard_indexes))
-    np.savez(os.path.join(out, "reference.npz"),
-             offsets=np.asarray(offsets, dtype=np.int64),
-             docs=np.concatenate(docs), scores=np.concatenate(scores),
-             totals=np.asarray(totals, dtype=np.int64),
-             k=np.asarray(REFERENCE_K))
-    np.savez(os.path.join(out, "queries.npz"),
-             offsets=np.cumsum([0] + [len(q) for q in queries]),
-             terms=np.concatenate([np.asarray(q, dtype=np.int64) for q in queries]),
-             postings=np.asarray(heaviest, dtype=np.int64))
-    log(f"reference for {len(queries)} queries {time.monotonic() - t3:.1f}s")
+    ref_s = write_reference(out, corpus, queries, shards, "or", with_strata=True)
     manifest = {"config": config["name"], "docs": corpus.num_docs,
                 "tokens": int(corpus.flat.shape[0]), "shards": shards,
                 "docs_per_shard": want, "queries": len(queries),
                 "index": INDEX, "field": FIELD,
                 "index_seconds": round(t2 - t1, 1),
+                "reference_seconds": round(ref_s, 1),
                 "build_seconds": round(time.monotonic() - t0, 1)}
     with open(os.path.join(out, "manifest.json"), "w", encoding="utf-8") as f:
         json.dump(manifest, f, indent=1)
     log(f"done: {manifest}")
 
 
+def write_reference(out: str, corpus: corpus_gen.Corpus, queries: List[List[int]],
+                    shards: int, operator: str, with_strata: bool = False) -> float:
+    """Every query's reference top-k under `operator` → the directory's
+    file for it (and `queries.npz`: the query set with, for the warm-up's
+    strata, each query's postings on its heaviest shard) → its seconds."""
+    t0 = time.monotonic()
+    terms = sorted({t for q in queries for t in q})
+    shard_indexes = reference.build_shard_indexes(
+        corpus.flat, corpus.offsets, shards, terms)
+    offsets, docs, scores, totals = [0], [], [], []
+    for q in queries:
+        total, d, s = reference.reference_topk(shard_indexes, q, REFERENCE_K, operator)
+        totals.append(total)
+        docs.append(d.astype(np.int32))
+        scores.append(s)
+        offsets.append(offsets[-1] + d.shape[0])
+    # under a name np.savez leaves alone, then renamed: a file is whole or absent
+    final = os.path.join(out, reference.stored_name(operator))
+    np.savez(final + ".tmp.npz",
+             offsets=np.asarray(offsets, dtype=np.int64),
+             docs=np.concatenate(docs), scores=np.concatenate(scores),
+             totals=np.asarray(totals, dtype=np.int64),
+             k=np.asarray(REFERENCE_K))
+    os.replace(final + ".tmp.npz", final)
+    if with_strata:
+        heaviest = [max(sum(sh.postings[t][0].shape[0] for t in q)
+                        for sh in shard_indexes) for q in queries]
+        np.savez(os.path.join(out, "queries.npz"),
+                 offsets=np.cumsum([0] + [len(q) for q in queries]),
+                 terms=np.concatenate([np.asarray(q, dtype=np.int64) for q in queries]),
+                 postings=np.asarray(heaviest, dtype=np.int64))
+    seconds = time.monotonic() - t0
+    log(f"reference [{operator}] for {len(queries)} queries {seconds:.1f}s "
+        f"({int(np.count_nonzero(totals))} with a hit)")
+    return seconds
+
+
+def add_reference(config: Dict[str, Any], out: str, operator: str) -> None:
+    """`--reference-only`: the reference of `operator` beside an index
+    that stands, from the seed and not from the node."""
+    with open(os.path.join(out, "manifest.json"), "r", encoding="utf-8") as f:
+        manifest = json.load(f)
+    corpus = corpus_gen.generate_corpus(config["generator"])
+    queries = corpus_gen.generate_queries(config["generator"])
+    if (corpus.num_docs, int(corpus.flat.shape[0]), len(queries)) != (
+            manifest["docs"], manifest["tokens"], manifest["queries"]):
+        raise RuntimeError(f"[{out}] was not built from this configuration: "
+                           f"its manifest says {manifest}")
+    write_reference(out, corpus, queries, manifest["shards"], operator)
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--config", required=True)
     parser.add_argument("--out", required=True)
+    parser.add_argument("--reference-only", action="store_true",
+                        help="index nothing: add the reference of --operator")
+    parser.add_argument("--operator", default="or", choices=reference.OPERATORS)
     args = parser.parse_args()
     with open(args.config, "r", encoding="utf-8") as f:
         config = json.load(f)
-    build(config, args.out)
+    if args.reference_only:
+        add_reference(config, args.out, args.operator)
+    else:
+        build(config, args.out)
     return 0
 
 

@@ -193,7 +193,7 @@ class Generator:
                 failed += not ok
                 if not ok:
                     if len(counts["failures"]) < 3:  # what a failure looks like
-                        counts["failures"].append([status, data[:300].decode("utf-8", "replace")])
+                        counts["failures"].append([status, data[:700].decode("utf-8", "replace")])
                     client.connect()
             with lock:
                 counts["sent"] += sent

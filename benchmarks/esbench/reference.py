@@ -8,7 +8,8 @@ corpus as flat token arrays it reproduces what the served path promises:
   idf(t)      = ln(1 + (N - n_t + 0.5) / (n_t + 0.5))
   dl(doc)     = byte4ToInt(intToByte4(length))        # lossy 1-byte norm
   score(doc)  = Σ_t idf(t)·(k1+1)·tf / (tf + f32(k1·(1-b+b·dl/avgdl)))
-  hits        = every doc with score > 0, best first; top k returned
+  hits        = every doc with score > 0 (operator `and`: that holds
+                every term), best first; top k returned
 
 (`elasticsearch_tpu/ops/reference_impl.py` is the program's copy of the
 same arithmetic; this one is the benchmark's and stays put.) The list a
@@ -27,6 +28,10 @@ import numpy as np
 K1 = 1.2
 B = 0.75
 REL_TOL = 1e-5
+#: the operators of a `match` query that have a reference
+OPERATORS = ("or", "and")
+#: `sum_by_doc` walks the doc axis where the postings pass this share of it
+DENSE_SHARE = 4
 
 
 # ---------------------------------------------------------------------------
@@ -154,16 +159,47 @@ def bm25_idf(doc_count: int, doc_freq: int) -> float:
     return float(np.log(1.0 + (doc_count - doc_freq + 0.5) / (doc_freq + 0.5)))
 
 
+def sum_by_doc(docs: np.ndarray, scores: np.ndarray, n_docs: int, held: int = 1
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """Postings of several terms, one term after another → (the docs that
+    have at least `held` of them, ascending; each one's f64 sum of
+    `scores`, added in the order given). Two routines, equal to the bit
+    (either way a doc's scores are added in input order): a sort of the
+    postings (`np.unique`), or, where they pass `1/DENSE_SHARE` of the
+    corpus's docs, a `np.bincount` over the whole doc axis, which the half
+    a million postings of a stop-word reach sooner than a sort does."""
+    if docs.shape[0] * DENSE_SHARE > n_docs:
+        # a mask first: nonzero() of a bool array is many times faster
+        uniq = np.flatnonzero(np.bincount(docs, minlength=n_docs) >= held)
+        return uniq, np.bincount(docs, weights=scores, minlength=n_docs)[uniq]
+    uniq, inv = np.unique(docs, return_inverse=True)
+    sums = np.bincount(inv, weights=scores, minlength=uniq.shape[0])
+    if held > 1:
+        every = np.bincount(inv, minlength=uniq.shape[0]) >= held
+        uniq, sums = uniq[every], sums[every]
+    return uniq, sums
+
+
 def reference_topk(shard_indexes: Sequence[ShardIndex], terms: Sequence[int],
-                   k: int) -> Tuple[int, np.ndarray, np.ndarray]:
-    """OR of `terms` → (total hits, global docs, f32 scores), best first,
-    ties by lower doc, cut past k at the end of the near-tie at the cut."""
+                   k: int, operator: str = "or"
+                   ) -> Tuple[int, np.ndarray, np.ndarray]:
+    """`terms` under `operator` → (total hits, global docs, f32 scores),
+    best first, ties by lower doc, cut past k at the end of the near-tie at
+    the cut. `or`: every doc that holds a term. `and`: the docs that hold
+    every term (a term that a shard lacks leaves that shard empty), scored
+    as `or` scores them: the sum over all terms by the shard's own
+    statistics."""
+    if operator not in OPERATORS:
+        raise ValueError(f"unknown operator [{operator}]: one of {OPERATORS}")
     all_docs, all_scores = [], []
     for sh in shard_indexes:
         docs_parts, score_parts = [], []
         for t in terms:
             docs, tf = sh.postings.get(int(t), (None, None))
             if docs is None or docs.shape[0] == 0:
+                if operator == "and":
+                    docs_parts = []
+                    break
                 continue
             w = bm25_idf(sh.doc_count, int(docs.shape[0])) * (K1 + 1.0)
             tff = tf.astype(np.float64)
@@ -171,11 +207,12 @@ def reference_topk(shard_indexes: Sequence[ShardIndex], terms: Sequence[int],
             score_parts.append(w * tff / (tff + sh.denom_add[docs]))
         if not docs_parts:
             continue
-        d = np.concatenate(docs_parts)
-        s = np.concatenate(score_parts)
         # f64 sums: their order moves the last bit, far inside REL_TOL
-        uniq, inv = np.unique(d, return_inverse=True)
-        sums = np.bincount(inv, weights=s, minlength=uniq.shape[0])
+        # `and`: a term's postings hold a doc once, so a doc with as many
+        # postings as the query has terms holds them all
+        uniq, sums = sum_by_doc(np.concatenate(docs_parts),
+                                np.concatenate(score_parts), sh.denom_add.shape[0],
+                                held=len(terms) if operator == "and" else 1)
         all_docs.append(uniq)
         all_scores.append(sums.astype(np.float32))
     if not all_docs:
@@ -183,11 +220,21 @@ def reference_topk(shard_indexes: Sequence[ShardIndex], terms: Sequence[int],
     docs = np.concatenate(all_docs)
     scores = np.concatenate(all_scores)
     pos = scores > 0
-    docs, scores = docs[pos], scores[pos]
+    if not pos.all():
+        docs, scores = docs[pos], scores[pos]
     total = int(docs.shape[0])
-    keep = np.arange(total)
+    keep = np.arange(min(total, k))
     if total > k:
         kth = np.partition(scores, total - k)[total - k]
         keep = np.flatnonzero(scores >= kth * (1.0 - 10 * REL_TOL))
     order = keep[np.lexsort((docs[keep], -scores[keep].astype(np.float64)))]
     return total, docs[order], scores[order]
+
+
+def stored_name(operator: str) -> str:
+    """The file of an index directory that holds the reference top-k of
+    every query under `operator`: one file an operator, `reference.npz`
+    the `or` one under the name it always had."""
+    if operator not in OPERATORS:
+        raise ValueError(f"no reference for operator [{operator}]: one of {OPERATORS}")
+    return "reference.npz" if operator == "or" else f"reference-{operator}.npz"

@@ -80,7 +80,8 @@ def warm_strata(spec: Dict[str, Any], postings: np.ndarray,
     """The warm-up's phases → (stratum, its queries, [(clients, requests
     per client)]). A stratum is a slice of the query set by `postings`
     (the heaviest shard's postings under the query's terms: by rank with
-    `postings_from`/`postings_to`, by value with `postings_min`) or by
+    `postings_from`/`postings_to`, by value with `postings_min` ≤ p <
+    `postings_max`, the edges of a rung of the launch ladder) or by
     number of terms, driven alone in a closed loop at each client count.
     The mix reaches some launch shapes only now and then (a batch of
     nothing but wide queries, a lone 12-term query); the extremes alone
@@ -99,6 +100,8 @@ def warm_strata(spec: Dict[str, Any], postings: np.ndarray,
             keep &= frac < float(st["postings_to"])
         if "postings_min" in st:
             keep &= postings >= int(st["postings_min"])
+        if "postings_max" in st:
+            keep &= postings < int(st["postings_max"])
         if "terms_min" in st:
             keep &= n_terms >= int(st["terms_min"])
         if "terms_max" in st:

@@ -49,7 +49,8 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
 
-from esbench import compare, corpus, hostspans, layers, tracered, traffic, window  # noqa: E402
+from esbench import (compare, corpus, hostspans, layers, reference, tracered,  # noqa: E402
+                     traffic, window)
 from esbench.loadgen import now_ns, sleep_until  # noqa: E402
 from esbench.peaks import peaks_for  # noqa: E402
 
@@ -93,6 +94,10 @@ def load_cell(workload: str, bench_path: Optional[str] = None,
         config = json.load(f)
     spec = traffic.load_traffic(os.path.join(
         traffic_dir or os.path.join(HERE, "traffic"), cell["traffic"] + ".json"))
+    if spec.get("operator", "or") not in reference.OPERATORS:
+        raise BenchFailure(f"traffic [{cell['traffic']}] sends operator "
+                           f"[{spec['operator']}], which has no reference: "
+                           f"esbench/reference.py knows {reference.OPERATORS}")
 
     def metrics_of(kind: str) -> List[Dict[str, Any]]:
         return [m for m in bench[kind]
@@ -125,14 +130,37 @@ def ensure_index(config: Dict[str, Any]) -> Tuple[str, float]:
     cfg_path = os.path.join(final, "config.json")
     with open(cfg_path, "w", encoding="utf-8") as f:
         json.dump(config, f, indent=1)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
     log(f"building the index of [{config['name']}] in {final}")
-    proc = subprocess.run([sys.executable, os.path.join(HERE, "build_index.py"),
-                           "--config", cfg_path, "--out", final], env=env,
-                          stdout=sys.stderr, check=False)
-    if proc.returncode != 0:
-        raise BenchFailure(f"build_index.py exited {proc.returncode}")
+    rc = build_child("--config", cfg_path, "--out", final)
+    if rc != 0:
+        raise BenchFailure(f"build_index.py exited {rc}")
     return final, time.monotonic() - t0
+
+
+def build_child(*args: str) -> int:
+    """build_index.py in a process of its own, off the chip → its exit code."""
+    return subprocess.run([sys.executable, os.path.join(HERE, "build_index.py"), *args],
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          stdout=sys.stderr, check=False).returncode
+
+
+def ensure_reference(index_dir: str, operator: str) -> Tuple[str, float]:
+    """The stored reference of the traffic's operator → (file, seconds
+    spent making it). An index directory is built with the `or` one; that
+    of another operator is added by the first run that asks for it, from
+    the seed and without indexing again. Responses are never held to the
+    top-k of another operator: no file, no run."""
+    path = os.path.join(index_dir, reference.stored_name(operator))
+    if os.path.isfile(path):
+        return path, 0.0
+    t0 = time.monotonic()
+    log(f"adding the reference of operator [{operator}] to {index_dir}")
+    rc = build_child("--config", os.path.join(index_dir, "config.json"), "--out",
+                     index_dir, "--reference-only", "--operator", operator)
+    if rc != 0 or not os.path.isfile(path):
+        raise BenchFailure(f"build_index.py --reference-only exited {rc}: "
+                           f"no reference of operator [{operator}]")
+    return path, time.monotonic() - t0
 
 
 # ---------------------------------------------------------------------------
@@ -254,13 +282,16 @@ class GcTimer:
 
 def settle_heap() -> float:
     """One full collection of the serving process before the stream starts →
-    its seconds. The node's heap holds millions of long-lived objects, and
-    a full collection under load stops every Python thread for about a
-    second (PERF.md, Findings, PR 23). When the next one falls depends on
-    how many objects survived since the last; after this call that count
-    starts from zero in every run, so the full collections fall at the
-    same points of the stream instead of anywhere. Nothing is disabled or
-    tuned: every pause inside the window is in the window's numbers."""
+    its seconds. When the next one falls depends on how many objects
+    survived since the last; after this call that count starts from zero
+    in every run, so the full collections fall at the same points of the
+    stream instead of anywhere. Since PR 34 the node keeps its standing
+    heap frozen (`tracing.StandingHeap`), so this walks what the warm-up's
+    requests left, in milliseconds, and a full collection under load is
+    tens of milliseconds, three seconds apart, where it was a second that
+    stopped every Python thread (PERF.md, Findings, PR 23 and PR 34). The
+    benchmark disables and tunes nothing of the collector: every pause
+    inside the window is in the window's numbers."""
     t0 = time.monotonic()
     gc.collect()
     return time.monotonic() - t0
@@ -356,12 +387,15 @@ def sample_queries(seed: int, n_queries: int) -> List[int]:
 
 
 def check_samples(samples: Dict[int, bytes], ref: Any, k: int
-                  ) -> Tuple[int, int, float, List[str]]:
-    """→ (checked, near-tie swaps, widest relative score gap, mismatches)."""
+                  ) -> Tuple[int, int, float, List[str], int]:
+    """→ (checked, near-tie swaps, widest relative score gap, mismatches,
+    responses whose `hits.total` is a lower bound: `compare_response`
+    admits one that is not above the reference's count). `ref` is the
+    stored reference of the operator the samples were sent under."""
     ref_k = int(ref["k"])
     if k > ref_k:
         raise BenchFailure(f"size {k} is beyond the stored reference's {ref_k}")
-    swaps, gap, bad = 0, 0.0, []
+    swaps, gap, bad, bounds = 0, 0.0, [], 0
     offsets, docs, scores, totals = (ref["offsets"], ref["docs"], ref["scores"],
                                      ref["totals"])
     for q, body in sorted(samples.items()):
@@ -369,13 +403,14 @@ def check_samples(samples: Dict[int, bytes], ref: Any, k: int
         ref_scores = scores[lo:hi].tolist()
         try:
             resp = json.loads(body)
+            bounds += resp["hits"]["total"]["relation"] != "eq"
             gap = max(gap, compare.score_gap(resp, ref_scores))
             swaps += compare.compare_response(
                 resp, int(totals[q]),
                 [corpus.doc_id(d) for d in docs[lo:hi].tolist()], ref_scores, k)
         except (compare.Mismatch, KeyError, ValueError, TypeError) as exc:
             bad.append(f"query {q}: {exc}")
-    return len(samples), swaps, gap, bad
+    return len(samples), swaps, gap, bad, bounds
 
 
 def facts_of(m: Dict[str, Any], seconds: float, setup: Dict[str, float],
@@ -451,11 +486,16 @@ def warm_up(gens: Generators, conn: http.client.HTTPConnection, spec: Dict[str, 
                     "cmd": "warm", "queries": idx.tolist(), "total_clients": n_clients,
                     "clients": gens.share(n_clients, p),
                     "requests_per_client": per_client} for p in range(len(gens))])
+                compiled: Dict[str, List[float]] = {}  # program → its compiles' seconds
+                for _t, fun, secs in compiles.events[n0:]:
+                    compiled.setdefault(fun, []).append(secs)
                 log(f"warm [{name}] {len(idx)} queries x{n_clients} clients: "
                     f"{sum(r['sent'] for r in replies)} sent, "
                     f"{sum(r['failed'] for r in replies)} failed, "
                     f"{max(r['seconds'] for r in replies):.1f}s, "
                     f"{len(compiles.events) - n0} compile events"
+                    + "".join(f" {fun} x{len(secs)} max {max(secs):.1f}s"
+                              for fun, secs in sorted(compiled.items())[:16])
                     + "".join(f"; failure {f}" for r in replies for f in r["failures"][:1]))
                 if pack_s is None:  # the first request built and placed the pack
                     pack_s = float(get_stats(conn)["stages"].get(
@@ -558,6 +598,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     log(f"device {device}; cell {args.workload} seed {args.seed}")
 
     index_dir, index_s = ensure_index(config)
+    ref_path, ref_s = ensure_reference(index_dir, spec.get("operator", "or"))
     with open(os.path.join(index_dir, "manifest.json"), "r", encoding="utf-8") as f:
         manifest = json.load(f)
     qnpz = np.load(os.path.join(index_dir, "queries.npz"))
@@ -584,7 +625,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     server = serve(node, port=0)
     conn = http.client.HTTPConnection("127.0.0.1", server.server_address[1],
                                       timeout=600)
-    setup = {"index_s": index_s, "load_s": time.monotonic() - t_load}
+    setup = {"index_s": index_s + ref_s, "load_s": time.monotonic() - t_load}
     try:
         if node.tpu_search is None:
             raise BenchFailure("the node has no TPU serving path")
@@ -602,7 +643,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         plans = probe_plans(args.probe, spec) if args.probe else [(spec, args.seconds)]
         if args.trace or args.probe:
             gc.callbacks.append(gc_timer)
-        ref = np.load(os.path.join(index_dir, "reference.npz"))
+        ref = np.load(ref_path)
         line: Dict[str, Any] = {}
         for i, (plan_spec, seconds) in enumerate(plans):
             m = measure(gens, conn, plan_spec, args.seed + i, seconds, n_queries,
@@ -649,7 +690,8 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
     kernel = compare.kernel_checks(m["before"], m["after"], answered,
                                    int(loaded["cell"]["chips"]), device["platform"])
     problems = compare.failures(kernel)
-    checked, swaps, gap, bad = check_samples(m["samples"], ref, int(spec["size"]))
+    checked, swaps, gap, bad, bounds = check_samples(m["samples"], ref,
+                                                     int(spec["size"]))
     if checked == 0:
         problems.append("no sampled response to check")
     problems += bad[:5]
@@ -730,7 +772,8 @@ def result_line(m: Dict[str, Any], loaded: Dict[str, Any], spec: Dict[str, Any],
                                            "unit": metric["unit"]}
     out_device = dict(device, memory_peak_bytes=m["memory_peak_bytes"])
     log(f"stream: {sent} sent, {answered} answered 200, {checked} sampled responses "
-        f"held to the reference ({swaps} near-tie swaps), window {counts}")
+        f"held to the reference ({swaps} near-tie swaps, {bounds} with hits.total a "
+        f"lower bound), window {counts}")
     slices = np.histogram(m["done_ns"][m["ok"]],
                           bins=np.arange(t0, t1 + 1, int(5e9)))[0]
     log("completions a second in each 5 s of the window: "

@@ -144,7 +144,9 @@ def _trace_facts(path, trains=4.0):
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_every_new_metric_has_a_reader_that_is_silent_on_empty_facts(name):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == name + ".closed")
-    assert entry["workloads"] == ["msmarco-1chip.or1000-closed384"]
+    # the cell they were added for lists them, whatever cells came after
+    assert "msmarco-1chip.or1000-closed384" in entry["workloads"]
+    assert set(entry["workloads"]) <= {w["name"] for w in BENCH["workloads"]}
     assert entry["moves"] == "qps"
     reader = layers.find_reader(entry["name"])
     assert reader is not None
@@ -284,4 +286,6 @@ def test_the_new_entries_only_add_to_the_benchmark():
     assert len(names) >= 16 + len(NEW_METRICS) and len(names) == len(set(names))
     layers_named = {m["layer"] for m in BENCH["per_layer"]}
     assert "host (all Python threads)" in layers_named
-    assert len(BENCH["workloads"]) == 1 and len(BENCH["configs"]) == 1
+    # later PRs add cells and configurations; none takes the first away
+    assert BENCH["workloads"][0]["name"] == "msmarco-1chip.or1000-closed384"
+    assert BENCH["configs"][0]["name"] == "msmarco-1chip"

@@ -53,8 +53,10 @@ def test_declared_in_benchmark_json():
     with open(os.path.join(ROOT, "BENCHMARK.json"), "r", encoding="utf-8") as f:
         bench = json.load(f)
     entry = [m for m in bench["per_layer"] if m["name"] == NAME]
-    assert entry == [bench["per_layer"][-1]]
+    assert len(entry) == 1                            # wherever later entries put it
+    workloads = entry[0].pop("workloads")
     assert entry[0] == {
         "name": NAME, "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "HTTP + REST", "moves": "qps",
-        "workloads": ["msmarco-1chip.or1000-closed384"]}
+        "source": "program_counter", "layer": "HTTP + REST", "moves": "qps"}
+    assert "msmarco-1chip.or1000-closed384" in workloads
+    assert set(workloads) <= {w["name"] for w in bench["workloads"]}

@@ -10,9 +10,10 @@ at the cell's own size, for the 256 queries that a run of each seed samples,
     python3 benchmarks/tools/control.py <workload> <seed> [<seed> ...]
 
 and in `tests/test_correct_is_false.py` at a toy size. Needs the
-configuration's built index (any run of the cell in this checkout leaves
-it) and no chip. Prints one line a seed: the reference in the program's
-place (has to be correct), then the control (has not to be).
+configuration's built index with the reference of the traffic's operator
+(any run of the cell in this checkout leaves both) and no chip. Prints one
+line a seed: the reference in the program's place (has to be correct),
+then the control (has not to be).
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ import numpy as np
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH_DIR)
 
-from esbench import compare, corpus  # noqa: E402
+from esbench import compare, corpus, reference  # noqa: E402
 
 
 def to_bfloat16(x: np.ndarray) -> np.ndarray:
@@ -45,8 +46,8 @@ def response(docs: Sequence[int], scores: Sequence[float], total: int) -> Dict[s
 
 
 def samples(ref: Any, queries: Sequence[int], k: int, lowered: bool) -> Dict[int, bytes]:
-    """The stored reference (`reference.npz`) of `queries` as response
-    bodies: as it stands, or `lowered` to bfloat16 and ranked again."""
+    """The stored reference (that of the traffic's operator) of `queries`
+    as response bodies: as it stands, or `lowered` to bfloat16 and ranked again."""
     out = {}
     for q in queries:
         lo, hi = int(ref["offsets"][q]), int(ref["offsets"][q + 1])
@@ -63,13 +64,15 @@ def samples(ref: Any, queries: Sequence[int], k: int, lowered: bool) -> Dict[int
 def main() -> int:
     import run
     loaded = run.load_cell(sys.argv[1])
-    ref = np.load(os.path.join(run.index_dir_for(loaded["config"]), "reference.npz"))
+    ref = np.load(os.path.join(
+        run.index_dir_for(loaded["config"]),
+        reference.stored_name(loaded["traffic"].get("operator", "or"))))
     k, n_queries = int(loaded["traffic"]["size"]), ref["totals"].shape[0]
     ok = True
     for seed in map(int, sys.argv[2:]):
         sample = run.sample_queries(seed, n_queries)
         for name, lowered in (("reference", False), ("control_bfloat16", True)):
-            checked, _swaps, gap, bad = run.check_samples(
+            checked, _swaps, gap, bad, _bounds = run.check_samples(
                 samples(ref, sample, k, lowered), ref, k)
             print(f"seed {seed} {name}: responses_sampled {checked} responses_differing "
                   f"{len(bad)} score_rel_gap_max {gap!r} limit {compare.REL_TOL!r}", flush=True)
