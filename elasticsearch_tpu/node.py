@@ -624,8 +624,8 @@ class Node:
             yield ("search.tpu.pack_queues", nl, depths["queues"],
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
-                CROSS_CHIP_COUNTS, EXACT_ENTRY_COUNTS, FULL_ENTRY_COUNTS,
-                HOLD_EXIT_COUNTS,
+                CROSS_CHIP_COUNTS, EXACT_ENTRY_COUNTS, EXACT_PIN_COUNTS,
+                EXACT_RESULT_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
                 KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
                 ROUTE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
@@ -652,6 +652,14 @@ class Node:
                 yield ("kernel.exact_entries", labels, counter)
             for labels, counter in FULL_ENTRY_COUNTS.items():
                 yield ("kernel.full_entries", labels, counter)
+            # an exact launch's queries and those below its slot pin;
+            # the exact kernel's answers and the empty ones:
+            # es_tpu_kernel_exact_pin_total{kind=...},
+            # es_tpu_kernel_exact_results_total{kind=...}
+            for labels, counter in EXACT_PIN_COUNTS.items():
+                yield ("kernel.exact_pin", labels, counter)
+            for labels, counter in EXACT_RESULT_COUNTS.items():
+                yield ("kernel.exact_results", labels, counter)
             # launches on a mesh of several devices, their rows and
             # devices: es_tpu_kernel_cross_chip_total{kind=...}
             for labels, counter in CROSS_CHIP_COUNTS.items():

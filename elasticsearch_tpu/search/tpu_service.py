@@ -1838,6 +1838,14 @@ ROUTE_COUNTS = LabeledCounters("route")
 #: entries their static shape sorts, rows × slots × chunk length
 #: (`padded`) → es_tpu_kernel_exact_entries_total
 EXACT_ENTRY_COUNTS = LabeledCounters("kind")
+#: real queries in the exact launches (`rows`) and those of them whose
+#: own slot pin lies below their launch's (`rows_under`: a launch takes
+#: the pin of its widest query, so they ride in lanes a launch of their
+#: own pin would not sort) → es_tpu_kernel_exact_pin_total
+EXACT_PIN_COUNTS = LabeledCounters("kind")
+#: queries the exact kernel answered (`queries`) and those of them with
+#: no hit (`empty`: total 0) → es_tpu_kernel_exact_results_total
+EXACT_RESULT_COUNTS = LabeledCounters("kind")
 #: the same pair for the full-postings launches (`full_s<slots>`): Σ of
 #: the slots' lengths, and rows × slots × chunk length × shard rows
 #: dispatched → es_tpu_kernel_full_entries_total
@@ -1861,6 +1869,10 @@ for _label in ("real", "padded"):
     FULL_ENTRY_COUNTS.child(_label)
 for _label in ("launches", "rows", "devices"):
     CROSS_CHIP_COUNTS.child(_label)
+for _label in ("rows", "rows_under"):
+    EXACT_PIN_COUNTS.child(_label)
+for _label in ("queries", "empty"):
+    EXACT_RESULT_COUNTS.child(_label)
 
 
 def _count_cross_chip(mesh, rows: int) -> None:
@@ -2136,6 +2148,25 @@ def _exact_slot_pin(t_slots: int, t_window: int) -> int:
     return dist._shape_bucket(
         t_slots, EXACT_MIN_SLOTS if t_window <= _PRUNE_WINDOW
         else EXACT_LONG_MIN_SLOTS)
+
+
+def _rows_under_pin(lengths: np.ndarray, terms: Sequence[Sequence[str]],
+                    t_pin: int) -> int:
+    """How many of an exact launch's queries would launch narrower
+    alone: those whose own `_exact_slot_pin` lies below `t_pin`, the pin
+    of the launch's widest. `lengths` [shard rows, rows, slots] is the
+    batch's; its first `len(terms)` rows are the queries. A query needs
+    the slots that hold postings on its heaviest shard row, and one a
+    term at the least (a term a row lacks keeps a slot without postings).
+    Every pin is a floor times a power of two, so a pin is below
+    `t_pin` exactly where need and floor fit `t_pin` / 2."""
+    n_terms = np.fromiter(map(len, terms), np.int64, len(terms))
+    need = np.maximum(
+        np.count_nonzero(lengths[:, :len(terms)], axis=2).max(axis=0),
+        n_terms)
+    floor = np.where(n_terms > _PRUNE_WINDOW, EXACT_LONG_MIN_SLOTS,
+                     EXACT_MIN_SLOTS)
+    return int(np.count_nonzero(2 * np.maximum(need, floor) <= t_pin))
 
 
 def _exact_k_kernel(k: int) -> int:
@@ -2430,8 +2461,9 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     rows = _serving_bucket(len(flats))
     if program is not None:
         rows, variant = max(rows, program.rows), program.variant
+    terms = [f.terms for f in flats]
     batch = dist.prepare_query_batch(
-        pack, [f.terms for f in flats],
+        pack, terms,
         boosts=[f.boost for f in flats],
         min_counts=[f.min_count for f in flats],
         pad_batch_to=rows,
@@ -2466,6 +2498,9 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     LAUNCH_COUNTS.inc(label)
     EXACT_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
     EXACT_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
+    EXACT_PIN_COUNTS.inc("rows", n=len(flats))
+    EXACT_PIN_COUNTS.inc("rows_under",
+                         n=_rows_under_pin(batch.lengths, terms, t_pin))
     _count_cross_chip(mesh, rows)
     t_disp = time.perf_counter()
     vals, gids, totals = dist.distributed_search_raw(
@@ -2496,8 +2531,11 @@ def _finish_exact(launch: Dict[str, Any],
         # variant-tagged, like `exact_dispatch.<variant>`
         stages.add(f"exact_device_wait.{launch['variant']}",
                    time.perf_counter() - t_dev)
+    n = launch["n"]
+    EXACT_RESULT_COUNTS.inc("queries", n=n)
+    EXACT_RESULT_COUNTS.inc("empty", n=n - int(np.count_nonzero(totals[:n])))
     return _columnar_results(launch["resident"], vals, gids, totals,
-                             launch["n"], lambda qi: "eq",
+                             n, lambda qi: "eq",
                              k_cap=launch["k"],
                              variant=launch.get("variant"))
 
@@ -4338,6 +4376,8 @@ class TpuSearchService:
                 "exact_entries": EXACT_ENTRY_COUNTS.counts(),
                 "full_entries": FULL_ENTRY_COUNTS.counts(),
                 "cross_chip": CROSS_CHIP_COUNTS.counts(),
+                "exact_pin": EXACT_PIN_COUNTS.counts(),
+                "exact_results": EXACT_RESULT_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),
                 "render": RENDER_COUNTS.counts(),
