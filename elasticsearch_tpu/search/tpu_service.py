@@ -41,7 +41,7 @@ import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeout
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -1785,7 +1785,9 @@ FULL_READY_SLOTS = 32
 #: (the unit of `_split_full_train`'s model: a row of the batch bucket ×
 #: a slot of the rung × a shard row the device holds): fixed device time
 #: (2.5 ms) and the launch thread's host time (6 ms) ÷ the device time
-#: of a slot-row (11-18 µs), from the chip table of PERF.md section 5
+#: of a slot-row (11-18 µs), from the chip table of PERF.md section 5.
+#: The exact kernel's ladder is split with the same constant: its own
+#: table gives 4.3 ms + 4.2 ms ÷ 11-23 µs, 380-790 (same section)
 FULL_LAUNCH_SLOT_ROWS = 512
 PREFIX_CAP = 4096               # base prefix for ad-hoc prefix runs
 PREFIX_CAP2 = 16384             # hot-tier prefix (queries over-width)
@@ -1841,7 +1843,10 @@ EXACT_ENTRY_COUNTS = LabeledCounters("kind")
 #: real queries in the exact launches (`rows`) and those of them whose
 #: own slot pin lies below their launch's (`rows_under`: a launch takes
 #: the pin of its widest query, so they ride in lanes a launch of their
-#: own pin would not sort) → es_tpu_kernel_exact_pin_total
+#: own pin would not sort: what `_split_exact_train` left on the table);
+#: trains that held an exact query (`trains`) and the exact launches
+#: dispatched for them (`launches`: `launches` ÷ `trains` is how far a
+#: train was split by pin) → es_tpu_kernel_exact_pin_total
 EXACT_PIN_COUNTS = LabeledCounters("kind")
 #: queries the exact kernel answered (`queries`) and those of them with
 #: no hit (`empty`: total 0) → es_tpu_kernel_exact_results_total
@@ -1869,7 +1874,7 @@ for _label in ("real", "padded"):
     FULL_ENTRY_COUNTS.child(_label)
 for _label in ("launches", "rows", "devices"):
     CROSS_CHIP_COUNTS.child(_label)
-for _label in ("rows", "rows_under"):
+for _label in ("rows", "rows_under", "trains", "launches"):
     EXACT_PIN_COUNTS.child(_label)
 for _label in ("queries", "empty"):
     EXACT_RESULT_COUNTS.child(_label)
@@ -1939,6 +1944,13 @@ def _serving_bucket(n: int, cap: int = 128) -> int:
     return _batch_bucket(n, 1024)
 
 
+def _serving_buckets(max_batch: int = 128) -> List[int]:
+    """Every bucket `_serving_bucket` gives a train of up to `max_batch`
+    queries: the batch rows its launches compile for."""
+    return sorted({_serving_bucket(n) for n in (1, 9, 65, max_batch)
+                   if n <= max_batch})
+
+
 def _slots_needed(resident: ResidentPack, flat: FlatQuery) -> int:
     """Max over shard rows of Σ_terms ceil(row_len/CHUNK): the slot
     count a FULL-postings sorted-merge of this query needs. Terms
@@ -1980,37 +1992,44 @@ def _full_bucket(slots: int) -> Optional[int]:
     return None
 
 
-def _group_launches(n: int, slots: int, shard_rows: int
-                    ) -> Tuple[int, List[int]]:
+def _group_launches(n: int, slots: int, shard_rows: int,
+                    row_buckets: Sequence[int]) -> Tuple[int, List[int]]:
     """`n` queries at one rung → (modelled cost, the rows of each launch):
-    chunks of one of the rung's row buckets, whichever costs least by
+    chunks of one of the rung's `row_buckets`, whichever costs least by
     Σ launches (rows × slots × `shard_rows` + FULL_LAUNCH_SLOT_ROWS)."""
     return min(
         (chunks * (rows * slots * shard_rows + FULL_LAUNCH_SLOT_ROWS),
          [rows] * chunks)
-        for rows in FULL_ROW_BUCKETS[slots]
+        for rows in row_buckets
         for chunks in (-(-n // rows),))
 
 
-def _split_full_train(groups: Dict[int, List[int]], shard_rows: int = 1
+def _split_full_train(groups: Dict[int, List[int]], shard_rows: int = 1,
+                      ladder: Mapping[int, Sequence[int]] = FULL_ROW_BUCKETS
                       ) -> List[Tuple[int, List[int]]]:
-    """A train's full-path queries, grouped by the narrowest rung that
-    holds each → the launches, as (slots, queries). A group launches at
+    """A train's queries of one launch path, grouped by the narrowest
+    rung of `ladder` that holds each → the launches, as (slots, queries).
+    `ladder` gives the rungs the split may launch at and the row buckets
+    each has programs at: the full-postings ladder whole
+    (FULL_ROW_BUCKETS, the default), or the exact kernel's slot pins
+    that this train holds (`_split_exact_train`). A group launches at
     its own rung or rides at a wider one (always correct: wider holds
     everything), and a rung's queries go in chunks of one of its row
     buckets (`_group_launches`); of the few such splits, the one that
     costs least by the model: a launch takes the device about as long
     as the lanes it sorts on the shard rows a device holds, whatever it
-    carries, and the host a constant (PERF.md section 5). The first of
-    equals keeps a group at its own rung."""
-    rungs = FULL_SLOT_BUCKETS
+    carries, and a constant besides, FULL_LAUNCH_SLOT_ROWS on either
+    ladder (PERF.md section 5). The first of equals keeps a group at its
+    own rung."""
+    rungs = sorted(ladder)
     best_cost, best = math.inf, []
     for ride in itertools.product(*(rungs[i:] for i in range(len(rungs)))):
         merged: Dict[int, List[int]] = {}
         for own, at in zip(rungs, ride):
             if groups.get(own):
                 merged[at] = merged.get(at, []) + groups[own]
-        plans = [(b, idxs, *_group_launches(len(idxs), b, shard_rows))
+        plans = [(b, idxs, *_group_launches(len(idxs), b, shard_rows,
+                                            ladder[b]))
                  for b, idxs in sorted(merged.items())]
         cost = sum(plan[2] for plan in plans)
         if cost < best_cost:
@@ -2062,8 +2081,7 @@ def full_program_set(resident: ResidentPack, k: int, max_batch: int = 128,
     pruned path at all), `k` and the node's constants alone."""
     if resident.imp_device_arrays is None:
         return []
-    reach = {_serving_bucket(n) for n in (1, 9, 65, max_batch)
-             if n <= max_batch}
+    reach = _serving_buckets(max_batch)
     return [FullProgram(rows, slots, _pruned_k_out(k),
                         variant or _pruned_variant())
             for slots in FULL_SLOT_BUCKETS if slots <= FULL_READY_SLOTS
@@ -2233,8 +2251,7 @@ def exact_program_set(resident: ResidentPack, k: int, max_batch: int = 128,
     (and, with `max_slots`, of at most so many slots): a function of the
     pack, `k` and the node's constants alone. Serving compiles nothing
     outside it; `prewarm` compiles members of it."""
-    rows_set = sorted({_serving_bucket(n) for n in (1, 9, 65, max_batch)
-                       if n <= max_batch})
+    rows_set = _serving_buckets(max_batch)
     k_kernel = _exact_k_kernel(k)
     variants = _exact_variants(resident)
     by_window: Dict[int, int] = {}   # window pin → the most terms under it
@@ -2273,13 +2290,39 @@ def _exact_reason(flat: FlatQuery, k: int, can_prune: bool) -> Optional[str]:
     return None
 
 
+def _split_exact_train(resident: ResidentPack, flats: Sequence[FlatQuery],
+                       exact_idx: Sequence[int], shard_rows: int,
+                       max_batch: int = 128) -> List[List[int]]:
+    """A train's exact queries → the queries of each exact launch. A
+    query's own pin is the one it would launch at alone
+    (`_exact_slot_pin` of the slots it needs, `_slots_needed`, under the
+    window of its terms); the train is split as the full-postings path
+    splits its own (`_split_full_train`), over the pins it holds at the
+    row buckets of `_serving_bucket`: a narrow query rides at a wider
+    pin only where that launch is made anyway and the lanes it adds
+    cost less than a launch of its own. `_launch_exact` pins each launch
+    by its widest query, so a launch is a member of `exact_program_set`
+    whatever the split."""
+    groups: Dict[int, List[int]] = {}
+    for i in exact_idx:
+        pin = _exact_slot_pin(_slots_needed(resident, flats[i]),
+                              _exact_window(len(flats[i].terms)))
+        groups.setdefault(pin, []).append(i)
+    row_buckets = _serving_buckets(max_batch)
+    return [idxs for _pin, idxs in _split_full_train(
+        groups, shard_rows, {pin: row_buckets for pin in groups})]
+
+
 def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
                       k: int, mesh=None,
                       stages: Optional[StageTimes] = None,
                       max_batch: int = 128) -> Dict[str, Any]:
     """Phase 1 of a micro-batch: host prep + ASYNC kernel dispatch for
     the tier-E pruned subset (rescore-free), the tier-H pruned subset,
-    and the exact subset (msm/AND, big k, many terms). Returns an
+    and the exact subset (msm/AND, big k, many terms). Each subset but
+    the tier-H one goes as several launches: the full-postings queries
+    split over the rungs of their ladder, the exact ones by slot pin
+    (`_split_full_train`, `_split_exact_train`). Returns an
     opaque launch state for finish_flat_batch. JAX dispatch is
     asynchronous, so the caller can launch batch N+1 while batch N
     executes on device (double-buffered serving).
@@ -2313,10 +2356,9 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
             hot_idx.append(i)
         else:
             full_groups[b].append(i)
+    shard_rows = max(1, resident.pack.num_shards // mesh.shape[SHARD_AXIS])
     full_launches = []
-    for b, idxs in _split_full_train(
-            full_groups,
-            max(1, resident.pack.num_shards // mesh.shape[SHARD_AXIS])):
+    for b, idxs in _split_full_train(full_groups, shard_rows):
         ROUTE_COUNTS.inc(f"pruned_full_s{b}", n=len(idxs))
         full_launches.append((idxs, _launch_pruned(
             resident, [flats[i] for i in idxs], k, mesh,
@@ -2324,22 +2366,28 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     st: Dict[str, Any] = {"resident": resident, "flats": flats, "k": k,
                           "mesh": mesh, "stages": stages,
                           "full_launches": full_launches,
-                          "hot_idx": hot_idx, "exact_idx": exact_idx}
+                          "hot_idx": hot_idx, "exact_launches": []}
     if hot_idx:
         ROUTE_COUNTS.inc("pruned_hot", n=len(hot_idx))
         st["hot_launch"] = _launch_pruned(
             resident, [flats[i] for i in hot_idx], k, mesh,
             prefix_cap=PREFIX_CAP2, stages=stages)
     if exact_idx:
-        st["exact_launch"] = _launch_exact(
-            resident, [flats[i] for i in exact_idx], k, mesh,
-            stages=stages)
+        st["exact_launches"] = [
+            (idxs, _launch_exact(resident, [flats[i] for i in idxs], k, mesh,
+                                 stages=stages))
+            for idxs in _split_exact_train(resident, flats, exact_idx,
+                                           shard_rows, max_batch)]
+        EXACT_PIN_COUNTS.inc("trains")
+        EXACT_PIN_COUNTS.inc("launches", n=len(st["exact_launches"]))
     return st
 
 
 def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
-    """Phase 2: materialize device results; residual tier-H validity
-    failures escalate to the deeper PREFIX_CAP3 prefix, then exact.
+    """Phase 2: materialize device results, launch by launch, each
+    launch's answers to its queries' places in the train; residual tier-H
+    validity failures escalate to the deeper PREFIX_CAP3 prefix, then
+    exact.
     On a batcher's completer thread the time spent here is its states
     `device_wait` and `decode`, once per program (an escalation launches
     from this thread, so its `prep`/`lock`/`put`/`call` are the
@@ -2375,9 +2423,9 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
         if invalid2 and stages is not None:
             stages.add("pruned_invalid_t3", 0.0, n=len(invalid2))
         tier3_idx = [retry_idx[j] for j in invalid2]
-    if "exact_launch" in st:
-        results = _finish_exact(st["exact_launch"], stages=stages)
-        for j, i in enumerate(st["exact_idx"]):
+    for idxs, launch in st["exact_launches"]:
+        results = _finish_exact(launch, stages=stages)
+        for j, i in enumerate(idxs):
             out[i] = results[j]
     if tier3_idx:
         ROUTE_COUNTS.inc("exact_escalated", n=len(tier3_idx))
@@ -2452,8 +2500,12 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     8, from 32 under long queries), run-sum window (`_exact_window`:
     powers of two from 8), chunk length (CHUNK_CAP). So the programs a
     pack can meet are the members of `exact_program_set`, each compiled
-    once ever and kept by the compilation cache. `program` (prewarm, the
-    tests) raises the pins to that member's."""
+    once ever and kept by the compilation cache. A launch takes the pin
+    of its widest query, so `launch_flat_batch` hands a train's exact
+    queries over in groups of like pins (`_split_exact_train`), a launch
+    a group; an escalation, prewarm and the tests hand theirs over
+    whole. `program` (prewarm, the tests) raises the pins to that
+    member's."""
     t_prep = time.perf_counter()
     states = tracing.current_states()
     states.switch("prep", queries=len(flats))

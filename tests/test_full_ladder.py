@@ -5,6 +5,8 @@ has a program for (`FULL_ROW_BUCKETS`), by modelled device time
 holds it; and the programs of the rungs up to `FULL_READY_SLOTS` are
 compiled by the node before it serves from them (`full_program_set`), so
 that no train compiles whatever mix of needs and whatever fill it has.
+The same split over the exact kernel's ladder (ISSUE 37): the slot pins a
+train holds at the row buckets of `_serving_bucket`.
 """
 
 from __future__ import annotations
@@ -26,7 +28,8 @@ from elasticsearch_tpu.node import Node  # noqa: E402
 from elasticsearch_tpu.parallel import distributed as dist  # noqa: E402
 from elasticsearch_tpu.search import tpu_service  # noqa: E402
 from elasticsearch_tpu.search.tpu_service import (  # noqa: E402
-    FULL_ROW_BUCKETS, FULL_SLOT_BUCKETS, FlatQuery, _split_full_train)
+    FULL_ROW_BUCKETS, FULL_SLOT_BUCKETS, FlatQuery, _serving_bucket,
+    _serving_buckets, _split_full_train)
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -75,6 +78,68 @@ def test_a_train_is_split_by_modelled_device_time(sizes, shard_rows, launches):
     for b, idxs in split:
         assert idxs and not any(i in groups[g] for i in idxs
                                 for g in FULL_SLOT_BUCKETS if g > b)
+
+
+@pytest.mark.parametrize("sizes, shard_rows, max_batch, launches", [
+    # the AND cell's mean train (ISSUE 37's reckoning; two shard rows a
+    # chip): the many at 8 slots in one tall launch, the pin-16 queries
+    # in two of eight rows, and the pin-32 ones in the 64-slot launch of
+    # eight rows that the one pin-64 query needs anyway
+    ({8: 109, 16: 13, 32: 5, 64: 1}, 2, 128,
+     [(8, 128, 109), (16, 8, 8), (16, 8, 5), (64, 8, 6)]),
+    # no pin-64 query in the train: the pin-32 ones fill up the second
+    # launch of the pin-16 ones (whose first holds none wider than 16)
+    ({8: 112, 16: 12, 32: 4}, 2, 128,
+     [(8, 128, 112), (16, 8, 8), (32, 8, 8)]),
+    ({8: 120, 16: 7, 32: 1}, 2, 128, [(8, 128, 120), (32, 8, 8)]),
+    ({8: 115, 16: 8, 32: 5}, 2, 128,
+     [(8, 128, 115), (16, 8, 8), (32, 8, 5)]),
+    # past eight rows a second launch of eight costs less than 64 rows
+    ({8: 118, 32: 10}, 2, 128, [(8, 128, 118), (32, 8, 8), (32, 8, 2)]),
+    ({8: 128}, 2, 128, [(8, 128, 128)]),
+    ({8: 60, 16: 4}, 2, 128, [(8, 64, 60), (16, 8, 4)]),
+    # 9 to 16 queries go in two launches of eight rows, 17 and more in
+    # one of 64
+    ({8: 9}, 2, 128, [(8, 8, 8), (8, 8, 1)]),
+    ({8: 17}, 2, 128, [(8, 64, 17)]),
+    # a short train of mixed pins stays one launch at its widest: lanes
+    # of eight rows cost less than a launch
+    ({8: 5, 16: 1, 32: 1}, 2, 128, [(32, 8, 7)]),
+    ({64: 3}, 2, 128, [(64, 8, 3)]),
+    ({}, 2, 128, []),
+    # Quora (one shard row; every exact query past 8 terms, so at the
+    # 32-slot floor): one group, one launch, as before the split
+    ({32: 86}, 1, 128, [(32, 128, 86)]),
+    ({32: 128}, 1, 128, [(32, 128, 128)]),
+    ({32: 40}, 1, 128, [(32, 64, 40)]),
+    ({32: 5}, 1, 128, [(32, 8, 5)]),
+    # a ladder that reaches 512 slots is searched over the pins present
+    ({8: 100, 512: 1}, 2, 128, [(8, 128, 100), (512, 8, 1)]),
+    # trains taller than 128 (a node set to them) have a taller bucket
+    ({8: 200, 16: 3}, 2, 256, [(8, 256, 200), (16, 8, 3)]),
+])
+def test_an_exact_train_is_split_by_slot_pin(sizes, shard_rows, max_batch,
+                                             launches):
+    """`_split_full_train` over the exact kernel's ladder: the pins the
+    train holds, each at the row buckets of `_serving_bucket`. A launch
+    is (its widest query's pin, its row bucket, its queries)."""
+    groups, own, at = {}, {}, 0
+    for pin, n in sizes.items():
+        groups[pin] = list(range(at, at + n))
+        own.update(dict.fromkeys(groups[pin], pin))
+        at += n
+    row_buckets = _serving_buckets(max_batch)
+    split = _split_full_train(groups, shard_rows,
+                              {pin: row_buckets for pin in groups})
+    # `_launch_exact` pins a launch by its widest query and its length
+    assert [(max(own[i] for i in idxs), _serving_bucket(len(idxs)), len(idxs))
+            for _pin, idxs in split] == launches
+    # every query in exactly one launch, at a pin that holds it, and every
+    # launch a (rows, pin) that the closed set lists
+    assert sorted(i for _pin, idxs in split for i in idxs) == list(range(at))
+    for pin, idxs in split:
+        assert pin in sizes and all(own[i] <= pin for i in idxs)
+        assert _serving_bucket(len(idxs)) in row_buckets
 
 
 # ---------------------------------------------------------------------------

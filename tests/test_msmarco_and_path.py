@@ -8,8 +8,9 @@ the rule that decides the cell's `correct` (`esbench/compare.py`): ids and
 scores equal (1e-5 relative, near-tie swaps only), `hits.total` exact with
 relation `eq`, every answer the kernel's (route `exact_min_count`, no
 fallback); what a train that mixes slot pins, operators or row buckets
-answers, bit for bit; and the counters the deployment added to
-`/_tpu/stats` (`exact_pin`, `exact_results`).
+answers, bit for bit, as one launch and split by slot pin into several
+(ISSUE 37: `_split_exact_train`); and the counters the deployment added
+to `/_tpu/stats` (`exact_pin`, `exact_results`).
 
 The corpus is 20,000 docs, not the 6,000 of the other toy deployments: a
 slot holds 4,096 postings, so only a word that more than 4,096 docs of a
@@ -38,6 +39,7 @@ from __future__ import annotations
 import http.client
 import json
 import os
+import subprocess
 import sys
 from concurrent.futures import ThreadPoolExecutor
 
@@ -406,10 +408,11 @@ def test_the_packed_variant_answers_alike(msmarco):
 
 def test_a_train_that_mixes_slot_pins_answers_each_query_as_alone(msmarco):
     """An exact launch takes the pin of its widest query: five queries of
-    8 slots and one of 16 ride at 32 beside one that needs 19. Each
-    answers what it answers alone (a launch of its own, at its own pin),
-    bit for bit, and what the reference answers; `exact_pin` counts the
-    seven and the six that rode above their own pin."""
+    8 slots and one of 16 ride at 32 beside one that needs 19 (a train
+    this short is not split: eight rows of lanes cost less than a launch).
+    Each answers what it answers alone (a launch of its own, at its own
+    pin), bit for bit, and what the reference answers; `exact_pin` counts
+    the seven and the six that rode above their own pin."""
     resident, mesh = msmarco["resident"], msmarco["mesh"]
     narrow = [msmarco["held"][n][3] for n in (2, 3, 4, 5)] + [msmarco["band"][0]]
     train = narrow + [WIDE16, WIDE32]
@@ -422,11 +425,13 @@ def test_a_train_that_mixes_slot_pins_answers_each_query_as_alone(msmarco):
     assert _rise(middle, before, "launches") == {
         "exact_ref_b8_s8_w8": 5, "exact_ref_b8_s16_w8": 1,
         "exact_ref_b8_s32_w8": 1}
-    assert _rise(middle, before, "exact_pin") == {"rows": 7}  # none under
+    assert _rise(middle, before, "exact_pin") == {       # none under
+        "rows": 7, "trains": 7, "launches": 7}
     together = tpu_service.execute_flat_batch(resident, _flats(train), SIZE, mesh)
     after = msmarco["http"].stats()
     assert _rise(after, middle, "launches") == {"exact_ref_b8_s32_w8": 1}
-    assert _rise(after, middle, "exact_pin") == {"rows": 7, "rows_under": 6}
+    assert _rise(after, middle, "exact_pin") == {
+        "rows": 7, "rows_under": 6, "trains": 1, "launches": 1}
     assert _rise(after, middle, "route") == {"exact_min_count": 7}
     padded = 8 * 32 * dist.CHUNK_CAP * SHARDS
     assert _rise(after, middle, "exact_entries")["padded"] == padded
@@ -447,6 +452,65 @@ def test_concurrent_clients_of_different_pins_answer_as_alone(msmarco):
     after = _served_by_the_kernel(msmarco, before, len(train))
     assert _rise(after, before, "exact_pin")["rows"] == len(train)
     assert _rise(after, before, "exact_results")["queries"] == len(train)
+    for q, a, b in zip(train, alone, together):
+        assert a["hits"] == b["hits"]
+        _held_to_reference(msmarco, b, q)
+
+
+def test_a_train_is_split_by_pin_and_answers_as_one_launch(msmarco):
+    """ISSUE 37: a train's exact queries go as several launches, each at
+    the pin of its own widest query. Twenty queries of 8 slots with one of
+    16 and one of 32 among them: the twenty in a launch of 64 rows at 8
+    slots, the two wide ones together in one of 8 rows at 32 (the pin-16
+    query rides: that launch is made anyway). Each query answers, at its
+    own place in the train, what one launch of all 22 at the widest pin
+    answers, to the bit; every launch is a member of `exact_program_set`;
+    the counters count one train, two launches and the one row that rode."""
+    resident, mesh = msmarco["resident"], msmarco["mesh"]
+    narrow = msmarco["band"][30:50]
+    train = narrow[:7] + [WIDE32] + narrow[7:15] + [WIDE16] + narrow[15:]
+    flats = _flats(train)
+    own = [tpu_service._exact_slot_pin(
+        tpu_service._slots_needed(resident, f),
+        tpu_service._exact_window(len(f.terms))) for f in flats]
+    assert sorted(set(own)) == [8, 16, 32] and own.count(8) == 20
+    split = tpu_service._split_exact_train(
+        resident, flats, range(len(flats)), SHARDS)
+    assert sorted(i for idxs in split for i in idxs) == list(range(len(flats)))
+    assert [[own[i] for i in idxs] for idxs in split] == [[8] * 20, [16, 32]]
+    whole = tpu_service._execute_exact(resident, flats, SIZE, mesh)
+    before = msmarco["http"].stats()
+    served = tpu_service.finish_flat_batch(
+        tpu_service.launch_flat_batch(resident, flats, SIZE, mesh))
+    after = msmarco["http"].stats()
+    launched = _rise(after, before, "launches")
+    assert launched == {"exact_ref_b64_s8_w8": 1, "exact_ref_b8_s32_w8": 1}
+    assert set(launched) <= {
+        p.label for p in tpu_service.exact_program_set(resident, SIZE)}
+    assert _rise(after, before, "exact_pin") == {
+        "rows": 22, "rows_under": 1, "trains": 1, "launches": 2}
+    assert _rise(after, before, "route") == {"exact_min_count": 22}
+    assert _rise(after, before, "exact_results")["queries"] == 22
+    assert len(served) == len(train)
+    for q, a, b in zip(train, whole, served):
+        _same_bits(a, b)
+        total, ids, scores = _ref(msmarco, q)
+        compare.compare_response(_as_response(b), total, ids, scores, SIZE)
+
+
+def test_a_split_train_over_http_answers_each_query_as_alone(msmarco):
+    """The same through the batcher and the completer: 22 clients at
+    once, each answer the one its query gets alone, whatever trains the
+    batcher forms and however each is split."""
+    narrow = msmarco["band"][50:70]
+    train = narrow[:3] + [WIDE16] + narrow[3:12] + [WIDE32] + narrow[12:]
+    alone = [msmarco["http"].search(q) for q in train]
+    before = msmarco["http"].stats()
+    together = _search_all(msmarco, train, ["and"] * len(train))
+    after = _served_by_the_kernel(msmarco, before, len(train))
+    pins = _rise(after, before, "exact_pin")
+    assert pins["rows"] == len(train)
+    assert pins["launches"] >= pins["trains"] >= 1
     for q, a, b in zip(train, alone, together):
         assert a["hits"] == b["hits"]
         _held_to_reference(msmarco, b, q)
@@ -478,18 +542,20 @@ def test_a_train_of_both_operators_answers_each_by_its_own(msmarco):
 
 
 def test_the_same_query_under_both_row_buckets(msmarco):
-    """A train of up to 8 queries launches at 8 rows, one of 9 to 64 at
-    64: another program, the same answer to the bit."""
+    """A train of up to 8 queries launches at 8 rows, one of 17 to 64 at
+    64 (one of 9 to 16 as two launches of 8 rows): another program, the
+    same answer to the bit."""
     resident, mesh = msmarco["resident"], msmarco["mesh"]
     q = msmarco["held"][4][9]
-    fill = [q] + msmarco["band"][10:18]
+    fill = [q] + msmarco["band"][10:26]
     before = msmarco["http"].stats()
     short = tpu_service.execute_flat_batch(resident, _flats([q]), SIZE, mesh)
     tall = tpu_service.execute_flat_batch(resident, _flats(fill), SIZE, mesh)
     after = msmarco["http"].stats()
     assert _rise(after, before, "launches") == {
         "exact_ref_b8_s8_w8": 1, "exact_ref_b64_s8_w8": 1}
-    assert _rise(after, before, "exact_pin") == {"rows": 1 + len(fill)}
+    assert _rise(after, before, "exact_pin") == {
+        "rows": 1 + len(fill), "trains": 2, "launches": 2}
     _same_bits(short[0], tall[0])
     total, ids, scores = _ref(msmarco, q)
     assert total >= 1
@@ -500,15 +566,35 @@ def test_the_same_query_under_both_row_buckets(msmarco):
 # the counters
 # ---------------------------------------------------------------------------
 
+def test_the_counters_read_nought_before_a_query():
+    """Every kind of the two families is on `/_tpu/stats` and the
+    exposition from the start, at 0 (a process of its own: this one has
+    served)."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json; from elasticsearch_tpu.search import tpu_service as t; "
+         "print(json.dumps([t.EXACT_PIN_COUNTS.counts(), "
+         "t.EXACT_RESULT_COUNTS.counts()]))"],
+        capture_output=True, text=True, timeout=120, check=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert json.loads(out.stdout.splitlines()[-1]) == [
+        {"rows": 0, "rows_under": 0, "trains": 0, "launches": 0},
+        {"queries": 0, "empty": 0}]
+
+
 def test_stats_and_prometheus_report_the_two_families(msmarco):
     stats = msmarco["http"].stats()
-    assert set(stats["exact_pin"]) == {"rows", "rows_under"}
+    assert set(stats["exact_pin"]) == {"rows", "rows_under", "trains",
+                                       "launches"}
+    assert 0 < stats["exact_pin"]["trains"] < stats["exact_pin"]["launches"]
     assert set(stats["exact_results"]) == {"queries", "empty"}
     assert stats["exact_pin"]["rows"] == stats["exact_results"]["queries"] > 0
     assert 0 < stats["exact_pin"]["rows_under"] < stats["exact_pin"]["rows"]
     assert 0 < stats["exact_results"]["empty"] < stats["exact_results"]["queries"]
     prom = msmarco["node"].metrics.prometheus_text()
     for family, kind in (("exact_pin", "rows"), ("exact_pin", "rows_under"),
+                         ("exact_pin", "trains"), ("exact_pin", "launches"),
                          ("exact_results", "queries"), ("exact_results", "empty")):
         assert f'es_tpu_kernel_{family}_total{{kind="{kind}"}}' in prom
 

@@ -323,6 +323,36 @@ def test_route_entries_and_launch_labels_count_what_was_sent(quora):
     assert 'es_tpu_kernel_launches_total{path="exact_ref_b8_s32_w16"}' in prom
 
 
+@pytest.mark.parametrize("n", [5, 48, 128])
+def test_a_train_of_long_questions_is_one_launch(quora, n):
+    """ISSUE 37's split by slot pin leaves this deployment alone: every
+    exact question has more than 8 terms, so its own pin is the 32-slot
+    floor, which is the launch's: one group, one launch at the train's
+    row bucket, nothing under its pin."""
+    resident, mesh = quora["resident"], quora["mesh"]
+    quora["node"].tpu_search.set_kernel_packed_sort(False)
+    long_ = [q for q in quora["queries"]
+             if len(q) > tpu_service.PRUNE_MAX_TERMS][:n]
+    flats = _flats(long_)
+    shard_rows = max(1, resident.pack.num_shards
+                     // mesh.shape[tpu_service.SHARD_AXIS])
+    assert tpu_service._split_exact_train(
+        resident, flats, range(n), shard_rows) == [list(range(n))]
+    before = quora["http"].stats()
+    results = tpu_service.execute_flat_batch(resident, flats, SIZE, mesh)
+    after = quora["http"].stats()
+    rows = tpu_service._serving_bucket(n)
+    def rise(block):
+        return {key: after[block][key] - before[block].get(key, 0)
+                for key in after[block]}
+
+    assert {p: n_ for p, n_ in rise("launches").items() if n_} \
+        == {f"exact_ref_b{rows}_s32_w16": 1}
+    assert rise("exact_pin") == {
+        "rows": n, "rows_under": 0, "trains": 1, "launches": 1}
+    assert [r.total_hits > 0 for r in results] == [True] * n
+
+
 def test_an_exact_launchs_states_carry_its_path_and_its_train(quora):
     http_, node = quora["http"], quora["node"]
     node.tpu_search.set_kernel_packed_sort(False)
