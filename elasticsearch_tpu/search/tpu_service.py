@@ -124,6 +124,27 @@ class StageTimes:
             tracing.record_stage("tpu." + stage, dt, n=n,
                                  **(attributes or {}))
 
+    def add_many(self, samples: Sequence[Tuple[str, float]]) -> None:
+        """`add` of one occurrence of each (stage, dt), under one taking
+        of the lock: what a request records at once (`batch_wait` and
+        its split) costs one acquisition, not one a stage."""
+        rings = []
+        with self._lock:
+            for stage, dt in samples:
+                self.seconds[stage] = self.seconds.get(stage, 0.0) + dt
+                self.counts[stage] = self.counts.get(stage, 0) + 1
+                ring = self._rings.get(stage)
+                if ring is None:
+                    ring = self._rings[stage] = self._ring_cls(
+                        self.RING_SIZE)
+                rings.append(ring)
+        span = tracing.current_span()
+        exemplar = span.trace_id if span is not None else None
+        for ring, (stage, dt) in zip(rings, samples):
+            ring.add(dt, exemplar=exemplar)
+            if span is not None:
+                tracing.record_stage("tpu." + stage, dt)
+
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
             stages = sorted(self.seconds)
@@ -1216,14 +1237,19 @@ class _Pending:
     # parent their launch/device spans under the FIRST traced query of
     # the train so a trace shows which batch served it
     trace_span: Any = None
-    # batch_wait decomposition marks (perf_counter). Workers stamp
-    # cycle/take/launched; the REQUEST thread reads them back after
-    # `future.result()` so the four sub-stages sum exactly to the
-    # legacy `batch_wait` measured on the same thread.
+    # batch_wait decomposition marks (perf_counter). The launch thread
+    # stamps cycle/take/launched, the completer ready (its launch's
+    # `device_wait` ended), decoded (the train's `decode` ended) and set
+    # (just before its `set_result`); the REQUEST thread reads them back
+    # after `future.result()` so the sub-stages sum exactly to
+    # `batch_wait` measured on the same thread.
     t_submit: float = 0.0
     t_cycle: float = 0.0
     t_take: float = 0.0
     t_launched: float = 0.0
+    t_ready: float = 0.0
+    t_decoded: float = 0.0
+    t_set: float = 0.0
     # the queue's sequence number of the train that took this query: the
     # launch thread's and the completer's stages and annotations of that
     # train, and a traced request's batch_launch/batch_finish spans,
@@ -1234,6 +1260,12 @@ class _Pending:
     # burst can't monopolize batch slots ahead of tenants already
     # waiting — the starved lane's cost would show up as batch_wait.queue
     tenant: str = tenancy.DEFAULT_TENANT
+
+
+#: a request's `batch_wait` split → its stage names
+_BATCH_WAIT_PARTS = {name: f"batch_wait.{name}" for name in (
+    "queue", "window", "dispatch", "completion",
+    "device", "decode", "deliver", "wake")}
 
 
 def _batch_bucket(n: int, cap: int) -> int:
@@ -1540,6 +1572,10 @@ class _PackQueue:
                                             queries=len(taken),
                                             train=train):
                         results = finish_flat_batch(st)
+                    # each query's launch's `device_wait` end (a stubbed
+                    # launch state has none: no marks, no split)
+                    ready = ((isinstance(st, dict) and st.get("t_ready"))
+                             or [0.0] * len(taken))
                 finally:
                     if wd is not None:
                         wd.end(token)
@@ -1557,14 +1593,16 @@ class _PackQueue:
             # the train's device results have been copied: let them go
             # before the worker is told that it may launch the next
             item = st = None
-            states.switch("deliver", queries=len(taken))
+            t_decoded = states.switch("deliver", queries=len(taken))
             with batcher._lock:
                 batcher.batches_executed += 1
                 batcher.queries_executed += len(taken)
-            for p, res in zip(taken, results):
+            for p, res, t_ready in zip(taken, results, ready):
                 # the watchdog may have failed this future already (an
                 # overdue launch that eventually returned)
                 if not p.future.done():
+                    p.t_ready, p.t_decoded = t_ready, t_decoded
+                    p.t_set = time.perf_counter()
                     p.future.set_result(res)
             with self.cv:  # batch finished — the worker may launch now
                 self.n_inflight -= 1
@@ -2337,28 +2375,37 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     # work — so a "wedged" launch holds nothing the watchdog needs
     _dispatch_fault_point(mesh)
     can_prune = resident.imp_device_arrays is not None
-    pruned_idx: List[int] = []
-    exact_idx: List[int] = []
-    for i, f in enumerate(flats):
-        reason = _exact_reason(f, k, can_prune)
-        if reason is None:
-            pruned_idx.append(i)
-        else:
-            exact_idx.append(i)
-            ROUTE_COUNTS.inc(reason)
-    # route each query to the smallest exact-sort width that holds its
-    # FULL postings; overflow goes to the prefix+rescore path
-    full_groups: Dict[int, List[int]] = {b: [] for b in FULL_SLOT_BUCKETS}
-    hot_idx: List[int] = []
-    for i in pruned_idx:
-        b = _full_bucket(_slots_needed(resident, flats[i]))
-        if b is None:
-            hot_idx.append(i)
-        else:
-            full_groups[b].append(i)
-    shard_rows = max(1, resident.pack.num_shards // mesh.shape[SHARD_AXIS])
+    # the routing, on the launch thread's clock (`prep.route`, inside
+    # `batcher.prep`): a path for each query, then each path's launches
+    with tracing.stage(stages, "prep.route"):
+        pruned_idx: List[int] = []
+        exact_idx: List[int] = []
+        for i, f in enumerate(flats):
+            reason = _exact_reason(f, k, can_prune)
+            if reason is None:
+                pruned_idx.append(i)
+            else:
+                exact_idx.append(i)
+                ROUTE_COUNTS.inc(reason)
+        # route each query to the smallest exact-sort width that holds
+        # its FULL postings; overflow goes to the prefix+rescore path
+        full_groups: Dict[int, List[int]] = {b: []
+                                             for b in FULL_SLOT_BUCKETS}
+        hot_idx: List[int] = []
+        for i in pruned_idx:
+            b = _full_bucket(_slots_needed(resident, flats[i]))
+            if b is None:
+                hot_idx.append(i)
+            else:
+                full_groups[b].append(i)
+        shard_rows = max(1,
+                         resident.pack.num_shards // mesh.shape[SHARD_AXIS])
+        full_split = _split_full_train(full_groups, shard_rows)
+        exact_split = (_split_exact_train(resident, flats, exact_idx,
+                                          shard_rows, max_batch)
+                       if exact_idx else [])
     full_launches = []
-    for b, idxs in _split_full_train(full_groups, shard_rows):
+    for b, idxs in full_split:
         ROUTE_COUNTS.inc(f"pruned_full_s{b}", n=len(idxs))
         full_launches.append((idxs, _launch_pruned(
             resident, [flats[i] for i in idxs], k, mesh,
@@ -2376,8 +2423,7 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
         st["exact_launches"] = [
             (idxs, _launch_exact(resident, [flats[i] for i in idxs], k, mesh,
                                  stages=stages))
-            for idxs in _split_exact_train(resident, flats, exact_idx,
-                                           shard_rows, max_batch)]
+            for idxs in exact_split]
         EXACT_PIN_COUNTS.inc("trains")
         EXACT_PIN_COUNTS.inc("launches", n=len(st["exact_launches"]))
     return st
@@ -2395,12 +2441,18 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
     resident, flats, k, mesh, stages = (st["resident"], st["flats"],
                                         st["k"], st["mesh"], st["stages"])
     out: List[Optional[FlatQueryResult]] = [None] * len(flats)
+    # each query's launch's `device_wait` end (an escalated query keeps
+    # its first launch's): the completer stamps it on the query's
+    # `_Pending` as `t_ready`
+    ready = st["t_ready"] = [0.0] * len(flats)
     tier3_idx: List[int] = []
     escalate: List[int] = []
     for idxs, launch in st["full_launches"]:
         results, invalid = _finish_pruned(launch, stages=stages)
+        t_ready = launch["t_ready"]
         for j, i in enumerate(idxs):
             out[i] = results[j]
+            ready[i] = t_ready
         # full-postings runs are exact ⇒ beta 0 ⇒ no invalids; if the
         # invariant ever breaks, escalate rather than crash serving
         escalate.extend(idxs[j] for j in invalid)
@@ -2408,8 +2460,10 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
         hot_idx = st["hot_idx"]
         results, invalid = _finish_pruned(st["hot_launch"],
                                           stages=stages)
+        t_ready = st["hot_launch"]["t_ready"]
         for j, i in enumerate(hot_idx):
             out[i] = results[j]
+            ready[i] = t_ready
         escalate.extend(hot_idx[j] for j in invalid)
     if escalate:
         retry_idx = escalate
@@ -2420,22 +2474,18 @@ def finish_flat_batch(st: Dict[str, Any]) -> List[FlatQueryResult]:
             stages=stages, prefix_cap=PREFIX_CAP3)
         for j, i in enumerate(retry_idx):
             out[i] = results2[j]
-        if invalid2 and stages is not None:
-            stages.add("pruned_invalid_t3", 0.0, n=len(invalid2))
         tier3_idx = [retry_idx[j] for j in invalid2]
     for idxs, launch in st["exact_launches"]:
         results = _finish_exact(launch, stages=stages)
+        t_ready = launch["t_ready"]
         for j, i in enumerate(idxs):
             out[i] = results[j]
+            ready[i] = t_ready
     if tier3_idx:
         ROUTE_COUNTS.inc("exact_escalated", n=len(tier3_idx))
-        t0 = time.perf_counter()
         results = _execute_exact(resident,
                                  [flats[i] for i in tier3_idx], k, mesh,
                                  stages=stages)
-        if stages is not None:
-            stages.add("exact_batch", time.perf_counter() - t0,
-                       n=len(tier3_idx))
         for j, i in enumerate(tier3_idx):
             out[i] = results[j]
     return out  # type: ignore[return-value]
@@ -2514,31 +2564,33 @@ def _launch_exact(resident: ResidentPack, flats: Sequence[FlatQuery],
     if program is not None:
         rows, variant = max(rows, program.rows), program.variant
     terms = [f.terms for f in flats]
-    batch = dist.prepare_query_batch(
-        pack, terms,
-        boosts=[f.boost for f in flats],
-        min_counts=[f.min_count for f in flats],
-        pad_batch_to=rows,
-        pad_max_len=dist.CHUNK_CAP,
-        compressed=resident.comp_streams)
-    t_window = _exact_window(batch.window)
-    t_pin = _exact_slot_pin(batch.t_slots, t_window)
-    if program is not None:
-        t_window = max(t_window, program.t_window)
-        t_pin = max(t_pin, program.slots)
-    if t_pin > batch.t_slots:
-        s, b, t = batch.starts.shape
-        pad = ((0, 0), (0, 0), (0, t_pin - t))
-        extra = {}
-        if batch.res_starts is not None:
-            # zero-padded slots: length 0 ⇒ inert in grouping/rescore
-            extra = dict(res_starts=np.pad(batch.res_starts, pad),
-                         res_lens=np.pad(batch.res_lens, pad),
-                         slot_terms=np.pad(batch.slot_terms, pad))
-        batch = dataclasses.replace(
-            batch, starts=np.pad(batch.starts, pad),
-            lengths=np.pad(batch.lengths, pad),
-            weights=np.pad(batch.weights, pad), t_slots=t_pin, **extra)
+    # the query operands, pinned (`prep.query_batch`, inside `batcher.prep`)
+    with tracing.stage(stages, "prep.query_batch"):
+        batch = dist.prepare_query_batch(
+            pack, terms,
+            boosts=[f.boost for f in flats],
+            min_counts=[f.min_count for f in flats],
+            pad_batch_to=rows,
+            pad_max_len=dist.CHUNK_CAP,
+            compressed=resident.comp_streams)
+        t_window = _exact_window(batch.window)
+        t_pin = _exact_slot_pin(batch.t_slots, t_window)
+        if program is not None:
+            t_window = max(t_window, program.t_window)
+            t_pin = max(t_pin, program.slots)
+        if t_pin > batch.t_slots:
+            s, b, t = batch.starts.shape
+            pad = ((0, 0), (0, 0), (0, t_pin - t))
+            extra = {}
+            if batch.res_starts is not None:
+                # zero-padded slots: length 0 ⇒ inert in grouping/rescore
+                extra = dict(res_starts=np.pad(batch.res_starts, pad),
+                             res_lens=np.pad(batch.res_lens, pad),
+                             slot_terms=np.pad(batch.slot_terms, pad))
+            batch = dataclasses.replace(
+                batch, starts=np.pad(batch.starts, pad),
+                lengths=np.pad(batch.lengths, pad),
+                weights=np.pad(batch.weights, pad), t_slots=t_pin, **extra)
     if variant is None:
         variant = _choose_exact_variant(resident, batch)
     # the launch's static shape: the device program's name (`jit_<label>`
@@ -2578,7 +2630,7 @@ def _finish_exact(launch: Dict[str, Any],
     vals = np.asarray(launch["vals"])
     gids = np.asarray(launch["gids"])
     totals = np.asarray(launch["totals"])
-    states.switch("decode")
+    launch["t_ready"] = states.switch("decode")
     if stages is not None:
         # variant-tagged, like `exact_dispatch.<variant>`
         stages.add(f"exact_device_wait.{launch['variant']}",
@@ -2652,25 +2704,33 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     if full_slots is not None:
         with_rescore = False
         k_cand = k_out  # exact totals: the candidate pool IS the result
-        batch = dist.prepare_query_batch(
+    # the query operands (`prep.query_batch`), then the rescore terms'
+    # ranges and the one array that carries them all (`prep.term_ranges`),
+    # each inside `batcher.prep`
+    with tracing.stage(stages, "prep.query_batch"):
+        if full_slots is not None:
+            batch = dist.prepare_query_batch(
+                pack, [f.terms for f in flats],
+                boosts=[f.boost for f in flats],
+                min_counts=[1] * len(flats),
+                pad_batch_to=b_bucket,
+                pad_t_slots=full_slots, pad_max_len=dist.CHUNK_CAP)
+        else:
+            batch = dist.prepare_query_batch(
+                pack, [f.terms for f in flats],
+                boosts=[f.boost for f in flats],
+                min_counts=[1] * len(flats),
+                pad_batch_to=b_bucket,
+                prefix_cap=prefix_cap, imp_impacts=imp_impacts,
+                pad_t_slots=_prune_t_slots(prefix_cap),
+                pad_max_len=dist.CHUNK_CAP)
+    with tracing.stage(stages, "prep.term_ranges"):
+        t_starts, t_lengths, t_weights = dist.prepare_term_ranges(
             pack, [f.terms for f in flats],
             boosts=[f.boost for f in flats],
-            min_counts=[1] * len(flats),
-            pad_batch_to=b_bucket,
-            pad_t_slots=full_slots, pad_max_len=dist.CHUNK_CAP)
-    else:
-        batch = dist.prepare_query_batch(
-            pack, [f.terms for f in flats],
-            boosts=[f.boost for f in flats],
-            min_counts=[1] * len(flats),
-            pad_batch_to=b_bucket,
-            prefix_cap=prefix_cap, imp_impacts=imp_impacts,
-            pad_t_slots=_prune_t_slots(prefix_cap),
-            pad_max_len=dist.CHUNK_CAP)
-    t_starts, t_lengths, t_weights = dist.prepare_term_ranges(
-        pack, [f.terms for f in flats],
-        boosts=[f.boost for f in flats],
-        pad_batch_to=b_bucket, pad_terms=PRUNE_MAX_TERMS)
+            pad_batch_to=b_bucket, pad_terms=PRUNE_MAX_TERMS)
+        ops = dist.pack_pruned_operands(batch, t_starts, t_lengths,
+                                        t_weights)
     if variant is None:
         variant = _pruned_variant()
     KERNEL_VARIANT_COUNTS.inc("full" if full_slots is not None
@@ -2703,7 +2763,6 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     from jax.sharding import NamedSharding, PartitionSpec as P
     from elasticsearch_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
     sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
-    ops = dist.pack_pruned_operands(batch, t_starts, t_lengths, t_weights)
     # dispatch, three ways: the wait for the process-wide dispatch lock,
     # the host→device copy of the operands, and the jitted call (host
     # dispatch plus whatever the runtime blocks on: donation holds, a
@@ -2743,7 +2802,7 @@ def _finish_pruned(launch: Dict[str, Any],
     states.switch("device_wait")
     t_dev = time.perf_counter()
     packed = np.asarray(launch["packed"])
-    t_decode = time.perf_counter()
+    t_decode = launch["t_ready"] = time.perf_counter()
     states.switch("decode")
     vals, gids, totals, cutoff, beta = dist.unpack_pruned(packed)
     if stages is not None:
@@ -4122,7 +4181,6 @@ class TpuSearchService:
         self._tripped = False  # a completed batch proves the path is live
         self.served += 1
         t_done = time.perf_counter()
-        self.stages.add("batch_wait", t_done - t_sub)
         split = self._record_batch_wait_split(pending, t_sub, t_done)
         if profile_sink is not None:
             profile_sink.update({
@@ -4141,27 +4199,42 @@ class TpuSearchService:
         return result
 
     def _record_batch_wait_split(self, pending, t_sub: float,
-                                 t_done: float) -> Optional[Dict[str, float]]:
-        """Decompose one query's batch_wait into queue (submit → the
-        worker's train cycle), window (batching window), dispatch
-        (host-side staging inside launch), and completion (device→host
-        + decode + callback). All four are measured from marks the
-        workers stamped on the `_Pending`, anchored to the same
-        request-thread clock as `batch_wait` — so the parts sum to the
-        aggregate exactly, by construction."""
-        if pending is None:
-            return None  # a mocked/foreign future carries no marks
-        t_c, t_t, t_l = pending.t_cycle, pending.t_take, pending.t_launched
-        if not t_t or not t_l:
-            return None  # launch path didn't stamp (shouldn't happen)
-        split = {
-            "queue": max(0.0, t_c - t_sub),
-            "window": max(0.0, t_t - max(t_sub, t_c)),
-            "dispatch": max(0.0, t_l - t_t),
-            "completion": max(0.0, t_done - t_l),
-        }
-        for name, dt in split.items():
-            self.stages.add(f"batch_wait.{name}", dt)
+                                 t_done: float
+                                 ) -> Optional[Dict[str, float]]:
+        """Record one query's `batch_wait` and its split, in one
+        `add_many`, → the split. `queue` (submit → the worker's train
+        cycle), `window` (batching window), `dispatch` (host-side
+        staging inside launch) and `completion` (launched → the request
+        thread running again); `completion` again as `device` (→ its
+        launch's results ready), `decode` (→ the train decoded),
+        `deliver` (→ its future set, behind the train's other
+        deliveries) and `wake` (→ this thread running after
+        `fut.result()`). Each is measured from marks the workers stamped
+        on the `_Pending`, anchored to the same request-thread clock as
+        `batch_wait`, so the first four parts sum to it exactly, by
+        construction, and so do the first three with the last four."""
+        samples = [("batch_wait", t_done - t_sub)]
+        split = None
+        if pending is not None:  # a mocked/foreign future carries no marks
+            t_c, t_t, t_l = (pending.t_cycle, pending.t_take,
+                             pending.t_launched)
+            if t_t and t_l:
+                split = {
+                    "queue": max(0.0, t_c - t_sub),
+                    "window": max(0.0, t_t - max(t_sub, t_c)),
+                    "dispatch": max(0.0, t_l - t_t),
+                    "completion": max(0.0, t_done - t_l),
+                }
+                t_r, t_d, t_s = (pending.t_ready, pending.t_decoded,
+                                 pending.t_set)
+                if t_r and t_d and t_s:
+                    split["device"] = max(0.0, t_r - t_l)
+                    split["decode"] = max(0.0, t_d - t_r)
+                    split["deliver"] = max(0.0, t_s - t_d)
+                    split["wake"] = max(0.0, t_done - t_s)
+                samples += [(_BATCH_WAIT_PARTS[name], dt)
+                            for name, dt in split.items()]
+        self.stages.add_many(samples)
         return split
 
     @staticmethod

@@ -86,10 +86,15 @@ class TestProfile:
             assert tpu["plan_cache"] in (
                 "hit", "miss", "revalidated", "uncacheable")
             split = tpu["stages_ms"]["batch_wait_split"]
+            # four parts, and `completion` again in four (ISSUE 38)
             assert set(split) == {
-                "queue", "window", "dispatch", "completion"}
-            assert sum(split.values()) == pytest.approx(
-                tpu["stages_ms"]["batch_wait"], rel=0.05, abs=0.05)
+                "queue", "window", "dispatch", "completion",
+                "device", "decode", "deliver", "wake"}
+            for parts in (("queue", "window", "dispatch", "completion"),
+                          ("queue", "window", "dispatch", "device",
+                           "decode", "deliver", "wake")):
+                assert sum(split[p] for p in parts) == pytest.approx(
+                    tpu["stages_ms"]["batch_wait"], rel=0.05, abs=0.05)
             assert res["profile"]["tpu"] == [tpu]
             # taking the path counts against the served variant (keys
             # are "kernel,variant" pairs)

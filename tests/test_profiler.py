@@ -201,16 +201,30 @@ class TestProfiledNodeSmoke:
         stages = profiled_node.tpu_search.stages.snapshot()
         total = stages["batch_wait"]["seconds"]
         assert total > 0
-        parts = sum(stages[f"batch_wait.{p}"]["seconds"]
-                    for p in ("queue", "window", "dispatch", "completion"))
-        # same-thread clock anchors: parts sum to the aggregate (5% is
-        # the acceptance bar; the construction makes it ~exact)
-        assert parts == pytest.approx(total, rel=0.05)
-        # the four parts, and no per-variant sibling of any of them
+        four = ("queue", "window", "dispatch", "completion")
+        seven = ("queue", "window", "dispatch",
+                 "device", "decode", "deliver", "wake")
+        # same-thread clock anchors: the four parts, and the seven that
+        # split `completion` in four, sum to the aggregate (5% is the
+        # acceptance bar; the construction makes it ~exact)
+        for parts in (four, seven):
+            assert sum(stages[f"batch_wait.{p}"]["seconds"]
+                       for p in parts) == pytest.approx(total, rel=0.05)
+            assert all(stages[f"batch_wait.{p}"]["count"]
+                       == stages["batch_wait"]["count"] for p in parts)
+        # the eight parts, and no per-variant sibling of any of them
         assert {k for k in stages if k.startswith("batch_wait.")} == {
-            f"batch_wait.{p}"
-            for p in ("queue", "window", "dispatch", "completion")
-        }, sorted(stages)
+            f"batch_wait.{p}" for p in four + seven}, sorted(stages)
+        # a profiled search's own split carries the new parts, and sums
+        status, res = _handle(profiled_node, "POST", "/prof/_search", body={
+            "query": {"match": {"title": "sampled"}}, "profile": True})
+        assert status == 200, res
+        tpu = res["profile"]["shards"][0]["tpu"]["stages_ms"]
+        split = tpu["batch_wait_split"]
+        assert set(split) == set(four + seven)
+        for parts in (four, seven):
+            assert sum(split[p] for p in parts) == pytest.approx(
+                tpu["batch_wait"], abs=1e-3)
 
     def test_stats_and_prometheus_scrape(self, profiled_node):
         status, stats = _handle(profiled_node, "GET", "/_tpu/stats")
