@@ -628,6 +628,8 @@ class Node:
                 EXACT_RESULT_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
                 KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
                 ROUTE_COUNTS)
+            from elasticsearch_tpu.parallel.distributed import (
+                TERM_TABLE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
                    1 if KERNEL_CONFIG["packed_sort"] else 0, "gauge")
             yield ("search.tpu.kernel_compressed_pack", nl,
@@ -660,6 +662,11 @@ class Node:
                 yield ("kernel.exact_pin", labels, counter)
             for labels, counter in EXACT_RESULT_COUNTS.items():
                 yield ("kernel.exact_results", labels, counter)
+            # query terms resolved for launches' operands, and the columns
+            # the packs' term tables built:
+            # es_tpu_kernel_term_table_total{kind=...}
+            for labels, counter in TERM_TABLE_COUNTS.items():
+                yield ("kernel.term_table", labels, counter)
             # launches on a mesh of several devices, their rows and
             # devices: es_tpu_kernel_cross_chip_total{kind=...}
             for labels, counter in CROSS_CHIP_COUNTS.items():

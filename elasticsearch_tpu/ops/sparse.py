@@ -84,6 +84,8 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from itertools import chain, islice
+from operator import itemgetter
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -828,53 +830,60 @@ def _cap_bucket(cap: int, lane: int) -> int:
     return b
 
 
-def plan_slots(rows: Sequence[Sequence[Tuple[int, int, float, int]]],
+def _split_row(row: Sequence[Tuple], max_len: int) -> List[Tuple]:
+    out = []
+    for e in row:
+        if e[1] <= max_len:
+            out.append(e)
+        else:
+            s, ln, w = e[0], e[1], e[2]
+            out.extend((s + off, min(max_len, ln - off), w)
+                       for off in range(0, ln, max_len))
+    return out
+
+
+def plan_slots(rows: Sequence[Sequence[Tuple]],
                min_counts: Sequence[int],
                chunk_cap: int = 4096,
-               lane: int = 128) -> SlotPlan:
-    """rows[r] = [(start, length, weight, term_id), ...] — one entry per
-    query term with its postings-row extent in the flat arrays. Long rows
-    split into chunks of ≤ L_c where L_c = min(bucket(max row length),
-    largest bucket ≤ chunk_cap). Returns padded static-shape slot tensors."""
-    longest = 1
-    window = 1
-    for row in rows:
-        window = max(window, len(row))
-        for (_, ln, _, _) in row:
-            longest = max(longest, ln)
+               lane: int = 128,
+               min_slots: int = 1) -> SlotPlan:
+    """rows[r] = [(start, length, weight, ...), ...] — one entry per
+    query term with its postings-row extent in the flat arrays (fields
+    past the third are ignored). Long rows split into chunks of ≤ L_c
+    where L_c = min(bucket(max row length), largest bucket ≤ chunk_cap);
+    an empty extent keeps one zero-length slot, so min_count sees the
+    term as present but unmatched. Returns padded static-shape slot
+    tensors, T the next power of two over the widest row or `min_slots`
+    if that is more. Plain Python over the entries, each tensor made once
+    from a list (`dist.TermTable` says why)."""
+    window = max(1, max(map(len, rows), default=0))
+    longest = max(1, max(map(itemgetter(1), chain.from_iterable(rows)),
+                         default=0))
     max_len = min(_len_bucket(longest, lane), _cap_bucket(chunk_cap, lane))
-
-    chunked: List[List[Tuple[int, int, float, int]]] = []
-    t_needed = 1
-    for row in rows:
-        out = []
-        for (s, ln, w, tid) in row:
-            off = 0
-            while off < ln:
-                take = min(max_len, ln - off)
-                out.append((s + off, take, w, tid))
-                off += take
-            if ln == 0:
-                # keep empty terms as zero-length slots so min_count
-                # semantics see the term as present-but-unmatched
-                out.append((s, 0, w, tid))
-        chunked.append(out)
-        t_needed = max(t_needed, len(out))
+    if longest > max_len:
+        rows = [_split_row(row, max_len)
+                if any(e[1] > max_len for e in row) else row
+                for row in rows]
+    t_needed = max(1, max(map(len, rows), default=0))
     t_slots = 1
     while t_slots < t_needed:
         t_slots *= 2
+    t_slots = max(t_slots, min_slots)
 
-    r = len(rows)
-    starts = np.zeros((r, t_slots), dtype=np.int32)
-    lengths = np.zeros((r, t_slots), dtype=np.int32)
-    weights = np.zeros((r, t_slots), dtype=np.float32)
-    for ri, out in enumerate(chunked):
-        for ti, (s, ln, w, _tid) in enumerate(out[:t_slots]):
-            starts[ri, ti] = s
-            lengths[ri, ti] = ln
-            weights[ri, ti] = w
-    return SlotPlan(starts, lengths, weights,
-                    np.asarray(min_counts, dtype=np.int32), max_len, t_slots,
+    n = len(rows) * t_slots
+    starts, lengths, weights = [0] * n, [0] * n, [0.0] * n
+    at = 0
+    for row in rows:
+        if row:
+            end = at + len(row)
+            starts[at:end], lengths[at:end], weights[at:end] = islice(
+                zip(*row), 3)
+        at += t_slots
+    shape = (len(rows), t_slots)
+    return SlotPlan(np.array(starts, dtype=np.int32).reshape(shape),
+                    np.array(lengths, dtype=np.int32).reshape(shape),
+                    np.array(weights, dtype=np.float32).reshape(shape),
+                    np.array(min_counts, dtype=np.int32), max_len, t_slots,
                     window)
 
 

@@ -2726,9 +2726,8 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
                 pad_max_len=dist.CHUNK_CAP)
     with tracing.stage(stages, "prep.term_ranges"):
         t_starts, t_lengths, t_weights = dist.prepare_term_ranges(
-            pack, [f.terms for f in flats],
-            boosts=[f.boost for f in flats],
-            pad_batch_to=b_bucket, pad_terms=PRUNE_MAX_TERMS)
+            pack, batch, boosts=[f.boost for f in flats],
+            pad_terms=PRUNE_MAX_TERMS)
         ops = dist.pack_pruned_operands(batch, t_starts, t_lengths,
                                         t_weights)
     if variant is None:
@@ -4502,6 +4501,7 @@ class TpuSearchService:
                 "full_entries": FULL_ENTRY_COUNTS.counts(),
                 "cross_chip": CROSS_CHIP_COUNTS.counts(),
                 "exact_pin": EXACT_PIN_COUNTS.counts(),
+                "term_table": dist.TERM_TABLE_COUNTS.counts(),
                 "exact_results": EXACT_RESULT_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),

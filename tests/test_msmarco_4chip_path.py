@@ -247,6 +247,28 @@ def test_cross_chip_counts_the_mesh_and_nothing_on_one_device(hosts, n_devices):
     assert 'es_tpu_kernel_cross_chip_total{kind="rows"}' in prom
 
 
+@pytest.mark.parametrize("n_devices", [1, 4], ids=["one_device", "mesh_1x4"])
+def test_term_table_counts_a_query_term_once_a_launch(hosts, n_devices):
+    """`term_table.lookups` rises by the terms of the queries launched
+    (one client: a launch a query), though the pack has eight shard rows
+    on either host: a launch's operands resolve a term once a launch,
+    not once a row. `/_tpu/stats` and the exposition carry the family."""
+    host = hosts["served"][n_devices]
+    mine = _of_terms(hosts["queries"], 4, n=2)
+    before = host["http"].stats()
+    for q in mine:
+        host["http"].search(q)
+    after = host["http"].stats()
+    assert host["resident"].pack.num_shards == SHARDS
+    assert sum(_rise(after, before, "launches").values()) == len(mine)
+    rise = _rise(after, before, "term_table")
+    assert rise["lookups"] == sum(len(q) for q in mine)
+    assert 0 <= rise["columns"] <= rise["lookups"]
+    prom = host["node"].metrics.prometheus_text()
+    for kind in ("lookups", "columns"):
+        assert f'es_tpu_kernel_term_table_total{{kind="{kind}"}}' in prom
+
+
 @pytest.mark.parametrize("make", ["pruned", "exact"])
 def test_the_merge_carries_its_scope(hosts, make):
     """`cross_chip_merge` names the collectives and the merge of the
@@ -259,8 +281,7 @@ def test_the_merge_carries_its_scope(hosts, make):
     if make == "pruned":
         fn = tpu_service._make_full_search(host["resident"], mesh, 16, 1024,
                                            "ref")
-        t = dist.prepare_term_ranges(pack, [[corpus.word(25), corpus.word(400)]],
-                                     pad_batch_to=8,
+        t = dist.prepare_term_ranges(pack, batch,
                                      pad_terms=tpu_service.PRUNE_MAX_TERMS)
         ops = dist.pack_pruned_operands(batch, *t)
         text = fn.lower(*host["resident"].imp_device_arrays[:2],
