@@ -18,9 +18,10 @@
  * an encoded JSON string a quote can only appear escaped, and commas
  * only separate top-level elements at depth 0 outside strings.
  *
- * es_render_hits, further down, writes the metadata-only block with no
- * encoded column at all: ids from a table the pack encoded once, scores
- * formatted here to the bytes json.dumps gives.
+ * es_render_hits, further down, writes a hits block with no encoded
+ * column at all: ids, and each hit's whole _source where the block
+ * returns it, from tables the pack encoded once, scores formatted here to
+ * the bytes json.dumps gives.
  */
 
 #include <math.h>
@@ -168,10 +169,10 @@ done:
 }
 
 /* ------------------------------------------------------------------------
- * es_render_hits — the metadata-only hits block written straight from the
- * kernel's result columns and the pack's pre-encoded id table.  No Python
- * object is touched: the caller passes raw pointers and ctypes drops the
- * GIL for the whole call.
+ * es_render_hits — a hits block written straight from the kernel's result
+ * columns and the pack's pre-encoded id table (and source table, for a
+ * block that returns _source).  No Python object is touched: the caller
+ * passes raw pointers and ctypes drops the GIL for the whole call.
  * ---------------------------------------------------------------------- */
 
 /* One probe of the digit search: x printed with p significant digits
@@ -398,9 +399,12 @@ static int format_score(float f, char *out)
     return w;
 }
 
-/* Write [{"_index":<name>,"_id":<id>,"_score":<score>},...] for n hits.
+/* Write [{"_index":<name>,"_id":<id>,"_score":<score>},...] for n hits,
+ * each with ,"_source":<source> after its score where src_blob is given.
  *   id_blob, id_off   the pack's ids as json.dumps literals, back to back;
  *                     id i is id_blob[id_off[i] : id_off[i + 1]], n_ids ids
+ *   src_blob, src_off NULL, or the docs' stored sources as literals, laid
+ *                     out and indexed as the ids are (n_ids of them)
  *   row_offset        n_rows offsets of each pack row's first id
  *   rows, ords        n (pack row, local ordinal) pairs
  *   scores            n float32 scores
@@ -409,6 +413,7 @@ static int format_score(float f, char *out)
  * outside the tables, -3 for a score format_score refuses: the caller
  * renders the block in Python for any negative return. */
 long es_render_hits(const char *id_blob, const int64_t *id_off, int64_t n_ids,
+                    const char *src_blob, const int64_t *src_off,
                     const int64_t *row_offset, int64_t n_rows,
                     const int32_t *rows, const int32_t *ords,
                     const float *scores, int32_t n,
@@ -436,6 +441,10 @@ long es_render_hits(const char *id_blob, const int64_t *id_off, int64_t n_ids,
         PUT(id_blob + id_off[id], id_off[id + 1] - id_off[id]);
         PUT(",\"_score\":", 10);
         PUT(score, slen);
+        if (src_blob) {
+            PUT(",\"_source\":", 11);
+            PUT(src_blob + src_off[id], src_off[id + 1] - src_off[id]);
+        }
         PUT("}", 1);
     }
     PUT("]", 1);

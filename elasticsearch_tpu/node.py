@@ -675,6 +675,16 @@ class Node:
             # es_tpu_batcher_hold_exit_total{hold_exit=...}
             for labels, counter in HOLD_EXIT_COUNTS.items():
                 yield ("batcher.hold_exit", labels, counter)
+            # hits blocks by the path that rendered them, and the hits
+            # returned with a `_source` and its bytes:
+            # es_tpu_response_render_total{path=...},
+            # es_tpu_response_fetch_total{kind=...}
+            from elasticsearch_tpu.search.serializer import (
+                FETCH_COUNTS, RENDER_COUNTS)
+            for labels, counter in RENDER_COUNTS.items():
+                yield ("response.render", labels, counter)
+            for labels, counter in FETCH_COUNTS.items():
+                yield ("response.fetch", labels, counter)
             for stage, seconds, count, ring, cpu in \
                     svc.stages.metrics_view():
                 lb = {"stage": stage}
@@ -1219,14 +1229,15 @@ class _Handler(BaseHTTPRequestHandler):
             ctype = "text/plain; charset=UTF-8"
         else:
             # dumps_response_bytes renders embedded ColumnarHits blocks
-            # from their device-result columns (the metadata-only shape
-            # in one native call with the GIL released, no per-hit
-            # Python); plain payloads serialize as before
+            # from their device-result columns (the metadata-only shape,
+            # and that shape with each hit's whole `_source`, in one
+            # native call with the GIL released, no per-hit Python);
+            # plain payloads serialize as before
             from elasticsearch_tpu.search.serializer import \
                 dumps_response_bytes
             with tracing.stage(stages, "rest_render", annotate=False,
                                cpu=False):
-                data = dumps_response_bytes(payload)
+                data = dumps_response_bytes(payload, stages)
             ctype = "application/json; charset=UTF-8"
         self.send_response(status)
         self.send_header("Content-Type", ctype)

@@ -195,9 +195,8 @@ def with_alias_filters(query: dsl.QueryNode,
 
 def parse_search_body(body: Optional[Dict[str, Any]]):
     body = body or {}
-    # unimplemented keys get a 400, never silently ignored (VERDICT r1
-    # weak #1): a sorted/highlighted query must not return wrong results
-    # with a 200
+    # unimplemented keys get a 400, never silently ignored: a
+    # sorted/highlighted query must not return wrong results with a 200
     unsupported = set(body) & {"script_fields"}
     if unsupported:
         raise IllegalArgumentException(
@@ -392,9 +391,9 @@ def search(indices: IndicesService, index_expr: Optional[str],
                     (seg_map, spec.boost))
 
     # ---- TPU fast path: micro-batched kernel over resident packs ----
-    # (VERDICT r1 #1: the batched pipeline IS the serving path for the
-    # queries it can express; everything else falls through to the
-    # planner below, unchanged.)
+    # (the batched pipeline IS the serving path for the queries it can
+    # express; everything else falls through to the planner below,
+    # unchanged.)
     profile = bool(body.get("profile"))
     if (tpu_search is not None and aggs is None and pinned is None
             and knn_wrap is None  # knn runs the two-phase planner path
@@ -842,10 +841,11 @@ def _search_fast(indices: IndicesService, names: List[str],
     when any target index's query can't lower (the whole request then
     runs on the planner so merge semantics stay uniform).
 
-    Hit assembly is vectorized (VERDICT r3 #1b): external ids resolve via
-    one fancy-index over the pack's id table, stored fields read straight
-    off the pinned segments — no per-hit ShardHit/fetch-phase objects on
-    the hot path."""
+    Hit assembly is vectorized, because a per-hit object costs the
+    request thread Python under the interpreter lock at every hit of a
+    1000-hit window: external ids resolve via one fancy-index over the
+    pack's id table, stored fields read straight off the pinned segments
+    — no per-hit ShardHit/fetch-phase objects on the hot path."""
     import numpy as np
 
     k = from_ + size

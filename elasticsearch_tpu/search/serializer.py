@@ -2,8 +2,8 @@
 
 The reference builds a SearchHit object per hit and serializes it
 field-by-field; at k=1000 that is ~1000 dict constructions + ~1000
-per-hit dumps per response and it shows up as the `assemble` stage in
-PERF.md (12.8 s over one bench run). Here the hot response shape —
+per-hit dumps per response, all of it Python under the interpreter lock
+on the request thread. Here the hot response shape —
 metadata-only hits (`"_source": false`), the shape high-QPS serving
 traffic uses — is serialized COLUMNAR: external ids resolve via one
 fancy-index over the pack's id table, ids and scores are JSON-encoded as
@@ -24,13 +24,16 @@ envelope around each hits block so the front splices the final bytes on
 its own core.
 
 The REST layer of the node itself goes one step further
-(`dumps_response_bytes`): the metadata-only block is written by ONE
-native call (`es_render_hits`) from the kernel's result columns and the
-pack's `EncodedIds` table, with the GIL released and no Python object
-per hit: no id is gathered or encoded and no score becomes a Python
-float on a request. Which path a block takes is decided from what the
-block is (shape flags, score dtype, table present, finite scores), and
-`RENDER_COUNTS` says how many took each.
+(`dumps_response_bytes`): the metadata-only block, and the block that
+also returns each hit's whole `_source`, is written by ONE native call
+(`es_render_hits`) from the kernel's result columns and the pack's
+`JsonLiterals` tables (its ids, and its stored sources once a `_source`
+block has asked for them), with the GIL released and no Python object
+per hit: no id or source is gathered or encoded and no score becomes a
+Python float on a request. Which path a block takes is decided from
+what the block is (shape flags, score dtype, tables present, finite
+scores), and `RENDER_COUNTS` says how many took each; `FETCH_COUNTS`
+counts the hits returned with a `_source` and its bytes.
 
 `ColumnarHits` is a lazy Sequence: in-process consumers (tests, ccs,
 rank_eval) that index or iterate it see ordinary hit dicts — built once,
@@ -51,14 +54,20 @@ from collections.abc import Sequence
 from json.encoder import encode_basestring_ascii
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.metrics import LabeledCounters
 
-__all__ = ["ColumnarHits", "SplicedHits", "SpliceColumns", "EncodedIds",
-           "RENDER_COUNTS", "assemble_hits_list", "dumps_response",
-           "dumps_response_bytes", "hits_columns_from_dicts",
-           "splice_hits_bytes", "encode_wire_response", "splice_wire"]
+__all__ = ["ColumnarHits", "SplicedHits", "SpliceColumns", "JsonLiterals",
+           "RENDER_COUNTS", "FETCH_COUNTS", "assemble_hits_list",
+           "dumps_response", "dumps_response_bytes", "encode_source",
+           "hits_columns_from_dicts", "splice_hits_bytes",
+           "encode_wire_response", "splice_wire"]
 
 _COMPACT = (",", ":")
+
+#: one value → the bytes the Python path writes for it inside a hit:
+#: `json.dumps(value, separators=(",", ":"))`, ensure_ascii and all
+encode_source = json.JSONEncoder(separators=_COMPACT).encode
 
 #: hits blocks rendered to their final bytes in this process, by path:
 #: `native` (es_render_hits: GIL released, no Python object per hit) or
@@ -67,6 +76,13 @@ _COMPACT = (",", ":")
 RENDER_COUNTS = LabeledCounters("path")
 for _path in ("native", "python"):
     RENDER_COUNTS.child(_path)  # both read 0, not absent, before a render
+
+#: kernel-path hits rendered with a `_source`, on either path: `hits`,
+#: and `source_bytes`, the bytes of their `_source` values →
+#: /_tpu/stats `fetch`
+FETCH_COUNTS = LabeledCounters("kind")
+for _kind in ("hits", "source_bytes"):
+    FETCH_COUNTS.child(_kind)
 
 
 def assemble_hits_list(name: str, resident, scores, rows, ords, source,
@@ -145,6 +161,7 @@ def _native_splice():
             _RENDER_FN = native.bind(
                 "response_splice", "es_render_hits", ctypes.c_long,
                 [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                 ctypes.c_void_p, ctypes.c_void_p,
                  ctypes.c_void_p, ctypes.c_int64,
                  ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                  ctypes.c_int32, ctypes.c_char_p, ctypes.c_long,
@@ -295,31 +312,39 @@ def hits_columns_from_dicts(hits: List[Dict[str, Any]]
 
 
 @dataclasses.dataclass
-class EncodedIds:
-    """A pack's external ids as JSON literals, encoded once per pack.
+class JsonLiterals:
+    """A JSON literal for each doc of a pack, encoded once per pack: of
+    its external ids (`ResidentPack.id_json`) and, once a block with
+    `_source` has rendered from the pack, of its stored sources
+    (`ResidentPack.source_literals`).
 
-    `blob` holds `json.dumps(id)` of every id back to back (uint8),
-    `offsets` (int64[n + 1]) where each starts, indexed like the pack's
-    `id_cat`; `max_len` is the longest literal, for sizing an output
-    buffer without reading the table. Host memory only. numpy is
-    imported where it is used: the serving fronts import this module and
-    nothing but the standard library."""
+    `blob` holds the literal of every value back to back (uint8, ASCII:
+    both encoders escape what is not), `offsets` (int64[n + 1]) where
+    each starts, indexed like the pack's `id_cat`; `max_len` is the
+    longest literal, for sizing an output buffer without reading the
+    table. Host memory only. numpy is imported where it is used: the
+    serving fronts import this module and nothing but the standard
+    library."""
 
     blob: Any
     offsets: Any
     max_len: int
 
     @classmethod
-    def build(cls, id_lists: Iterable[Sequence]) -> Optional["EncodedIds"]:
-        """One table over the concatenation of `id_lists` (a pack's
-        per-row id lists, in row order). None when an id is not a
-        string: such a pack renders through the Python path."""
+    def build(cls, value_lists: Iterable[Sequence],
+              encode=encode_basestring_ascii) -> Optional["JsonLiterals"]:
+        """One table over the concatenation of `value_lists` (a pack's
+        per-row lists, in row order), each value written by `encode`: a
+        string's literal for ids, `encode_source` for sources. None when
+        `encode` refuses a value (an id that is not a string, a source
+        json cannot write): such a pack renders through the Python
+        path."""
         import numpy as np
         parts: List[str] = []
         try:
-            for ids in id_lists:
-                parts.extend(map(encode_basestring_ascii, ids))
-        except TypeError:
+            for values in value_lists:
+                parts.extend(map(encode, values))
+        except (TypeError, ValueError):
             return None
         lengths = np.fromiter(map(len, parts), dtype=np.int64,
                               count=len(parts))
@@ -328,9 +353,13 @@ class EncodedIds:
         blob = np.frombuffer("".join(parts).encode("ascii"), dtype=np.uint8)
         return cls(blob, offsets, int(lengths.max(initial=0)))
 
+    @property
+    def nbytes(self) -> int:
+        return int(self.blob.nbytes + self.offsets.nbytes)
+
     @classmethod
-    def concat(cls, tables: Sequence[Optional["EncodedIds"]]
-               ) -> Optional["EncodedIds"]:
+    def concat(cls, tables: Sequence[Optional["JsonLiterals"]]
+               ) -> Optional["JsonLiterals"]:
         """The table of a chain of packs, or None when one has none."""
         import numpy as np
         if any(t is None for t in tables):
@@ -443,20 +472,32 @@ class ColumnarHits(Sequence):
     def to_json(self) -> str:
         RENDER_COUNTS.inc("python")
         cols = self.splice_columns()
-        if cols is not None:
-            return splice_hits_bytes(cols)
-        return json.dumps(self._materialize(), separators=_COMPACT)
+        text = (splice_hits_bytes(cols) if cols is not None
+                else json.dumps(self._materialize(), separators=_COMPACT))
+        if self.source is not False:
+            with_source = [h["_source"] for h in self._materialize()
+                           if "_source" in h]
+            FETCH_COUNTS.inc("hits", n=len(with_source))
+            FETCH_COUNTS.inc("source_bytes",
+                             n=sum(len(encode_source(s)) for s in with_source))
+        return text
 
-    def render_native(self) -> Optional[bytes]:
+    def render_native(self, stages=None) -> Optional[bytes]:
         """The bytes of `to_json()` from one native call that runs with
         the GIL released and touches no Python object per hit: ids come
-        from the resident's `EncodedIds`, scores are formatted in C. None
-        when the block is not the metadata-only shape over float32 scores,
+        from the resident's `id_json`, each hit's whole `_source` (for
+        `source is True`) from its `source_literals` (built on this first
+        need, a stage `source_table` in `stages`), scores are formatted
+        in C. None when the block is neither the metadata-only shape nor
+        that shape with the whole `_source`, is not over float32 scores,
         was materialized (a consumer may have edited the dicts), its
-        resident has no id table, the library is absent or switched off,
-        or the C side refuses an input (a score that is not finite, a row
-        outside the tables): the caller renders it through `to_json`."""
-        if self._hits is not None or not self._metadata_only():
+        resident has no id or source table, the library is absent or
+        switched off, or the C side refuses an input (a score that is not
+        finite, a row outside the tables): the caller renders it through
+        `to_json`."""
+        if (self._hits is not None
+                or not (self.source is False or self.source is True)
+                or self.version or self.seq_no_primary_term):
             return None
         table = getattr(self.resident, "id_json", None)
         fn = _native_render()
@@ -475,19 +516,34 @@ class ColumnarHits(Sequence):
                 or row_offset.dtype != np.int64
                 or not row_offset.flags.c_contiguous):
             return None
+        sources = None
+        if self.source is True:
+            build = getattr(self.resident, "source_literals", None)
+            sources = build(stages) if build is not None else None
+            if sources is None or len(sources.offsets) != len(table.offsets):
+                return None
         name = encode_basestring_ascii(self.name).encode("ascii")
         # per hit: {"_index": ,"_id": ,"_score": } and a comma are 29
-        # bytes, a score is 25 at most
+        # bytes, a score is 25 at most; ,"_source": is 11
         cap = 2 + n * (54 + len(name) + table.max_len)
+        if sources is not None:
+            cap += n * (11 + sources.max_len)
         out = np.empty(cap, dtype=np.uint8)
         rc = fn(table.blob.ctypes.data, table.offsets.ctypes.data,
-                len(table.offsets) - 1, row_offset.ctypes.data,
-                len(row_offset), rows.ctypes.data, ords.ctypes.data,
-                scores.ctypes.data, n, name, len(name),
+                len(table.offsets) - 1,
+                None if sources is None else sources.blob.ctypes.data,
+                None if sources is None else sources.offsets.ctypes.data,
+                row_offset.ctypes.data, len(row_offset), rows.ctypes.data,
+                ords.ctypes.data, scores.ctypes.data, n, name, len(name),
                 out.ctypes.data, cap)
         if rc < 0:
             return None
         RENDER_COUNTS.inc("native")
+        if sources is not None:
+            at = row_offset[rows] + ords
+            FETCH_COUNTS.inc("hits", n=n)
+            FETCH_COUNTS.inc("source_bytes", n=int(
+                (sources.offsets[at + 1] - sources.offsets[at]).sum()))
         return out[:rc].tobytes()
 
 
@@ -564,12 +620,21 @@ def dumps_response(payload: Any) -> str:
     return text
 
 
-def dumps_response_bytes(payload: Any) -> bytes:
+def _block_bytes(block: Any, stages: Any) -> bytes:
+    data = (block.render_native(stages) if isinstance(block, ColumnarHits)
+            else None)
+    return block.to_json().encode("utf-8") if data is None else data
+
+
+def dumps_response_bytes(payload: Any, stages: Any = None) -> bytes:
     """`dumps_response(payload).encode()`, byte for byte, for the REST
     layer: a block that `ColumnarHits.render_native` takes is written by
     the native renderer, and the envelope is joined around the blocks as
     bytes once, so no 60 KB body is decoded, searched and encoded again
-    under the GIL. Any other block renders through `to_json`."""
+    under the GIL. Any other block renders through `to_json`. In
+    `stages` (a StageTimes, or None), the rendering of a kernel-path
+    block that returns `_source` is the stage `fetch`, on either path
+    (wall, and thread CPU one time in `CPU_SAMPLE_EVERY`)."""
     text, blocks = _tokenize(payload)
     if not blocks:
         return text.encode("utf-8")
@@ -577,11 +642,14 @@ def dumps_response_bytes(payload: Any) -> bytes:
     tail = text
     for token, block in blocks.items():
         pre, _, tail = tail.partition(json.dumps(token))
-        data = (block.render_native() if isinstance(block, ColumnarHits)
-                else None)
         out.append(pre.encode("utf-8"))
-        out.append(block.to_json().encode("utf-8")
-                   if data is None else data)
+        if isinstance(block, ColumnarHits) and block.source is not False:
+            with tracing.stage(stages, "fetch", annotate=False,
+                               cpu=stages is not None
+                               and stages.sample_cpu("fetch")):
+                out.append(_block_bytes(block, stages))
+        else:
+            out.append(_block_bytes(block, stages))
     out.append(tail.encode("utf-8"))
     return b"".join(out)
 

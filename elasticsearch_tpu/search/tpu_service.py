@@ -52,7 +52,8 @@ from elasticsearch_tpu.ops import sparse
 from elasticsearch_tpu.parallel import distributed as dist
 from elasticsearch_tpu.parallel.mesh import SHARD_AXIS, make_mesh
 from elasticsearch_tpu.search import dsl
-from elasticsearch_tpu.search.serializer import RENDER_COUNTS, EncodedIds
+from elasticsearch_tpu.search.serializer import (FETCH_COUNTS, RENDER_COUNTS,
+                                                 JsonLiterals, encode_source)
 
 logger = logging.getLogger("elasticsearch_tpu.tpu_service")
 
@@ -395,8 +396,15 @@ class ResidentPack:
     # the same ids as JSON literals, indexed like id_cat, encoded once
     # here so that rendering a response encodes none (host memory; None
     # when an id is not a string: the serializer then renders in Python)
-    id_json: Optional[EncodedIds] = None
+    id_json: Optional[JsonLiterals] = None
     row_segments: Optional[List[Any]] = None  # row → Segment (pinned)
+    # the docs' stored sources as JSON literals, indexed like id_cat:
+    # built by `source_literals` when the first hits block with `_source`
+    # renders from this pack (host memory, some 300 B a doc for MS
+    # MARCO's passages), so a pack that never serves `_source` holds none
+    source_json: Optional[JsonLiterals] = None
+    source_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
     # terms-tuple → _slots_needed result. The slot count depends only on
     # this pack's postings lengths, so the memo lives (and dies) with the
     # pack — a rebuild starts fresh, no invalidation protocol needed.
@@ -446,6 +454,22 @@ class ResidentPack:
         if len(rows) == 0:
             return np.empty(0, dtype=object)
         return self.id_cat[self.row_offset[rows] + ords]
+
+    def source_literals(self, stages: Optional["StageTimes"] = None
+                        ) -> Optional[JsonLiterals]:
+        """The docs' stored sources as literals (`encode_source` of
+        each, as the Python path writes a hit's `_source`), built at the
+        first call, under this pack's lock, as the stage `source_table`
+        of `stages`. None when a source is one json cannot write."""
+        with self.source_lock:
+            if self.source_json is None:
+                with tracing.stage(stages, "source_table", annotate=False,
+                                   cpu=False):
+                    self.source_json = JsonLiterals.build(
+                        (self.row_segments[row].stored_source
+                         for row, ids in enumerate(self.pack.shard_doc_ids)
+                         if len(ids)), encode=encode_source)
+            return self.source_json
 
 
 # -- streaming delta chain (LSM resident path) ------------------------------
@@ -540,7 +564,9 @@ class _UnionView:
         self.row_shard = np.concatenate(shard_parts)
         self.row_offset = np.concatenate(off_parts)
         self.id_cat = np.concatenate(id_parts)
-        self.id_json = EncodedIds.concat([p.id_json for p in self.packs])
+        self.id_json = JsonLiterals.concat([p.id_json for p in self.packs])
+        self.source_json: Optional[JsonLiterals] = None
+        self.source_lock = threading.Lock()
         base = self.packs[0]
         self.pack = base.pack          # stats consumers see the base
         self.readers = base.readers
@@ -559,6 +585,18 @@ class _UnionView:
         if len(rows) == 0:
             return np.empty(0, dtype=object)
         return self.id_cat[self.row_offset[rows] + ords]
+
+    def source_literals(self, stages: Optional["StageTimes"] = None
+                        ) -> Optional[JsonLiterals]:
+        """Each pack's source table (built where it was not), joined
+        in the chain's order, once a view."""
+        with self.source_lock:
+            if self.source_json is None:
+                tables = [p.source_literals(stages) for p in self.packs]
+                with tracing.stage(stages, "source_table", annotate=False,
+                                   cpu=False):
+                    self.source_json = JsonLiterals.concat(tables)
+            return self.source_json
 
 
 class IndexPackCache:
@@ -610,7 +648,13 @@ class IndexPackCache:
             # per-(index,field) HBM breakdown: raw vs resident bytes,
             # ratio, block metadata — the /_tpu/stats + Prometheus view
             # of the compressed-pack capacity win
-            packs = {f"{idx}/{field}": dict(entry.hbm_detail)
+            packs = {f"{idx}/{field}": {
+                **entry.hbm_detail,
+                # host bytes of the pack's source table (0 until a
+                # `_source` block renders from it)
+                "source_table_bytes": (entry.source_json.nbytes
+                                       if entry.source_json is not None
+                                       else 0)}
                      for (idx, field), entry in self._cache.items()}
             deltas = {
                 f"{idx}/{field}": {
@@ -1160,7 +1204,7 @@ class IndexPackCache:
             imp_device_arrays=imp_arrays,
             row_shard=row_shard, row_offset=row_offset,
             id_cat=id_cat,
-            id_json=EncodedIds.build(pack.shard_doc_ids),
+            id_json=JsonLiterals.build(pack.shard_doc_ids),
             row_segments=row_segments,
             comp_streams=streams, hbm_detail=hbm_detail,
             group_id=self.group_id, mesh=mesh)
@@ -4506,6 +4550,7 @@ class TpuSearchService:
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),
                 "render": RENDER_COUNTS.counts(),
+                "fetch": FETCH_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
                 "supervision": self.supervisor.stats(),
                 "watchdog": self.watchdog.stats(),

@@ -14,7 +14,7 @@ from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.indices.service import IndicesService
 from elasticsearch_tpu.search import coordinator, serializer
 from elasticsearch_tpu.search.serializer import (RENDER_COUNTS, ColumnarHits,
-                                                 EncodedIds, SpliceColumns,
+                                                 JsonLiterals, SpliceColumns,
                                                  assemble_hits_list,
                                                  dumps_response,
                                                  dumps_response_bytes,
@@ -187,7 +187,7 @@ def _resident(id_lists, table=True):
     np.cumsum(sizes[:-1], out=row_offset[1:])
     res = types.SimpleNamespace(
         id_cat=id_cat, row_offset=row_offset,
-        id_json=EncodedIds.build(id_lists) if table else None)
+        id_json=JsonLiterals.build(id_lists) if table else None)
     res.resolve_ids = lambda rows, ords: id_cat[row_offset[rows] + ords]
     return res
 
@@ -324,13 +324,13 @@ def test_native_two_shards_row_offset(native_render, dtype):
 
 def test_encoded_ids_concat_is_the_table_of_the_concatenation():
     parts = [["a", 'q"'], [], ["é", "", "zz"]]
-    whole = EncodedIds.build([[i for p in parts for i in p]])
-    chained = EncodedIds.concat([EncodedIds.build([p]) for p in parts])
+    whole = JsonLiterals.build([[i for p in parts for i in p]])
+    chained = JsonLiterals.concat([JsonLiterals.build([p]) for p in parts])
     assert chained.blob.tobytes() == whole.blob.tobytes()
     assert chained.offsets.tolist() == whole.offsets.tolist()
     assert chained.max_len == whole.max_len == len('"\\u00e9"')
-    assert EncodedIds.concat([whole, None]) is None
-    assert EncodedIds.build([["a"], [7]]) is None
+    assert JsonLiterals.concat([whole, None]) is None
+    assert JsonLiterals.build([["a"], [7]]) is None
 
 
 def _block(**kw):
@@ -349,7 +349,8 @@ def _block(**kw):
 
 
 OLD_PATH_CASES = {
-    "source": lambda: _block(source=True),
+    # a `_source` filter: the native renderer writes whole sources only
+    "source": lambda: _block(source=["f"]),
     "version": lambda: _block(version=True),
     "seq_no_primary_term": lambda: _block(seq_no_primary_term=True),
     "non_string_ids": lambda: _block(ids=["a", 7, "c"]),
