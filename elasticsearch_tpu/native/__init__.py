@@ -50,12 +50,19 @@ def load(name: str):
         return lib
 
 
-def bind(lib_name: str, symbol: str, restype, argtypes):
+def bind(lib_name: str, symbol: str, restype, argtypes,
+         hold_gil: bool = False):
     """load() + bind one symbol's ctypes signature; None when the
-    native library is unavailable (callers use their Python fallback)."""
+    native library is unavailable (callers use their Python fallback).
+
+    A call lets go of the interpreter lock for its length and takes it
+    back after; `hold_gil` keeps it instead (`ctypes.PyDLL`), for a call
+    shorter than the wait to take the lock back from busy threads."""
     lib = load(lib_name)
     if lib is None:
         return None
+    if hold_gil:
+        lib = ctypes.PyDLL(lib._name, handle=lib._handle)
     fn = getattr(lib, symbol, None)
     if fn is None:
         # a library left from an older source whose mtime says otherwise

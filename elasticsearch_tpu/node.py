@@ -627,7 +627,7 @@ class Node:
                 CROSS_CHIP_COUNTS, EXACT_ENTRY_COUNTS, EXACT_PIN_COUNTS,
                 EXACT_RESULT_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
                 KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
-                ROUTE_COUNTS)
+                OPERAND_COUNTS, ROUTE_COUNTS)
             from elasticsearch_tpu.parallel.distributed import (
                 TERM_TABLE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
@@ -667,6 +667,10 @@ class Node:
             # es_tpu_kernel_term_table_total{kind=...}
             for labels, counter in TERM_TABLE_COUNTS.items():
                 yield ("kernel.term_table", labels, counter)
+            # full-postings launches by the builder of their operand:
+            # es_tpu_kernel_operands_total{builder=...}
+            for labels, counter in OPERAND_COUNTS.items():
+                yield ("kernel.operands", labels, counter)
             # launches on a mesh of several devices, their rows and
             # devices: es_tpu_kernel_cross_chip_total{kind=...}
             for labels, counter in CROSS_CHIP_COUNTS.items():

@@ -31,7 +31,8 @@ import dataclasses
 import math
 import threading
 from functools import lru_cache, partial
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -135,7 +136,7 @@ class StackedShardPack:
         init=False, repr=False, compare=False)
 
     def __post_init__(self):
-        self.term_table = TermTable()
+        self.term_table = TermTable(self.num_shards)
 
     def nbytes_device(self) -> int:
         return (self.flat_docs.nbytes + self.flat_impact.nbytes
@@ -173,29 +174,65 @@ for _kind in ("lookups", "columns"):
 Column = Tuple[Tuple[int, int, float, bool, Optional[float]], ...]
 
 
+class ColumnArrays(NamedTuple):
+    """A term table's columns as dense [capacity, shard rows] arrays, a
+    term a row; idf NaN where a `Column` holds None."""
+
+    start: np.ndarray    # int32
+    length: np.ndarray   # int32
+    weight: np.ndarray   # float64, at boost 1
+    held: np.ndarray     # bool
+    idf: np.ndarray      # float64
+
+    @classmethod
+    def empty(cls, capacity: int, shards: int) -> "ColumnArrays":
+        shape = (capacity, shards)
+        return cls(np.zeros(shape, np.int32), np.zeros(shape, np.int32),
+                   np.zeros(shape, np.float64), np.zeros(shape, bool),
+                   np.full(shape, np.nan))
+
+
+#: the table row of every term whose column is all zero: held by no
+#: shard row and of df 0 in every statistics group
+ZERO_TERM = 0
+
+
 class TermTable:
-    """A pack's query terms, a `Column` each, so that a launch's operands
-    cost one dict lookup a query term and not one a (shard row, query
-    term), and no `math.log` at all once a term is known. A column is
-    built on its term's first use (`_row_stats`, `_idf`: what
-    `term_weights` computes) and held with the pack only if some shard
-    row's vocabulary holds the term, so the table never outgrows the
-    union of the rows' vocabularies, whatever terms users send: about
-    180 B of host memory a (term, shard row), 4.2 MB for 2,980 terms on
-    8 rows. A term no row holds gets a column built anew at each use.
+    """A pack's query terms, so that a launch's operands cost one dict
+    lookup a query term and not one a (shard row, query term), and no
+    `math.log` at all once a term is known. A column is built on its
+    term's first use (`_row_stats`, `_idf`: what `term_weights`
+    computes) and kept with the pack only if some shard row's
+    vocabulary holds the term, so the table never outgrows the union of
+    the rows' vocabularies, whatever terms users send. A term no row
+    holds gets its column built anew at each use; where that column is
+    all zero (df 0 in every group, as in any pack `build_stacked_pack`
+    makes), it reads as the reserved row `ZERO_TERM`.
 
-    `prepare_query_batch` and `prepare_term_ranges` read columns with
-    plain Python and make each array once from a list: numpy operations
-    over a launch's entries each let go of the interpreter lock, and the
-    launch thread then waits for it behind the request threads (on four
-    chips, operand code that was numpy throughout waited 80 ms a train
-    for 5 ms of work)."""
+    The columns live in `ColumnArrays`, 25 B a (term, shard row), grown
+    by doubling under the table's lock; a row once written never
+    changes, so arrays taken under the lock stay valid for a reader
+    after it lets go. `resolve_ids` gives the native operand builder
+    (`build_full_operands`) a launch's term rows. `resolve` gives the
+    Python builders (`prepare_query_batch`, `prepare_term_ranges`) each
+    term's `Column`, a tuple view made on the term's first use by them
+    and kept: they read it with plain Python and make each array once
+    from a list, because numpy operations over a launch's entries each
+    let go of the interpreter lock, and the launch thread then waits for
+    it behind the request threads (on four chips, operand code that was
+    numpy throughout waited 80 ms a train for 5 ms of work)."""
 
-    def __init__(self):
+    def __init__(self, shards: int):
+        self._ids: Dict[str, int] = {}
         self._columns: Dict[str, Column] = {}
+        self._arrays = ColumnArrays.empty(16, shards)
+        self._n = ZERO_TERM + 1
         self._lock = threading.Lock()
 
-    def _add(self, pack: StackedShardPack, term: str) -> Column:
+    def _add(self, pack: StackedShardPack, term: str) -> Tuple[int, Column]:
+        """(the term's row, its column): a new row if some shard row
+        holds the term, else `ZERO_TERM` for an all-zero column and -1
+        for one the table does not keep."""
         k1p = pack.k1 + 1.0
         rows = []
         for si in range(pack.num_shards):
@@ -212,23 +249,75 @@ class TermTable:
             else:
                 rows.append((0, 0, w, False, idf))
         column = tuple(rows)
-        if any(row[3] for row in rows):
-            self._columns[term] = column
+        if not any(row[3] for row in rows):
+            zero = all(row[4] is None for row in rows)
+            return (ZERO_TERM if zero else -1), column
+        i = self._n
+        a = self._arrays
+        if i == len(a.start):
+            grown = ColumnArrays.empty(2 * i, pack.num_shards)
+            for old, new in zip(a, grown):
+                new[:i] = old
+            a = self._arrays = grown
+        for array, values in zip(a, zip(*rows)):
+            array[i] = values  # an idf of None reads as NaN
+        self._ids[term] = i
+        self._n = i + 1
+        return i, column
+
+    def _column(self, pack: StackedShardPack, term: str) -> Column:
+        """The Python builders' view of a term's column (under the lock)."""
+        i = self._ids.get(term)
+        if i is None:
+            i, column = self._add(pack, term)
+            if i <= ZERO_TERM:
+                return column
+        else:
+            a = self._arrays
+            column = tuple(zip(
+                a.start[i].tolist(), a.length[i].tolist(),
+                a.weight[i].tolist(), a.held[i].tolist(),
+                [None if v != v else v for v in a.idf[i].tolist()]))
+        self._columns[term] = column
         return column
 
     def resolve(self, pack: StackedShardPack,
                 queries: Sequence[Sequence[str]]) -> List[List[Column]]:
         """Each query's terms' columns, built on first use."""
         with self._lock:
-            known = len(self._columns)
+            known = self._n
             get = self._columns.get
-            out = [[get(term) or self._add(pack, term) for term in q]
+            out = [[get(term) or self._column(pack, term) for term in q]
                    for q in queries]
-            built = len(self._columns) - known
+            built = self._n - known
         TERM_TABLE_COUNTS.inc("lookups", n=sum(map(len, out)))
         if built:
             TERM_TABLE_COUNTS.inc("columns", n=built)
         return out
+
+    def resolve_ids(self, pack: StackedShardPack,
+                    queries: Sequence[Sequence[str]]
+                    ) -> Optional[Tuple[List[int], ColumnArrays, int]]:
+        """(the queries' terms' rows in query order, the arrays, the rows
+        they hold), columns built on first use; None where a term's
+        column is neither kept nor all zero (the Python builders read it)."""
+        ids: List[int] = []
+        with self._lock:
+            known = self._n
+            get = self._ids.get
+            for q in queries:
+                for term in q:
+                    i = get(term)
+                    if i is None:
+                        i = self._add(pack, term)[0]
+                    ids.append(i)
+            arrays, n = self._arrays, self._n
+        if n > known:
+            TERM_TABLE_COUNTS.inc("columns", n=n - known)
+        if min(ids, default=ZERO_TERM) < ZERO_TERM:
+            return None
+        TERM_TABLE_COUNTS.inc("lookups", n=len(ids))
+        return ids, arrays, n
 
 
 def build_stacked_pack(segments: Sequence[Segment], field: str,
@@ -976,6 +1065,98 @@ def pack_pruned_operands(batch: QueryBatch, t_starts: np.ndarray,
              t_starts.view(np.float32), t_lengths.view(np.float32),
              t_weights, tail]
     return np.concatenate(parts, axis=2)
+
+
+_OPERANDS_FN = None
+_OPERANDS_TRIED = False
+
+
+def native_operand_builder():
+    """`es_pruned_operands` (native/launch_operands.c), bound on first
+    use; None where the library did not build (the Python builders
+    serve every launch)."""
+    global _OPERANDS_FN, _OPERANDS_TRIED
+    if not _OPERANDS_TRIED:
+        import ctypes
+
+        from elasticsearch_tpu import native
+        p, i32, i64 = ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64
+        _OPERANDS_FN = native.bind(
+            "launch_operands", "es_pruned_operands", i64,
+            [p, p, p, i32, p, p, p, p, p, i32, i32, i32, i32, i32, i64, i64,
+             ctypes.c_double, p, p], hold_gil=True)
+        _OPERANDS_TRIED = True
+    return _OPERANDS_FN
+
+
+class LaunchTerms(NamedTuple):
+    """A launch's query terms as rows of its pack's term table."""
+
+    ids: np.ndarray      # int32[Σ terms]: the rows, in query order
+    offsets: np.ndarray  # int32[queries + 1]: query q's are [o[q], o[q+1])
+    boosts: np.ndarray   # float64[queries]
+    columns: ColumnArrays
+    n_columns: int       # the rows `columns` held when the ids were read
+
+
+def resolve_launch_terms(pack: StackedShardPack,
+                         queries: Sequence[Sequence[str]],
+                         boosts: Sequence[float]) -> Optional[LaunchTerms]:
+    """The queries' terms resolved through the pack's `TermTable`, once
+    each, into the arrays `build_full_operands` reads; None where a term's
+    column is not in the table (`TermTable.resolve_ids`)."""
+    resolved = pack.term_table.resolve_ids(pack, queries)
+    if resolved is None:
+        return None
+    ids, columns, n_columns = resolved
+    offsets = [0]
+    for q in queries:
+        offsets.append(offsets[-1] + len(q))
+    return LaunchTerms(np.array(ids, dtype=np.int32),
+                       np.array(offsets, dtype=np.int32),
+                       np.array(boosts, dtype=np.float64), columns, n_columns)
+
+
+class FullOperands(NamedTuple):
+    ops: np.ndarray  # float32[S, B, 3·t_slots + 3·pad_terms + 1]
+    t_slots: int
+    max_len: int     # L_c of the slot plan, before any pad
+    window: int
+    real: int        # Σ of the slots' lengths
+
+
+def build_full_operands(pack: StackedShardPack, terms: LaunchTerms,
+                        rows: int, t_slots: int, pad_terms: int,
+                        chunk_cap: int = CHUNK_CAP, lane: int = 128
+                        ) -> Optional[FullOperands]:
+    """A full-postings launch's fused operand from one native call that
+    holds no Python object: `pack_pruned_operands(
+    prepare_query_batch(pack, queries, boosts, pad_batch_to=rows,
+    pad_t_slots=t_slots), *prepare_term_ranges(...))` byte for byte,
+    with the slot plan's L_c, window and Σ lengths beside it, and no
+    numpy operation over the launch's entries on the calling thread.
+    None where the plan needs more than `t_slots` slots (the caller's
+    Python builders then plan it at its own width)."""
+    fn = native_operand_builder()
+    c = terms.columns
+    if (c.start.shape != (len(c.start), pack.num_shards)
+            or not len(c.start) >= terms.n_columns > ZERO_TERM
+            or len(terms.offsets) != len(terms.boosts) + 1):
+        raise ValueError("launch terms that are not this pack's table's")
+    out = np.empty((pack.num_shards, rows, 3 * t_slots + 3 * pad_terms + 1),
+                   dtype=np.float32)
+    info = np.empty(4, dtype=np.int64)
+    rc = fn(terms.ids.ctypes.data, terms.offsets.ctypes.data,
+            terms.boosts.ctypes.data, len(terms.boosts),
+            c.start.ctypes.data, c.length.ctypes.data, c.weight.ctypes.data,
+            c.held.ctypes.data, c.idf.ctypes.data, terms.n_columns,
+            pack.num_shards, rows, t_slots, pad_terms, chunk_cap, lane,
+            pack.k1 + 1.0, out.ctypes.data, info.ctypes.data)
+    if rc == -1:
+        raise ValueError("es_pruned_operands: an input out of range")
+    if rc < 0:
+        return None
+    return FullOperands(out, *map(int, info))
 
 
 @lru_cache(maxsize=32)

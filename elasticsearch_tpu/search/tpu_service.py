@@ -1937,6 +1937,10 @@ EXACT_RESULT_COUNTS = LabeledCounters("kind")
 #: the slots' lengths, and rows × slots × chunk length × shard rows
 #: dispatched → es_tpu_kernel_full_entries_total
 FULL_ENTRY_COUNTS = LabeledCounters("kind")
+#: full-postings launches by the builder of their fused operand: `native`
+#: (`dist.build_full_operands`, one C call) or `python` (the library
+#: absent, or a launch it does not take) → es_tpu_kernel_operands_total
+OPERAND_COUNTS = LabeledCounters("builder")
 #: launches dispatched on a mesh of more than one device, the query rows
 #: they carried as dispatched and the devices they ran on (one count a
 #: launch, so `devices` ÷ `launches` is the mesh's size): what a reader
@@ -1956,6 +1960,8 @@ for _label in ("real", "padded"):
     FULL_ENTRY_COUNTS.child(_label)
 for _label in ("launches", "rows", "devices"):
     CROSS_CHIP_COUNTS.child(_label)
+for _label in ("native", "python"):
+    OPERAND_COUNTS.child(_label)
 for _label in ("rows", "rows_under", "trains", "launches"):
     EXACT_PIN_COUNTS.child(_label)
 for _label in ("queries", "empty"):
@@ -2712,6 +2718,33 @@ def run_exact_program(resident: ResidentPack, program: ExactProgram,
                    program=program)
 
 
+def _native_full_operands(pack: dist.StackedShardPack,
+                          flats: Sequence[FlatQuery], rows: int,
+                          slots: int, stages: Optional[StageTimes]
+                          ) -> Optional[dist.FullOperands]:
+    """A full-path launch's fused operand from one native call
+    (`dist.build_full_operands`), counted `operands.native`: the terms
+    resolved (`prep.query_batch`), then the slot plan, the term ranges
+    and the array in one C call that keeps the interpreter lock, far
+    shorter than the wait to take it back (`prep.term_ranges`). None
+    where the library did not build, a term's column is not in the
+    table, or the plan needs more than `slots`: the Python builders then
+    build the launch's operand as before."""
+    if dist.native_operand_builder() is None:
+        return None
+    with tracing.stage(stages, "prep.query_batch"):
+        terms = dist.resolve_launch_terms(pack, [f.terms for f in flats],
+                                          [f.boost for f in flats])
+    if terms is None:
+        return None
+    with tracing.stage(stages, "prep.term_ranges"):
+        full = dist.build_full_operands(pack, terms, rows, slots,
+                                        PRUNE_MAX_TERMS)
+    if full is not None:
+        OPERAND_COUNTS.inc("native")
+    return full
+
+
 def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
                    k: int, mesh, prefix_cap: int = PREFIX_CAP,
                    stages: Optional[StageTimes] = None,
@@ -2750,30 +2783,40 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
         k_cand = k_out  # exact totals: the candidate pool IS the result
     # the query operands (`prep.query_batch`), then the rescore terms'
     # ranges and the one array that carries them all (`prep.term_ranges`),
-    # each inside `batcher.prep`
-    with tracing.stage(stages, "prep.query_batch"):
+    # each inside `batcher.prep`: on the full path from one native call
+    # where it can, else by the Python builders
+    full = (_native_full_operands(pack, flats, b_bucket, full_slots, stages)
+            if full_slots is not None else None)
+    if full is not None:
+        ops = full.ops
+    else:
+        with tracing.stage(stages, "prep.query_batch"):
+            if full_slots is not None:
+                batch = dist.prepare_query_batch(
+                    pack, [f.terms for f in flats],
+                    boosts=[f.boost for f in flats],
+                    min_counts=[1] * len(flats),
+                    pad_batch_to=b_bucket,
+                    pad_t_slots=full_slots, pad_max_len=dist.CHUNK_CAP)
+            else:
+                batch = dist.prepare_query_batch(
+                    pack, [f.terms for f in flats],
+                    boosts=[f.boost for f in flats],
+                    min_counts=[1] * len(flats),
+                    pad_batch_to=b_bucket,
+                    prefix_cap=prefix_cap, imp_impacts=imp_impacts,
+                    pad_t_slots=_prune_t_slots(prefix_cap),
+                    pad_max_len=dist.CHUNK_CAP)
+        with tracing.stage(stages, "prep.term_ranges"):
+            t_starts, t_lengths, t_weights = dist.prepare_term_ranges(
+                pack, batch, boosts=[f.boost for f in flats],
+                pad_terms=PRUNE_MAX_TERMS)
+            ops = dist.pack_pruned_operands(batch, t_starts, t_lengths,
+                                            t_weights)
         if full_slots is not None:
-            batch = dist.prepare_query_batch(
-                pack, [f.terms for f in flats],
-                boosts=[f.boost for f in flats],
-                min_counts=[1] * len(flats),
-                pad_batch_to=b_bucket,
-                pad_t_slots=full_slots, pad_max_len=dist.CHUNK_CAP)
-        else:
-            batch = dist.prepare_query_batch(
-                pack, [f.terms for f in flats],
-                boosts=[f.boost for f in flats],
-                min_counts=[1] * len(flats),
-                pad_batch_to=b_bucket,
-                prefix_cap=prefix_cap, imp_impacts=imp_impacts,
-                pad_t_slots=_prune_t_slots(prefix_cap),
-                pad_max_len=dist.CHUNK_CAP)
-    with tracing.stage(stages, "prep.term_ranges"):
-        t_starts, t_lengths, t_weights = dist.prepare_term_ranges(
-            pack, batch, boosts=[f.boost for f in flats],
-            pad_terms=PRUNE_MAX_TERMS)
-        ops = dist.pack_pruned_operands(batch, t_starts, t_lengths,
-                                        t_weights)
+            full = dist.FullOperands(ops, batch.t_slots, batch.max_len,
+                                     batch.window, int(batch.lengths.sum()))
+            OPERAND_COUNTS.inc("python")
     if variant is None:
         variant = _pruned_variant()
     KERNEL_VARIANT_COUNTS.inc("full" if full_slots is not None
@@ -2781,13 +2824,16 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     LAUNCH_COUNTS.inc(path)
     _count_cross_chip(mesh, b_bucket)
     if full_slots is not None:
-        FULL_ENTRY_COUNTS.inc("real", n=int(batch.lengths.sum()))
-        FULL_ENTRY_COUNTS.inc("padded", n=batch.lengths.size * batch.max_len)
+        FULL_ENTRY_COUNTS.inc("real", n=full.real)
+        # rows × slots × the chunk length dispatched (`pad_max_len`)
+        FULL_ENTRY_COUNTS.inc("padded", n=ops.shape[0] * ops.shape[1]
+                              * full.t_slots * max(full.max_len,
+                                                   dist.CHUNK_CAP))
         # (a caller that forces a rung its queries do not fit, which the
         # routing never does, gets the jitted program at their width)
         ready = (_ready_full_programs(resident, mesh, k, max_batch, variant)
                  if full_slots <= FULL_READY_SLOTS
-                 and batch.t_slots == full_slots else {})
+                 and full.t_slots == full_slots else {})
         fn = ready.get((full_slots, b_bucket)) or _make_full_search(
             resident, mesh, full_slots, k_out, variant)
     else:
@@ -4546,6 +4592,7 @@ class TpuSearchService:
                 "cross_chip": CROSS_CHIP_COUNTS.counts(),
                 "exact_pin": EXACT_PIN_COUNTS.counts(),
                 "term_table": dist.TERM_TABLE_COUNTS.counts(),
+                "operands": OPERAND_COUNTS.counts(),
                 "exact_results": EXACT_RESULT_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),

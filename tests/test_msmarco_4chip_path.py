@@ -29,8 +29,9 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 
-from esbench import compare, corpus, reference  # noqa: E402
+from esbench import compare, corpus, layers, reference  # noqa: E402
 
+from elasticsearch_tpu import native  # noqa: E402
 from elasticsearch_tpu.common.settings import Settings  # noqa: E402
 from elasticsearch_tpu.node import Node, serve  # noqa: E402
 from elasticsearch_tpu.parallel import distributed as dist  # noqa: E402
@@ -267,6 +268,59 @@ def test_term_table_counts_a_query_term_once_a_launch(hosts, n_devices):
     prom = host["node"].metrics.prometheus_text()
     for kind in ("lookups", "columns"):
         assert f'es_tpu_kernel_term_table_total{{kind="{kind}"}}' in prom
+
+
+@pytest.mark.parametrize("n_devices", [1, 4], ids=["one_device", "mesh_1x4"])
+def test_the_python_builders_serve_where_the_library_is_absent(
+        hosts, n_devices, monkeypatch):
+    """A train of full-path launches with the native operand builder,
+    then with `native.bind` giving None, as in a process where the
+    library did not build: the same answers to the bit, the reference's,
+    each launch counted `operands.native` and then `operands.python`,
+    read by `operands_native_pct` as 100 and then 0 (and as nothing on a
+    program without the counter); `/_tpu/stats` and the exposition carry
+    the family."""
+    host = hosts["served"][n_devices]
+    resident, mesh = host["resident"], host["mesh"]
+    train = _of_terms(hosts["queries"], 2) + _of_terms(hosts["queries"], 5)
+    flats = [tpu_service.FlatQuery(FIELD, [corpus.word(t) for t in q], 1.0, 1)
+             for q in train]
+    reader = layers.find_reader("operands_native_pct.closed")
+    assert reader({"window.term_table.lookups": 9.0}) is None  # a parent
+    answers = {}
+    for builder, other, share in (("native", "python", 100.0),
+                                  ("python", "native", 0.0)):
+        if builder == "python":
+            monkeypatch.setattr(native, "bind", lambda *a, **k: None)
+            monkeypatch.setattr(dist, "_OPERANDS_TRIED", False)
+            monkeypatch.setattr(dist, "_OPERANDS_FN", None)
+        before = host["http"].stats()
+        results = tpu_service.execute_flat_batch(resident, flats, SIZE, mesh)
+        after = host["http"].stats()
+        launched = sum(n for path, n in _rise(after, before, "launches").items()
+                       if path.startswith("full_s"))
+        assert launched >= 1
+        assert _rise(after, before, "operands") == {builder: launched,
+                                                    other: 0}
+        facts = layers.difference(layers.flatten(after, "s", {}),
+                                  layers.flatten(before, "s", {}), "s",
+                                  "window")
+        assert reader(facts) == share
+        answers[builder] = [(r.total_hits, r.total_relation, list(r.hits))
+                            for r in results]
+    assert dist.native_operand_builder() is None
+    assert answers["native"] == answers["python"]
+    for q, (total_hits, relation, hits) in zip(train, answers["python"]):
+        total, docs, scores = reference.reference_topk(hosts["shards"], q, SIZE)
+        resp = {"_shards": {"failed": 0}, "hits": {
+            "total": {"value": total_hits, "relation": relation},
+            "hits": [{"_id": h[-1], "_score": h[0]} for h in hits]}}
+        compare.compare_response(resp, total,
+                                 [corpus.doc_id(d) for d in docs.tolist()],
+                                 scores.tolist(), SIZE)
+    prom = host["node"].metrics.prometheus_text()
+    for builder in ("native", "python"):
+        assert f'es_tpu_kernel_operands_total{{builder="{builder}"}}' in prom
 
 
 @pytest.mark.parametrize("make", ["pruned", "exact"])

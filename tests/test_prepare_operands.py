@@ -11,10 +11,17 @@ shape and bytes (f32 weights and tail bounds included), and `max_len`,
 `term_table` counts a query term once a launch, whatever the pack's
 shard rows; a table holds columns only of terms its rows hold, and a new
 pack builds its own.
+
+The native builder of a full-path launch's fused operand
+(`build_full_operands`, `native/launch_operands.c`) is held to the Python
+builders' `pack_pruned_operands(prepare_query_batch(...),
+*prepare_term_ranges(...))` byte for byte, with the plan's `t_slots`,
+`max_len`, `window` and Σ lengths beside it.
 """
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import math
 import sys
@@ -456,6 +463,139 @@ def test_fused_operands_carry_the_batch_and_its_term_ranges(
 
 
 # ---------------------------------------------------------------------------
+# the native builder of a full-path launch's fused operand
+# ---------------------------------------------------------------------------
+
+PAD_TERMS = 8
+
+
+def native_full(pack, queries, boosts, rows, rung):
+    assert dist.native_operand_builder() is not None
+    terms = dist.resolve_launch_terms(
+        pack, queries, boosts if boosts is not None else [1.0] * len(queries))
+    assert terms is not None
+    return dist.build_full_operands(pack, terms, rows, rung, PAD_TERMS)
+
+
+def python_fused(pack, queries, boosts, rows, rung):
+    """The Python builders' operand and batch, called as `_launch_pruned`
+    calls them on the full path (less the pad of `max_len`)."""
+    batch = dist.prepare_query_batch(pack, queries, boosts=boosts,
+                                     min_counts=[1] * len(queries),
+                                     pad_batch_to=rows, pad_t_slots=rung)
+    t = dist.prepare_term_ranges(pack, batch, boosts=boosts,
+                                 pad_terms=PAD_TERMS)
+    return dist.pack_pruned_operands(batch, *t), batch
+
+
+def ref_fused(pack, queries, boosts, rows, rung):
+    """The loops' slots, term ranges and a zero tail side by side."""
+    want = ref_prepare_query_batch(pack, queries, boosts=boosts,
+                                   pad_batch_to=rows, pad_t_slots=rung)
+    t = ref_prepare_term_ranges(pack, queries, boosts=boosts,
+                                pad_batch_to=rows, pad_terms=PAD_TERMS)
+    return np.concatenate(
+        [want.starts.view(np.float32), want.lengths.view(np.float32),
+         want.weights, t[0].view(np.float32), t[1].view(np.float32), t[2],
+         np.zeros((pack.num_shards, rows, 1), dtype=np.float32)], axis=2)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 8])
+@pytest.mark.parametrize("bucket", [8, 64, 128])
+@pytest.mark.parametrize("rung", [16, 32, 128])
+def test_native_operand_equals_the_python_builders(seeded_np, rows, bucket,
+                                                   rung):
+    """Boosts other than 1, terms no row holds (`nowhere`), terms some
+    rows hold only, under a statistics group a row (a weight 0 where a
+    row lacks the term) and under one group (the group's weight on a
+    row without postings), extents past CHUNK_CAP split into chunks, an
+    empty query and the rows the bucket pads: the native operand is the
+    Python builders' to the byte, its `t_slots`, `max_len` and `window`
+    are their batch's and its Σ lengths `lengths.sum()`; the Python
+    builders, reading the columns the native call resolved first, are
+    the loops'."""
+    pack = synthetic_pack(seeded_np, rows, rows > 1 and rung != 32, True)
+    queries = [[t for t in q if t != "ghost"]
+               for q in draw_queries(seeded_np, pack, bucket - bucket // 8,
+                                     max_terms=4)]
+    boosts = [float(seeded_np.choice([1.0, 1.0, 0.5, 2.5])) for _ in queries]
+    got = native_full(pack, queries, boosts, bucket, rung)
+    want, batch = python_fused(pack, queries, boosts, bucket, rung)
+    assert got.ops.shape == (rows, bucket, 3 * rung + 3 * PAD_TERMS + 1)
+    same(got.ops, want, "ops")
+    assert (got.t_slots, got.max_len, got.window) == \
+        (batch.t_slots, batch.max_len, batch.window)
+    assert got.t_slots == rung
+    assert got.real == int(batch.lengths.sum()) > 0
+    same(want, ref_fused(pack, queries, boosts, bucket, rung), "loops")
+
+
+def test_the_native_builder_keeps_the_interpreter_lock():
+    """Bound through `ctypes.PyDLL`: a launch's operand takes the call a
+    fraction of a millisecond, and a call that let go of the lock would
+    wait far longer than that to take it back from the request threads."""
+    fn = dist.native_operand_builder()
+    assert fn is not None and fn._flags_ & ctypes._FUNCFLAG_PYTHONAPI
+
+
+@pytest.mark.parametrize("rows", [2, 8])
+def test_a_column_the_table_does_not_keep_goes_to_the_python_builders(
+        seeded_np, rows):
+    """`nowhere` (no row, df 0 everywhere) reads as the all-zero row
+    `ZERO_TERM`; `ghost` (no row holds it, yet the statistics give it a
+    df, which no pack `build_stacked_pack` makes has) has a weight the
+    table keeps no row for, so the native path declines its launch and
+    counts no lookup; neither term grows the table."""
+    pack = synthetic_pack(seeded_np, rows, True, False)
+    held = sorted(pack.vocabs[0])[:2]
+    before = _counts()
+    terms = dist.resolve_launch_terms(pack, [[held[0], "nowhere"], [held[1]]],
+                                      [1.0, 2.0])
+    ids = terms.ids.tolist()
+    assert ids[1] == dist.ZERO_TERM and dist.ZERO_TERM not in (ids[0], ids[2])
+    assert terms.offsets.tolist() == [0, 2, 3]
+    assert terms.boosts.tolist() == [1.0, 2.0]
+    assert _rise(before) == {"lookups": 3, "columns": 2}
+    assert dist.resolve_launch_terms(pack, [[held[0], "ghost"]], [1.0]) is None
+    assert _rise(before) == {"lookups": 3, "columns": 2}
+    assert set(pack.term_table._ids) == set(held)
+    queries = [[held[0], "ghost", "nowhere"], [held[1]]]
+    same_batch(dist.prepare_query_batch(pack, queries),
+               ref_prepare_query_batch(pack, queries))
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+def test_a_plan_wider_than_the_buffer_returns_the_error(seeded_np, rows):
+    """Nine times a term past CHUNK_CAP on the first row need 18 or more
+    slots: the call over a 16-slot buffer returns minus the plan's
+    width and writes nothing, `build_full_operands` gives None, and the
+    Python builders plan the launch at its own width as before."""
+    pack = synthetic_pack(seeded_np, rows, False, True)
+    start = pack.row_starts[0]
+    long = next(t for t, r in sorted(pack.vocabs[0].items())
+                if start[r + 1] - start[r] > CHUNK_CAP)
+    queries = [[long] * 9, [long]]
+    terms = dist.resolve_launch_terms(pack, queries, [1.0, 1.0])
+    assert dist.build_full_operands(pack, terms, 8, 16, PAD_TERMS) is None
+    batch = python_fused(pack, queries, None, 8, 16)[1]
+    assert batch.t_slots >= 32
+    out = np.full((rows, 8, 3 * 16 + 3 * PAD_TERMS + 1), 7.0, np.float32)
+    info = np.zeros(4, dtype=np.int64)
+    c = terms.columns
+    rc = dist.native_operand_builder()(
+        terms.ids.ctypes.data, terms.offsets.ctypes.data,
+        terms.boosts.ctypes.data, 2, c.start.ctypes.data,
+        c.length.ctypes.data, c.weight.ctypes.data, c.held.ctypes.data,
+        c.idf.ctypes.data, terms.n_columns, rows, 8, 16, PAD_TERMS,
+        CHUNK_CAP, 128, pack.k1 + 1.0, out.ctypes.data, info.ctypes.data)
+    assert rc == -batch.t_slots
+    assert (out == 7.0).all() and not info.any()
+    wide = dist.build_full_operands(pack, terms, 8, batch.t_slots, PAD_TERMS)
+    same(wide.ops, python_fused(pack, queries, None, 8, batch.t_slots)[0],
+         "ops")
+
+
+# ---------------------------------------------------------------------------
 # the counter
 # ---------------------------------------------------------------------------
 
@@ -511,9 +651,11 @@ def test_terms_no_row_holds_never_grow_the_table(seeded_np, rows):
 
 def test_threads_resolving_at_once_build_each_column_once(seeded_np):
     """Sixteen threads, more than the cores, build one pack's operands
-    at once with the interpreter switching every microsecond: every term
-    its rows hold gets one column (as many built as there are such
-    terms, forty or more), and every batch is the loops'."""
+    at once with the interpreter switching every microsecond, half of
+    them through the native builder while the table's arrays grow under
+    them: every term its rows hold gets one column (as many built as
+    there are such terms, forty or more), and every batch and every
+    fused operand is the loops'."""
     pack = synthetic_pack(seeded_np, 8, True, True)
     batches = [draw_queries(np.random.default_rng(i), pack, 12)
                + [[f"x{i}.{k}", "t3"] for k in range(8)] for i in range(16)]
@@ -521,11 +663,17 @@ def test_threads_resolving_at_once_build_each_column_once(seeded_np):
                     if any(t in v for v in pack.vocabs)})
     errors = []
 
-    def work(queries):
+    def work(queries, native):
         try:
             for _ in range(3):
-                same_batch(dist.prepare_query_batch(pack, queries),
-                           ref_prepare_query_batch(pack, queries))
+                if native:
+                    # the table grows under the native builder's readers
+                    qs = [[t for t in q if t != "ghost"] for q in queries]
+                    same(native_full(pack, qs, None, 32, 32).ops,
+                         ref_fused(pack, qs, None, 32, 32), "ops")
+                else:
+                    same_batch(dist.prepare_query_batch(pack, queries),
+                               ref_prepare_query_batch(pack, queries))
         except Exception as exc:            # reported after the join
             errors.append(exc)
 
@@ -533,7 +681,8 @@ def test_threads_resolving_at_once_build_each_column_once(seeded_np):
     before = _counts()
     sys.setswitchinterval(1e-6)
     try:
-        threads = [threading.Thread(target=work, args=(qs,)) for qs in batches]
+        threads = [threading.Thread(target=work, args=(qs, i % 2 == 1))
+                   for i, qs in enumerate(batches)]
         for t in threads:
             t.start()
         for t in threads:
@@ -543,7 +692,8 @@ def test_threads_resolving_at_once_build_each_column_once(seeded_np):
     assert not any(t.is_alive() for t in threads)
     assert errors == []
     assert _rise(before)["columns"] == distinct
-    assert len(pack.term_table._columns) == distinct >= 40
+    assert len(pack.term_table._ids) == distinct >= 40
+    assert len(pack.term_table._columns) <= distinct
 
 
 def test_a_new_pack_never_reads_an_older_packs_columns(seeded_np):
