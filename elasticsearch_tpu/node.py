@@ -625,9 +625,9 @@ class Node:
                    "gauge")
             from elasticsearch_tpu.search.tpu_service import (
                 CROSS_CHIP_COUNTS, EXACT_ENTRY_COUNTS, EXACT_PIN_COUNTS,
-                EXACT_RESULT_COUNTS, FULL_ENTRY_COUNTS, HOLD_EXIT_COUNTS,
-                KERNEL_CONFIG, KERNEL_VARIANT_COUNTS, LAUNCH_COUNTS,
-                OPERAND_COUNTS, ROUTE_COUNTS)
+                EXACT_RESULT_COUNTS, FULL_ENTRY_COUNTS, HEADS_COUNTS,
+                HOLD_EXIT_COUNTS, KERNEL_CONFIG, KERNEL_VARIANT_COUNTS,
+                LAUNCH_COUNTS, OPERAND_COUNTS, ROUTE_COUNTS)
             from elasticsearch_tpu.parallel.distributed import (
                 TERM_TABLE_COUNTS)
             yield ("search.tpu.kernel_packed_sort", nl,
@@ -671,6 +671,10 @@ class Node:
             # es_tpu_kernel_operands_total{builder=...}
             for labels, counter in OPERAND_COUNTS.items():
                 yield ("kernel.operands", labels, counter)
+            # the heads route's queries, launches, head terms and head
+            # block bytes: es_tpu_kernel_heads_total{kind=...}
+            for labels, counter in HEADS_COUNTS.items():
+                yield ("kernel.heads", labels, counter)
             # launches on a mesh of several devices, their rows and
             # devices: es_tpu_kernel_cross_chip_total{kind=...}
             for labels, counter in CROSS_CHIP_COUNTS.items():

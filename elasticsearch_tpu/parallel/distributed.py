@@ -227,7 +227,17 @@ class TermTable:
         self._columns: Dict[str, Column] = {}
         self._arrays = ColumnArrays.empty(16, shards)
         self._n = ZERO_TERM + 1
+        self._heads: Optional[Dict[str, int]] = None
         self._lock = threading.Lock()
+
+    def head_index(self, pack: StackedShardPack) -> Dict[str, int]:
+        """The pack's head terms (`head_terms`), each with its row of the
+        pack's head block: marked on first use, so that routing a query
+        costs one dict lookup a term."""
+        with self._lock:
+            if self._heads is None:
+                self._heads = {t: i for i, t in enumerate(head_terms(pack))}
+            return self._heads
 
     def _add(self, pack: StackedShardPack, term: str) -> Tuple[int, Column]:
         """(the term's row, its column): a new row if some shard row
@@ -318,6 +328,72 @@ class TermTable:
             return None
         TERM_TABLE_COUNTS.inc("lookups", n=len(ids))
         return ids, arrays, n
+
+
+# ---------------------------------------------------------------------------
+# head terms: a pack's terms of very high document frequency, scored as one
+# dense block instead of through the sorted merge
+# ---------------------------------------------------------------------------
+#: a head term's dense column costs 4 B a doc; its postings cost some 16 B
+#: each (`pack_bytes_per_posting`): the column costs no more HBM than the
+#: postings where df ≥ docs ÷ HEAD_DF_DIVISOR
+HEAD_DF_DIVISOR = 4
+#: a head term's postings fill more than this many slots of CHUNK_CAP on
+#: some shard row: more than the narrowest rung of the full-postings
+#: ladder holds (`tpu_service.FULL_SLOT_BUCKETS`), so no query with it
+#: shares that rung's sort, whatever its other terms
+HEAD_MIN_SLOTS = 16
+#: the most head terms a pack scores densely, the highest df first
+HEADS_MAX = 32
+#: docs a block maximum covers in the head-only top-k (one lane row)
+HEAD_BLOCK_DOCS = 128
+
+
+def head_terms(pack: StackedShardPack) -> List[str]:
+    """The pack's head terms, highest df first (ties by term): those whose
+    df over the pack's rows is at least a HEAD_DF_DIVISOR-th of its docs
+    and whose postings fill more than HEAD_MIN_SLOTS slots on some shard
+    row. So a pack whose rows hold at most HEAD_MIN_SLOTS · CHUNK_CAP
+    docs (65,536) has no head term. At most HEADS_MAX. A function of the
+    pack alone."""
+    docs = sum(pack.shard_num_docs)
+    if docs == 0:
+        return []
+    out = []
+    for term, dfv in pack.df.items():
+        if HEAD_DF_DIVISOR * dfv < docs:
+            continue
+        longest = 0
+        for vocab, rstart in zip(pack.vocabs, pack.row_starts):
+            r = vocab.get(term)
+            if r is not None:
+                longest = max(longest, int(rstart[r + 1] - rstart[r]))
+        if longest > HEAD_MIN_SLOTS * CHUNK_CAP:
+            out.append(term)
+    out.sort(key=lambda t: (-pack.df[t], t))
+    return out[:HEADS_MAX]
+
+
+def build_head_block(pack: StackedShardPack, terms: Sequence[str]
+                     ) -> np.ndarray:
+    """float32[shard rows, h_pad, width]: row h of shard row s holds term
+    h's BM25 impact at boost 1 for every doc of the row, the product a
+    lane of the sorted merge computes (the term's weight at boost 1, as
+    its `TermTable` column gives it, times the doc's eager impact, both
+    float32), 0 where the doc lacks the term or is deleted. h_pad is the
+    terms padded to 8, 16 or 32, width the longest row's docs padded to
+    HEAD_BLOCK_DOCS: one shape a pack."""
+    h_pad = _shape_bucket(len(terms), 8)
+    width = _pad_to(max(pack.shard_num_docs, default=0), HEAD_BLOCK_DOCS)
+    block = np.zeros((pack.num_shards, h_pad, width), dtype=np.float32)
+    (columns,) = pack.term_table.resolve(pack, [list(terms)])
+    for h, col in enumerate(columns):
+        for si, (st, ln, w, held, _idf) in enumerate(col):
+            if held and ln:
+                docs = pack.flat_docs[si, st:st + ln]
+                block[si, h, docs] = (np.float32(w)
+                                      * pack.flat_impact[si, st:st + ln])
+    return block
 
 
 def build_stacked_pack(segments: Sequence[Segment], field: str,
@@ -1159,6 +1235,70 @@ def build_full_operands(pack: StackedShardPack, terms: LaunchTerms,
     return FullOperands(out, *map(int, info))
 
 
+def _row_groups(starts, lengths, weights, max_len: int, p_pad: int):
+    """A device's slots [S_l, B, T] in groups of shard rows that the merge
+    fuses into one sort pool a query (sort cost is row-count-bound on a
+    TPU, so fusing rows pays; G rows a group by `fuse_group_rows`, so that
+    one group's lanes are live at a time) → (G, (starts made absolute,
+    lengths, weights, each slot's row), [n_groups, B, G·T] each)."""
+    s_l, b, t = starts.shape
+    row_of_slot = jnp.broadcast_to(
+        jnp.arange(s_l, dtype=jnp.int32)[:, None, None], starts.shape)
+    starts_abs = starts + row_of_slot * p_pad
+    g = min(fuse_group_rows(b, t, max_len), s_l)
+    n_groups = (s_l + g - 1) // g
+    pad_rows = n_groups * g - s_l
+
+    def grouped(a):
+        if pad_rows:
+            a = jnp.concatenate(
+                [a, jnp.zeros((pad_rows,) + a.shape[1:], dtype=a.dtype)],
+                axis=0)
+        return jnp.transpose(
+            a.reshape(n_groups, g, b, t), (0, 2, 1, 3)
+        ).reshape(n_groups, b, g * t)
+
+    return g, tuple(grouped(a) for a in (starts_abs, lengths, weights,
+                                         row_of_slot))
+
+
+def _lane_streams(flat_docs, flat_imps, starts, lengths, weights,
+                  max_len: int):
+    """The merge's lanes of slots [B, T] over flat postings: each slot's
+    `max_len` doc ids and weighted impacts, and which of its lanes hold a
+    posting (impact 0 on the others) → three [B, T, max_len] arrays."""
+    idx = jnp.arange(max_len, dtype=jnp.int32)
+
+    def slice_one(s):
+        return (jax.lax.dynamic_slice(flat_docs, (s,), (max_len,)),
+                jax.lax.dynamic_slice(flat_imps, (s,), (max_len,)))
+
+    with jax.named_scope("gather_streams"):
+        docs, imps = jax.vmap(jax.vmap(slice_one))(starts)
+    valid = idx[None, None, :] < lengths[:, :, None]
+    return docs, jnp.where(valid, weights[:, :, None] * imps, 0.0), valid
+
+
+def _merge_sort(docs, imp, valid, rows, d_pad: int):
+    """The lanes sorted by their gid key, row·(d_pad+1)+doc (distinct
+    docs across rows never merge; an empty lane takes the row's sentinel
+    doc d_pad and impact 0) → (keys, impacts), [B, T·max_len] each."""
+    b = docs.shape[0]
+    gid = rows[:, :, None] * (d_pad + 1) + jnp.where(valid, docs, d_pad)
+    with jax.named_scope("merge_sort"):
+        return jax.lax.sort([gid.reshape(b, -1), imp.reshape(b, -1)],
+                            num_keys=1)
+
+
+def _run_totals(sk, sv, t_window: int):
+    """Each run of equal keys' sum at its last lane, and which lanes end
+    a run with a sum above 0 (a doc that matched)."""
+    total = sparse.segmented_run_sum(sk, sv, t_window)
+    run_end = jnp.concatenate(
+        [sk[:, :-1] != sk[:, 1:], jnp.ones((sk.shape[0], 1), bool)], axis=1)
+    return total, run_end & (total > 0.0)
+
+
 @lru_cache(maxsize=32)
 def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
                        c_cand: int, k_out: int, t_window: int,
@@ -1234,28 +1374,9 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
         # at 16 rows × B=128 OOM'd 24G of 16G HBM at MS-MARCO scale.
         flat_imp_docs = fd_imp.reshape(-1)
         flat_imp_imps = fi_imp.reshape(-1)
-        row_of_slot = jnp.broadcast_to(
-            jnp.arange(s_l, dtype=jnp.int32)[:, None, None],
-            starts.shape)                                   # [S_l, B, T]
-        starts_abs = starts + row_of_slot * p_pad
-        g = min(fuse_group_rows(b, t, max_len), s_l)
-        n_groups = (s_l + g - 1) // g
-        pad_rows = n_groups * g - s_l
-
-        def grouped(a):  # [S_l, B, T] → [n_groups, B, G*T]
-            if pad_rows:
-                a = jnp.concatenate(
-                    [a, jnp.zeros((pad_rows,) + a.shape[1:],
-                                  dtype=a.dtype)], axis=0)
-            return jnp.transpose(
-                a.reshape(n_groups, g, b, t), (0, 2, 1, 3)
-            ).reshape(n_groups, b, g * t)
-
-        g_starts = grouped(starts_abs)
-        g_lengths = grouped(lengths)
-        g_weights = grouped(weights)
-        g_rows = grouped(row_of_slot)
-        idx = jnp.arange(max_len, dtype=jnp.int32)
+        g, (g_starts, g_lengths, g_weights, g_rows) = _row_groups(
+            starts, lengths, weights, max_len, p_pad)
+        n_groups = g_starts.shape[0]
         width = g * t * max_len
         k_dev = min(c_local, width)
         # single-key sort applies only when a group's relative gid range
@@ -1265,18 +1386,11 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
         use_pack = (pack_keys and variant == "packed" and with_rescore
                     and g * (d_pad + 1) <= sparse.PACKED_DOC_LIMIT)
 
-        def slice_one(s):
-            return (jax.lax.dynamic_slice(flat_imp_docs, (s,), (max_len,)),
-                    jax.lax.dynamic_slice(flat_imp_imps, (s,), (max_len,)))
-
         def one_group(opnds):
             f_starts, f_lengths, f_weights, f_rows = opnds
-            with jax.named_scope("gather_streams"):
-                docs, imps = jax.vmap(jax.vmap(slice_one))(f_starts)
-            valid = idx[None, None, :] < f_lengths[:, :, None]
-            # gid key: row·(d_pad+1)+doc — distinct docs across rows
-            # never merge; padded lanes carry impact 0, drop via total>0
-            imp = jnp.where(valid, f_weights[:, :, None] * imps, 0.0)
+            docs, imp, valid = _lane_streams(
+                flat_imp_docs, flat_imp_imps, f_starts, f_lengths,
+                f_weights, max_len)
             if use_pack:
                 # group-relative gid in the high 16 bits, impact code in
                 # the low 16: ONE u32 sort operand. Padded rows (zeros
@@ -1296,17 +1410,8 @@ def make_pruned_search(mesh: Mesh, *, max_len: int, d_pad: int, p_pad: int,
                       + row0 * (d_pad + 1))
                 sv = sparse.decode_code16(skp & 0xFFFF)
             else:
-                gid = (f_rows[:, :, None] * (d_pad + 1)
-                       + jnp.where(valid, docs, d_pad))
-                with jax.named_scope("merge_sort"):
-                    sk, sv = jax.lax.sort(
-                        [gid.reshape(b, width), imp.reshape(b, width)],
-                        num_keys=1)
-            total = sparse.segmented_run_sum(sk, sv, t_window)
-            run_end = jnp.concatenate(
-                [sk[:, :-1] != sk[:, 1:], jnp.ones((b, 1), bool)],
-                axis=1)
-            ok = run_end & (total > 0.0)
+                sk, sv = _merge_sort(docs, imp, valid, f_rows, d_pad)
+            total, ok = _run_totals(sk, sv, t_window)
             score = jnp.where(ok, total, NEG_INF)
             totals_g = jnp.sum(ok, axis=1).astype(jnp.int32)
             # when the single-key sort doesn't apply (gid range overflows
@@ -1465,6 +1570,165 @@ def compile_pruned_program(fn, mesh: Mesh, arrays: Sequence[Any], rows: int,
     ops = jax.ShapeDtypeStruct((arrays[0].shape[0], rows, width), jnp.float32,
                                sharding=sbt)
     return _compiled(fn, structs + (ops,))
+
+
+def heads_program_name(rows: int, slots: int) -> str:
+    """The heads program under its static shape (batch rows, tail slots a
+    row): `jit_<this>` on a trace's `XLA Modules` line."""
+    return f"heads_b{rows}_s{slots}"
+
+
+@lru_cache(maxsize=32)
+def make_heads_search(mesh: Mesh, *, d_pad: int, p_pad: int, k_out: int,
+                      t_window: int, name: str):
+    """The heads step, ONE fused launch: OR queries that hold some of the
+    pack's head terms (`head_terms`), exact. Its operands are the pack's
+    impact-sorted postings, its head block [S, H, W] (`build_head_block`,
+    sharded like the postings), the tail terms' fused operand [S, B,
+    3·T + 1] (`build_full_operands` with no rescore terms) and each
+    query's weight of every head row at boost 1, [B, H].
+
+      head scores  [B, H] @ [H, W] for every shard row on the matrix
+                   unit at HIGHEST precision (a float32 product; at the
+                   default precision it is rounded to bfloat16);
+      tail         the tail terms' full postings through the sorted merge
+                   of the full-postings path, each matched doc's head
+                   score added by a gather from the dense row;
+      top-k        the maxima of HEAD_BLOCK_DOCS-doc blocks of the dense
+                   rows, the k_out best blocks, their docs' top k_out:
+                   every doc of the head-only top k_out lies in one of
+                   those blocks (fewer than k_out blocks hold a doc above
+                   it), and a doc of the final top k that holds no tail
+                   term is in the head-only top k (no doc above it in the
+                   head-only ranking scores less in the final one). With
+                   the tail's top k_out, a doc in both keeps its larger
+                   copy, the one with its tail terms: exact.
+
+    `hits.total` is exact too: the docs whose head score is above 0 and
+    the tail docs whose head score is 0 (a matching doc never scores 0).
+    The output is the full-postings path's [B, 2k+3] with beta 0."""
+    hb = HEAD_BLOCK_DOCS
+
+    def body(fd_imp, fi_imp, block, ops, w):
+        t = (ops.shape[2] - 1) // 3
+
+        def bc(a):
+            return jax.lax.bitcast_convert_type(a, jnp.int32)
+
+        starts, lengths = bc(ops[:, :, 0:t]), bc(ops[:, :, t:2 * t])
+        weights = ops[:, :, 2 * t:3 * t]
+        s_l, b = starts.shape[0], starts.shape[1]
+        width = block.shape[2]
+        my = jax.lax.axis_index(SHARD_AXIS)
+        with jax.named_scope("heads_matmul"):
+            dense = jnp.einsum("bh,shd->bsd", w, block,
+                               precision=jax.lax.Precision.HIGHEST)
+        flat_dense = dense.reshape(b, s_l * width)
+        head_docs = jnp.sum(flat_dense > 0.0, axis=1).astype(jnp.int32)
+
+        # ---- the tail: the full-postings merge of its terms' slots, in
+        # groups of rows as `make_pruned_search` runs it
+        g, opnds = _row_groups(starts, lengths, weights, CHUNK_CAP, p_pad)
+        k_tail = min(k_out, g * t * CHUNK_CAP)
+        flat_imp_docs, flat_imp_imps = fd_imp.reshape(-1), fi_imp.reshape(-1)
+        dense_flat = dense.reshape(-1)
+        q_base = jnp.arange(b, dtype=jnp.int32)[:, None] * s_l
+
+        def one_group(opnds):
+            f_starts, f_lengths, f_weights, f_rows = opnds
+            docs, imp, valid = _lane_streams(
+                flat_imp_docs, flat_imp_imps, f_starts, f_lengths,
+                f_weights, CHUNK_CAP)
+            sk, sv = _merge_sort(docs, imp, valid, f_rows, d_pad)
+            total, ok = _run_totals(sk, sv, t_window)
+            with jax.named_scope("heads_merge"):
+                row, doc = sk // (d_pad + 1), sk % (d_pad + 1)
+                at = jnp.where(doc < width, (q_base + row) * width + doc,
+                               dense_flat.shape[0])
+                head = jnp.take(dense_flat, at, mode="fill", fill_value=0.0)
+                extra = jnp.sum(ok & (head == 0.0), axis=1).astype(jnp.int32)
+                vals, pos = sparse.hierarchical_top_k(
+                    jnp.where(ok, total + head, NEG_INF), k_tail)
+            return vals, jnp.take_along_axis(sk, pos, axis=1), extra
+
+        if opnds[0].shape[0] == 1:
+            vals_t, gid_t, extra = one_group(tuple(a[0] for a in opnds))
+        else:
+            vals_gs, gid_gs, extra_gs = jax.lax.map(one_group, opnds)
+            vals_t = jnp.transpose(vals_gs, (1, 0, 2)).reshape(b, -1)
+            gid_t = jnp.transpose(gid_gs, (1, 0, 2)).reshape(b, -1)
+            extra = jnp.sum(extra_gs, axis=0)
+
+        # ---- the head-only top k_out, through the blocks' maxima
+        with jax.named_scope("heads_blockmax"):
+            n_blocks = s_l * width // hb
+            blocks = flat_dense.reshape(b, n_blocks, hb)
+            k_blocks = min(k_out, n_blocks)
+            _, best = jax.lax.top_k(jnp.max(blocks, axis=2), k_blocks)
+            cand = jnp.take_along_axis(blocks, best[:, :, None], axis=1)
+            vals_h, pos = sparse.hierarchical_top_k(
+                cand.reshape(b, k_blocks * hb), min(k_out, k_blocks * hb))
+            at = jnp.take_along_axis(best, pos // hb, axis=1) * hb + pos % hb
+            gid_h = (at // width) * (d_pad + 1) + at % width
+            vals_h = jnp.where(vals_h > 0.0, vals_h, NEG_INF)
+
+        # ---- one pool a device: a doc in both lists keeps its larger copy
+        with jax.named_scope("heads_merge"):
+            pool_g = jnp.concatenate([gid_h, gid_t], axis=1)
+            pool_v = jnp.concatenate([vals_h, vals_t], axis=1)
+            neg = jnp.where(pool_v > NEG_INF, -pool_v, jnp.inf)
+            sg, sn = jax.lax.sort([pool_g, neg], num_keys=2)
+            again = jnp.concatenate(
+                [jnp.zeros((b, 1), bool), sg[:, 1:] == sg[:, :-1]], axis=1)
+            vals_b = jnp.where(again | jnp.isinf(sn), NEG_INF, -sn)
+            gids_b = (sg.astype(jnp.int64)
+                      + (my * s_l).astype(jnp.int64) * (d_pad + 1))
+            gids_b = jnp.where(vals_b > NEG_INF, gids_b, 0)
+
+        with jax.named_scope("cross_chip_merge"):
+            all_vals = jax.lax.all_gather(vals_b, SHARD_AXIS, axis=1,
+                                          tiled=True)
+            all_gids = jax.lax.all_gather(gids_b, SHARD_AXIS, axis=1,
+                                          tiled=True)
+            totals = jax.lax.psum(head_docs + extra, SHARD_AXIS)
+            c = min(k_out, all_vals.shape[1])
+            cand_vals, pos = sparse.hierarchical_top_k(all_vals, c)
+            cand_gids = jnp.take_along_axis(all_gids, pos, axis=1)
+
+        # final order: (-score, gid), the full-postings path's
+        neg = jnp.where(cand_vals > NEG_INF, -cand_vals, jnp.inf)
+        sk, sg = jax.lax.sort([neg, cand_gids], num_keys=2)
+        out_vals = jnp.where(jnp.isinf(sk), NEG_INF, -sk)
+        with jax.named_scope("pack_out"):
+            zero = jnp.zeros((b, 1), jnp.float32)
+            return jnp.concatenate(
+                [out_vals, jax.lax.bitcast_convert_type(
+                    sg.astype(jnp.int32), jnp.float32),
+                 totals[:, None].astype(jnp.float32), zero, zero], axis=1)
+
+    spec_post = P(SHARD_AXIS, None)
+    mapped = shard_map(
+        body, mesh=mesh,
+        in_specs=(spec_post, spec_post, P(SHARD_AXIS, None, None),
+                  P(SHARD_AXIS, DATA_AXIS, None), P(DATA_AXIS, None)),
+        out_specs=P(DATA_AXIS, None))
+    return jax.jit(_named(mapped, name))
+
+
+def compile_heads_program(fn, mesh: Mesh, arrays: Sequence[Any], rows: int,
+                          slots: int, h_pad: int):
+    """`fn` (of `make_heads_search`) compiled ahead of time for the
+    resident `arrays` (impact-sorted postings, head block) and a launch of
+    `rows` queries at `slots` tail slots: the executable, kept for the
+    process. Nothing runs on the device."""
+    structs = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+                    for a in arrays)
+    ops = jax.ShapeDtypeStruct(
+        (arrays[0].shape[0], rows, 3 * slots + 1), jnp.float32,
+        sharding=NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None)))
+    w = jax.ShapeDtypeStruct((rows, h_pad), jnp.float32,
+                             sharding=NamedSharding(mesh, P(DATA_AXIS, None)))
+    return _compiled(fn, structs + (ops, w))
 
 
 def unpack_pruned(packed: np.ndarray, k_keep: Optional[int] = None):

@@ -413,6 +413,23 @@ class ResidentPack:
     # number of terms → _widest_slots result, on the same terms
     widest_slots_memo: Dict[int, int] = dataclasses.field(
         default_factory=dict)
+    # terms-tuple → `_heads_plan`'s result, memoised as `slots_memo` is
+    heads_memo: Dict[Tuple[str, ...], Optional[Tuple[int, Tuple[str, ...]]]] = \
+        dataclasses.field(default_factory=dict)
+    # the head block on the device (`_head_block`): built, under
+    # `heads_lock`, by the first launch on the heads route; None on a pack
+    # that never served a query holding a head term
+    heads_block: Optional["HeadBlock"] = None
+    heads_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
+    # (mesh, k bucket, max_batch) → the heads programs' executables, as
+    # futures, by (tail slots, rows): `_ready_heads_programs`
+    heads_ready: Dict[Tuple, Dict[Tuple[int, int], Future]] = \
+        dataclasses.field(default_factory=dict)
+    # the cache that placed the pack and charges what it adds to the
+    # `hbm` breaker (`IndexPackCache.charge`)
+    cache: Optional["IndexPackCache"] = dataclasses.field(
+        default=None, repr=False, compare=False)
     # (mesh, k bucket, variant, max_batch) → the executables of
     # `full_program_set`, by (slots, rows): `_ready_full_programs`
     full_ready: Dict[Tuple, Dict[Tuple[int, int], Any]] = dataclasses.field(
@@ -1207,11 +1224,35 @@ class IndexPackCache:
             id_json=JsonLiterals.build(pack.shard_doc_ids),
             row_segments=row_segments,
             comp_streams=streams, hbm_detail=hbm_detail,
-            group_id=self.group_id, mesh=mesh)
+            group_id=self.group_id, mesh=mesh, cache=self)
         # the pack, its id table and resolution tables are here to stay:
         # out of the collector's sight, under traffic too (microseconds)
         tracing.HEAP.freeze()
         return resident
+
+    def charge(self, resident: ResidentPack, nbytes: int,
+               label: str = "") -> None:
+        """Charge `nbytes` more device memory of `resident` (its head
+        block) to the `hbm` breaker, released with the pack's own bytes
+        on eviction; a negative `nbytes` gives it back. Raises where the
+        pack is no longer resident here (its charge is already released)
+        or the breaker trips."""
+        with self._lock:
+            key = next((key for key, entry in self._cache.items()
+                        if entry is resident), None)
+            if key is None and not any(
+                    p is resident for lst in self._deltas.values()
+                    for p in lst):
+                raise RuntimeError(f"{label}: the pack is not resident")
+            if self._breaker is not None:
+                if nbytes > 0:
+                    self._breaker.add_estimate_bytes_and_maybe_break(
+                        nbytes, label=label)
+                else:
+                    self._breaker.release(-nbytes)
+            resident.hbm_bytes += nbytes
+            if key is not None:
+                self._last_bytes[key] = int(resident.hbm_bytes)
 
     def invalidate(self, index_name: str) -> None:
         evicted = []
@@ -1877,6 +1918,19 @@ PREFIX_CAP3 = 65536             # escalation prefix
 PRUNE_MAX_K = 1000
 PRUNE_MAX_TERMS = 8          # > 8 query terms → exact path
 _PRUNE_WINDOW = 8
+#: the most tail terms (the terms that are not the pack's head terms) of a
+#: query on the heads route: the heads programs' run-sum window
+HEADS_MAX_TAIL_TERMS = 16
+#: the heads route's ladder for the tail: rung → the row buckets it
+#: launches at. The full-postings ladder's narrowest rung at its own
+#: buckets, and its widest at eight rows, which every tail fits: a taller
+#: bucket there pays, by `_split_full_train`'s model, only past 52 queries
+#: of that rung in one train (a chunk of eight costs 8·128·2 + 512
+#: slot-rows on two shard rows, a 64-row launch 64·128·2 + 512), and the
+#: 32-slot rung's 8 rows would add a program for a twentieth of the
+#: queries. Three programs a pack, so that the warm-up that serves a query
+#: of each kind has launched every one of them
+HEADS_ROW_BUCKETS = {16: (64, 128), 128: (8,)}
 
 # device-kernel variant selection. packed_sort=True routes launches
 # through the single-packed-key sort + hierarchical top-k kernels;
@@ -1914,7 +1968,7 @@ LAUNCH_COUNTS = LabeledCounters("path")
 #: failed twice (counted under its pruned route before)
 #: → es_tpu_kernel_route_total
 ROUTES = ("pruned_full_s16", "pruned_full_s32", "pruned_full_s128",
-          "pruned_hot",
+          "pruned_hot", "heads",
           "exact_terms", "exact_min_count", "exact_k", "exact_no_impacts",
           "exact_escalated")
 ROUTE_COUNTS = LabeledCounters("route")
@@ -1947,6 +2001,11 @@ OPERAND_COUNTS = LabeledCounters("builder")
 #: needs to reckon the bytes of the cross-chip top-k merge. All three
 #: stay 0 on one device → es_tpu_kernel_cross_chip_total
 CROSS_CHIP_COUNTS = LabeledCounters("kind")
+#: the heads route (`_launch_heads`): its queries, its launches, the
+#: head terms those queries scored through the head block (one count a
+#: query term) and the bytes of the head blocks built
+#: → es_tpu_kernel_heads_total
+HEADS_COUNTS = LabeledCounters("kind")
 #: trains by the reason their hold ended (`_PackQueue._hold`): one count
 #: a train taken → es_tpu_batcher_hold_exit_total
 HOLD_EXITS = ("full", "backlog_low", "idle_window")
@@ -1966,6 +2025,8 @@ for _label in ("rows", "rows_under", "trains", "launches"):
     EXACT_PIN_COUNTS.child(_label)
 for _label in ("queries", "empty"):
     EXACT_RESULT_COUNTS.child(_label)
+for _label in ("queries", "launches", "terms", "block_bytes"):
+    HEADS_COUNTS.child(_label)
 
 
 def _count_cross_chip(mesh, rows: int) -> None:
@@ -2040,6 +2101,10 @@ def _serving_buckets(max_batch: int = 128) -> List[int]:
 
 
 def _slots_needed(resident: ResidentPack, flat: FlatQuery) -> int:
+    return _slots_of(resident, tuple(flat.terms))
+
+
+def _slots_of(resident: ResidentPack, terms: Tuple[str, ...]) -> int:
     """Max over shard rows of Σ_terms ceil(row_len/CHUNK): the slot
     count a FULL-postings sorted-merge of this query needs. Terms
     MISSING from a row still cost one (zero-length) slot — plan_slots
@@ -2049,8 +2114,7 @@ def _slots_needed(resident: ResidentPack, flat: FlatQuery) -> int:
     Memoized per pack by terms tuple: the scan walks EVERY shard row's
     vocab, which at many segments is the costliest host step per query
     — and repeated query shapes hit the same terms constantly."""
-    memo_key = tuple(flat.terms)
-    cached = resident.slots_memo.get(memo_key)
+    cached = resident.slots_memo.get(terms)
     if cached is not None:
         return cached
     pack = resident.pack
@@ -2059,7 +2123,7 @@ def _slots_needed(resident: ResidentPack, flat: FlatQuery) -> int:
         vocab = pack.vocabs[si]
         rstart = pack.row_starts[si]
         n = 0
-        for t in flat.terms:
+        for t in terms:
             r = vocab.get(t)
             if r is None:
                 n += 1  # zero-length slot
@@ -2069,7 +2133,7 @@ def _slots_needed(resident: ResidentPack, flat: FlatQuery) -> int:
         worst = max(worst, n)
     result = max(worst, 1)
     if len(resident.slots_memo) < 65536:  # bound pathological cardinality
-        resident.slots_memo[memo_key] = result
+        resident.slots_memo[terms] = result
     return result
 
 
@@ -2217,6 +2281,174 @@ def _ready_full_programs(resident: ResidentPack, mesh, k: int,
                 ((p.slots, p.rows) for p in programs),
                 pool.map(compile_one, programs)))
         return ready
+
+
+# ---------------------------------------------------------------------------
+# the heads route: a pack's head terms scored as one dense block
+# ---------------------------------------------------------------------------
+# A term whose postings span a good share of a shard row's docs costs the
+# sorted merge more lanes than the row has docs (a stop-word of MS MARCO:
+# 134 slots of 4,096 on a 552,960-doc row). Such terms, the pack's head
+# terms (`dist.head_terms`, read from the pack), are scored by a matmul
+# over a dense block of their impacts, and the query's other terms, its
+# tail, go through the full-postings merge at the rung of the ladder that
+# holds them (`dist.make_heads_search`). A query of min_count 1 that holds
+# a head term takes this route whatever its number of terms; a pack with
+# no head term, and a query without one, route as before.
+
+@dataclasses.dataclass(frozen=True)
+class HeadBlock:
+    """A pack's head block on the device (`dist.build_head_block`)."""
+
+    terms: Tuple[str, ...]
+    h_pad: int        # block rows: the head terms, padded
+    width: int        # docs a shard row
+    array: Any        # float32[shard rows, h_pad, width], sharded as the pack
+    nbytes: int
+
+
+@dataclasses.dataclass(frozen=True)
+class HeadsProgram:
+    """One compiled signature of the heads program on a pack."""
+
+    rows: int
+    slots: int
+    k_out: int
+
+    @property
+    def label(self) -> str:
+        return dist.heads_program_name(self.rows, self.slots)
+
+
+def heads_program_set(resident: ResidentPack, k: int,
+                      max_batch: int = 128) -> List[HeadsProgram]:
+    """Every heads program `launch_flat_batch` can dispatch on this pack
+    at `k` for trains of up to `max_batch` queries: HEADS_ROW_BUCKETS'
+    rungs, for the tail, at their row buckets. Empty where the pack has
+    no head term or no full-postings path."""
+    pack = resident.pack
+    if (resident.imp_device_arrays is None
+            or not pack.term_table.head_index(pack)):
+        return []
+    reach = _serving_buckets(max_batch)
+    return [HeadsProgram(rows, slots, _pruned_k_out(k))
+            for slots, buckets in sorted(HEADS_ROW_BUCKETS.items())
+            for rows in buckets if rows in reach]
+
+
+def _heads_plan(resident: ResidentPack, flat: FlatQuery
+                ) -> Optional[Tuple[int, Tuple[str, ...]]]:
+    """(the tail's rung, the tail's terms) where `flat` takes the heads
+    route on this pack: it holds a head term, its tail has at most
+    HEADS_MAX_TAIL_TERMS terms and fits a rung of HEADS_ROW_BUCKETS (an
+    empty tail fits the narrowest); else None. Memoised with the pack."""
+    memo_key = tuple(flat.terms)
+    if memo_key in resident.heads_memo:
+        return resident.heads_memo[memo_key]
+    pack = resident.pack
+    heads = pack.term_table.head_index(pack)
+    plan = None
+    if any(t in heads for t in flat.terms):
+        tail = tuple(t for t in flat.terms if t not in heads)
+        need = _slots_of(resident, tail)
+        rung = next((b for b in sorted(HEADS_ROW_BUCKETS) if need <= b), None)
+        if rung is not None and len(tail) <= HEADS_MAX_TAIL_TERMS:
+            plan = (rung, tail)
+    if len(resident.heads_memo) < 65536:
+        resident.heads_memo[memo_key] = plan
+    return plan
+
+
+def _head_block(resident: ResidentPack, mesh,
+                stages: Optional[StageTimes]) -> HeadBlock:
+    """The pack's head block, built at the first call (the stage
+    `heads_block` of `stages`, under the pack's `heads_lock`) from the
+    pack's host postings and placed as its postings are; its bytes are
+    charged to the `hbm` breaker with the pack's (`IndexPackCache.charge`)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    with resident.heads_lock:
+        if resident.heads_block is None:
+            pack = resident.pack
+            terms = tuple(pack.term_table.head_index(pack))
+            with tracing.stage(stages, "heads_block", annotate=False,
+                               cpu=False):
+                host = dist.build_head_block(pack, terms)
+                label = f"heads[{pack.field}]"
+                if resident.cache is not None:
+                    resident.cache.charge(resident, host.nbytes, label)
+                try:
+                    array = jax.device_put(host, NamedSharding(
+                        mesh, P(SHARD_AXIS, None, None)))
+                except Exception:
+                    if resident.cache is not None:
+                        resident.cache.charge(resident, -host.nbytes, label)
+                    raise
+            _, h_pad, width = host.shape
+            resident.heads_block = HeadBlock(terms, h_pad, width, array,
+                                             host.nbytes)
+            resident.hbm_detail = {**resident.hbm_detail, "heads_block": {
+                "terms": len(terms), "rows": h_pad,
+                "shard_rows": pack.num_shards, "docs_per_row": width,
+                "bytes": host.nbytes}}
+            HEADS_COUNTS.inc("block_bytes", n=host.nbytes)
+        return resident.heads_block
+
+
+#: heads programs compiled at once after the first (`_ready_heads_programs`)
+HEADS_READY_WORKERS = 2
+
+
+def _ready_heads_programs(resident: ResidentPack, mesh, k: int,
+                          max_batch: int, first: HeadsProgram
+                          ) -> Dict[Tuple[int, int], Future]:
+    """(tail slots, rows) → the future executable of each member of
+    `heads_program_set`: all submitted at the first call for (pack, mesh,
+    k bucket), which comes with the head block, and compiled ahead of
+    time on threads that are not the launch thread: `first` (the calling
+    launch's) alone, then the others, HEADS_READY_WORKERS at a time. A
+    launch waits for its own program alone, so that the first one waits
+    for one compile and not for the set, well inside the batch timeout.
+    Remembered with the pack."""
+    key = (mesh, _pruned_k_out(k), max_batch)
+    with _FULL_READY_LOCK:
+        ready = resident.heads_ready.get(key)
+        if ready is not None:
+            return ready
+        block = resident.heads_block
+        arrays = tuple(resident.imp_device_arrays) + (block.array,)
+
+        def compile_one(program: HeadsProgram, after: Optional[Future]):
+            if after is not None:
+                after.result()
+            return dist.compile_heads_program(
+                _make_heads_search(resident, mesh, program), mesh, arrays,
+                program.rows, program.slots, block.h_pad)
+
+        def log_failure(fut: Future) -> None:
+            if fut.exception() is not None:
+                logger.warning("heads program compile failed: %s",
+                               fut.exception())
+
+        pool = ThreadPoolExecutor(max_workers=HEADS_READY_WORKERS,
+                                  thread_name_prefix="tpu-heads-ready")
+        lead = pool.submit(compile_one, first, None)
+        ready = resident.heads_ready[key] = {
+            (p.slots, p.rows): (lead if p == first
+                                else pool.submit(compile_one, p, lead))
+            for p in heads_program_set(resident, k, max_batch)}
+        for fut in ready.values():
+            fut.add_done_callback(log_failure)
+        pool.shutdown(wait=False)
+        return ready
+
+
+def _make_heads_search(resident: ResidentPack, mesh, program: HeadsProgram):
+    pack = resident.pack
+    return dist.make_heads_search(
+        mesh, d_pad=pack.d_pad, p_pad=pack.p_pad, k_out=program.k_out,
+        t_window=HEADS_MAX_TAIL_TERMS, name=program.label)
 
 
 # ---------------------------------------------------------------------------
@@ -2430,7 +2662,17 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
     with tracing.stage(stages, "prep.route"):
         pruned_idx: List[int] = []
         exact_idx: List[int] = []
+        heads_groups: Dict[int, List[int]] = {b: []
+                                              for b in HEADS_ROW_BUCKETS}
+        tails: Dict[int, Tuple[str, ...]] = {}
         for i, f in enumerate(flats):
+            plan = (_heads_plan(resident, f)
+                    if can_prune and f.min_count == 1 and k <= PRUNE_MAX_K
+                    else None)
+            if plan is not None:
+                heads_groups[plan[0]].append(i)
+                tails[i] = plan[1]
+                continue
             reason = _exact_reason(f, k, can_prune)
             if reason is None:
                 pruned_idx.append(i)
@@ -2451,6 +2693,9 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
         shard_rows = max(1,
                          resident.pack.num_shards // mesh.shape[SHARD_AXIS])
         full_split = _split_full_train(full_groups, shard_rows)
+        heads_split = (_split_full_train(heads_groups, shard_rows,
+                                         HEADS_ROW_BUCKETS)
+                       if tails else [])
         exact_split = (_split_exact_train(resident, flats, exact_idx,
                                           shard_rows, max_batch)
                        if exact_idx else [])
@@ -2460,6 +2705,13 @@ def launch_flat_batch(resident: ResidentPack, flats: Sequence[FlatQuery],
         full_launches.append((idxs, _launch_pruned(
             resident, [flats[i] for i in idxs], k, mesh,
             stages=stages, full_slots=b, max_batch=max_batch)))
+    # the heads launches answer as the full-postings ones do (exact, the
+    # same output), so they finish with them
+    for b, idxs in heads_split:
+        ROUTE_COUNTS.inc("heads", n=len(idxs))
+        full_launches.append((idxs, _launch_heads(
+            resident, [flats[i] for i in idxs], [tails[i] for i in idxs],
+            k, mesh, b, stages=stages, max_batch=max_batch)))
     st: Dict[str, Any] = {"resident": resident, "flats": flats, "k": k,
                           "mesh": mesh, "stages": stages,
                           "full_launches": full_launches,
@@ -2719,30 +2971,26 @@ def run_exact_program(resident: ResidentPack, program: ExactProgram,
 
 
 def _native_full_operands(pack: dist.StackedShardPack,
-                          flats: Sequence[FlatQuery], rows: int,
-                          slots: int, stages: Optional[StageTimes]
+                          queries: Sequence[Sequence[str]],
+                          boosts: Sequence[float], rows: int, slots: int,
+                          pad_terms: int, stages: Optional[StageTimes]
                           ) -> Optional[dist.FullOperands]:
-    """A full-path launch's fused operand from one native call
-    (`dist.build_full_operands`), counted `operands.native`: the terms
-    resolved (`prep.query_batch`), then the slot plan, the term ranges
-    and the array in one C call that keeps the interpreter lock, far
-    shorter than the wait to take it back (`prep.term_ranges`). None
-    where the library did not build, a term's column is not in the
-    table, or the plan needs more than `slots`: the Python builders then
-    build the launch's operand as before."""
+    """A full-postings launch's fused operand from one native call
+    (`dist.build_full_operands`): the terms resolved
+    (`prep.query_batch`), then the slot plan, the term ranges and the
+    array in one C call that keeps the interpreter lock, far shorter than
+    the wait to take it back (`prep.term_ranges`). None where the library
+    did not build, a term's column is not in the table, or the plan needs
+    more than `slots`: the Python builders then build the launch's
+    operand as before."""
     if dist.native_operand_builder() is None:
         return None
     with tracing.stage(stages, "prep.query_batch"):
-        terms = dist.resolve_launch_terms(pack, [f.terms for f in flats],
-                                          [f.boost for f in flats])
+        terms = dist.resolve_launch_terms(pack, queries, boosts)
     if terms is None:
         return None
     with tracing.stage(stages, "prep.term_ranges"):
-        full = dist.build_full_operands(pack, terms, rows, slots,
-                                        PRUNE_MAX_TERMS)
-    if full is not None:
-        OPERAND_COUNTS.inc("native")
-    return full
+        return dist.build_full_operands(pack, terms, rows, slots, pad_terms)
 
 
 def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
@@ -2785,9 +3033,12 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
     # ranges and the one array that carries them all (`prep.term_ranges`),
     # each inside `batcher.prep`: on the full path from one native call
     # where it can, else by the Python builders
-    full = (_native_full_operands(pack, flats, b_bucket, full_slots, stages)
+    full = (_native_full_operands(pack, [f.terms for f in flats],
+                                  [f.boost for f in flats], b_bucket,
+                                  full_slots, PRUNE_MAX_TERMS, stages)
             if full_slots is not None else None)
     if full is not None:
+        OPERAND_COUNTS.inc("native")
         ops = full.ops
     else:
         with tracing.stage(stages, "prep.query_batch"):
@@ -2874,6 +3125,85 @@ def _launch_pruned(resident: ResidentPack, flats: Sequence[FlatQuery],
         stages.add("batch_put", t_call - t_put)
     return {"resident": resident, "flats": flats, "k": k,
             "packed": packed, "variant": variant}
+
+
+def _launch_heads(resident: ResidentPack, flats: Sequence[FlatQuery],
+                  tails: Sequence[Sequence[str]], k: int, mesh, slots: int,
+                  stages: Optional[StageTimes] = None,
+                  max_batch: int = 128) -> Dict[str, Any]:
+    """One ASYNC launch of the heads program (`dist.make_heads_search`):
+    queries holding the pack's head terms, their tails (`tails`, the
+    terms that are not head terms) at the `slots` rung. Its operands: the
+    tails' fused operand (one native call where it can, as the full path
+    builds its own, with no rescore terms) and the head weights [rows,
+    h_pad], each head row's sum of the query's boosts. The first launch
+    on a pack builds its head block (`_head_block`) and submits every
+    member of `heads_program_set` for compilation; the launch waits for
+    its own. Finished by `_finish_pruned`: the output is the full path's."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticsearch_tpu.parallel.mesh import DATA_AXIS
+
+    t_prep = time.perf_counter()
+    pack = resident.pack
+    rows = next((r for r in HEADS_ROW_BUCKETS[slots] if r >= len(flats)),
+                _serving_bucket(len(flats)))
+    label = dist.heads_program_name(rows, slots)
+    states = tracing.current_states()
+    states.switch("prep", queries=len(flats), path=label, rows=rows)
+    block = _head_block(resident, mesh, stages)
+    heads = pack.term_table.head_index(pack)
+    boosts = [f.boost for f in flats]
+    w = np.zeros((rows, block.h_pad), dtype=np.float32)
+    n_terms = 0
+    for q, f in enumerate(flats):
+        for term in f.terms:
+            h = heads.get(term)
+            if h is not None:
+                w[q, h] += f.boost
+                n_terms += 1
+    full = _native_full_operands(pack, tails, boosts, rows, slots, 0, stages)
+    if full is not None:
+        ops = full.ops
+    else:
+        with tracing.stage(stages, "prep.query_batch"):
+            batch = dist.prepare_query_batch(
+                pack, tails, boosts=boosts, pad_batch_to=rows,
+                pad_t_slots=slots, pad_max_len=dist.CHUNK_CAP)
+        with tracing.stage(stages, "prep.term_ranges"):
+            none = np.zeros(batch.starts.shape[:2] + (0,), dtype=np.int32)
+            ops = dist.pack_pruned_operands(batch, none, none,
+                                            none.view(np.float32))
+    if ops.shape[2] != 3 * slots + 1:
+        raise ValueError(f"{label}: tails that need more than {slots} slots")
+    HEADS_COUNTS.inc("queries", n=len(flats))
+    HEADS_COUNTS.inc("launches")
+    HEADS_COUNTS.inc("terms", n=n_terms)
+    LAUNCH_COUNTS.inc(label)
+    _count_cross_chip(mesh, rows)
+    program = HeadsProgram(rows, slots, _pruned_k_out(k))
+    ready = _ready_heads_programs(resident, mesh, k, max_batch, program)
+    fn = (ready[(slots, rows)].result() if (slots, rows) in ready
+          else _make_heads_search(resident, mesh, program))
+    sbt = NamedSharding(mesh, P(SHARD_AXIS, DATA_AXIS, None))
+    t_disp = states.switch("lock")
+    with dist.DEVICE_DISPATCH_LOCK:
+        t_put = states.switch("put")
+        ops_dev = jax.device_put(ops, sbt)
+        w_dev = jax.device_put(w, NamedSharding(mesh, P(DATA_AXIS, None)))
+        t_call = states.switch("call", path=label, rows=rows)
+        packed = fn(resident.imp_device_arrays[0],
+                    resident.imp_device_arrays[1], block.array, ops_dev,
+                    w_dev)
+    t_dev = states.switch("prep")
+    _freeze_first_launch(resident, label, program.k_out, mesh)
+    if stages is not None:
+        stages.add("batch_prep", t_disp - t_prep)
+        stages.add("batch_dispatch", t_dev - t_disp)
+        stages.add("batch_put", t_call - t_put)
+    return {"resident": resident, "flats": flats, "k": k,
+            "packed": packed, "variant": "heads"}
 
 
 def _finish_pruned(launch: Dict[str, Any],
@@ -4594,8 +4924,10 @@ class TpuSearchService:
                 "term_table": dist.TERM_TABLE_COUNTS.counts(),
                 "operands": OPERAND_COUNTS.counts(),
                 "exact_results": EXACT_RESULT_COUNTS.counts(),
+                "heads": HEADS_COUNTS.counts(),
                 "exact_programs": self.exact_programs(),
                 "full_programs": self.full_programs(),
+                "heads_programs": self.heads_programs(),
                 "render": RENDER_COUNTS.counts(),
                 "fetch": FETCH_COUNTS.counts(),
                 "queue": self.batcher.queue_depths(),
@@ -4628,6 +4960,13 @@ class TpuSearchService:
         the full-postings programs that its first full-path launch at
         `k` compiles before it is answered (`full_program_set`)."""
         return self._programs(full_program_set, k)
+
+    def heads_programs(self, k: int = PRUNE_MAX_K) -> Dict[str, List[str]]:
+        """The /_tpu/stats `heads_programs` block: for each resident pack,
+        the heads programs its first launch on the heads route at `k`
+        submits for compilation (`heads_program_set`; none where the pack
+        has no head term)."""
+        return self._programs(heads_program_set, k)
 
     def device_stats(self) -> Dict[str, Any]:
         """The /_tpu/stats `devices` block: the device stamp (platform,

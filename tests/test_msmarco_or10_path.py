@@ -303,3 +303,19 @@ def test_the_configuration_is_the_standing_deployments_data():
     assert {k: v for k, v in ours["generator"].items()
             if k not in ("docs", "num_queries")} == \
         {k: v for k, v in GENERATOR.items() if k not in ("docs", "num_queries")}
+
+
+def test_the_band_law_pack_builds_no_head_block(msmarco):
+    """The heads route leaves the band law alone: the toy pack has no
+    head term (`dist.head_terms`), its answers above built no head block,
+    readied no `jit_heads_*` program, and `/_tpu/stats` lists none."""
+    from elasticsearch_tpu.parallel import distributed as dist
+
+    resident = msmarco["resident"]
+    assert dist.head_terms(resident.pack) == []
+    assert resident.pack.term_table.head_index(resident.pack) == {}
+    stats = msmarco["http"].stats()
+    assert stats["heads_programs"] == {"msmarco/body": []}
+    assert resident.heads_block is None and resident.heads_ready == {}
+    assert not any(path.startswith("heads_") for path, *_ in resident.launched)
+    assert "heads_block" not in stats["pack_cache"]["packs"]["msmarco/body"]

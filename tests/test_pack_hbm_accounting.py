@@ -203,3 +203,55 @@ def test_build_failure_refunds_charge(svc, seeded_np,  # noqa: F811
         assert breaker.used == detail["hbm_bytes"] > 0
     finally:
         tpu.close()
+
+
+def test_a_head_block_is_charged_with_its_pack_and_drains(
+        svc, seeded_np, monkeypatch):  # noqa: F811
+    """A pack's head block (the heads route: `dist.head_terms`, built by
+    the first query that holds a head term) is charged to the `hbm`
+    breaker beside the pack's own bytes, shown as the pack's
+    `heads_block`, and released with the pack: the drain is still exact.
+    A toy shard row holds too few docs for a head term at the
+    deployment's HEAD_MIN_SLOTS, so the test lowers it to one slot."""
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.parallel import distributed as dist
+    from elasticsearch_tpu.search.tpu_service import KERNEL_CONFIG
+
+    monkeypatch.setattr(dist, "HEAD_MIN_SLOTS", 1)
+    idx = svc.create_index(
+        "heads", Settings.of({"index": {"number_of_shards": 1}}),
+        {"properties": {"body": {"type": "text"}}})
+    shard = idx.shard(0)
+    others = ["beta", "gamma", "delta", "epsilon", "zeta", "eta"]
+    for i in range(4500):  # "alpha" in every doc: 4,500 postings, 2 slots
+        words = ["alpha"] + [others[int(w)] for w in
+                             seeded_np.integers(0, len(others), 2)]
+        shard.apply_index_on_primary(f"d{i}", {"body": " ".join(words)})
+    idx.refresh()
+    breaker = CircuitBreaker("hbm", 1 << 30)
+    tpu = TpuSearchService(window_s=0.0, batch_timeout_s=300.0,
+                           breaker=breaker, compressed_pack=False)
+    try:
+        assert tpu.try_search(idx, dsl.MatchQuery(field="body", query="beta"),
+                              k=10) is not None
+        detail = tpu.packs.stats()["packs"]["heads/body"]
+        assert "heads_block" not in detail  # no head term was asked for
+        assert breaker.used == detail["hbm_bytes"] > 0
+
+        result = tpu.try_search(
+            idx, dsl.MatchQuery(field="body", query="alpha beta"), k=10)
+        assert result is not None and result.total_hits == 4500
+        assert tpu.stats()["route"]["heads"] >= 1
+        detail = tpu.packs.stats()["packs"]["heads/body"]
+        block = detail["heads_block"]
+        assert block["terms"] == 1 and block["bytes"] == (
+            4 * block["rows"] * block["shard_rows"] * block["docs_per_row"])
+        assert breaker.used == detail["hbm_bytes"] + block["bytes"]
+
+        svc.delete_index("heads")
+        tpu.invalidate_index("heads")
+        assert tpu.packs.stats()["packs"] == {}
+        assert breaker.used == 0
+    finally:
+        tpu.close()
+        KERNEL_CONFIG["compressed_pack"] = True
